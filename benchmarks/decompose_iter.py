@@ -9,6 +9,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import time, numpy as np, jax, jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops.gather import gather_small
+from lightgbm_tpu.utils.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 N, F = 10_500_000, 28
 rs = np.random.RandomState(0)

@@ -34,7 +34,10 @@ import numpy as np
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.models.gbdt import GBDTBooster
+from lightgbm_tpu.utils.compile_cache import configure_compile_cache
 from lightgbm_tpu.utils.timer import Timer
+
+configure_compile_cache()
 
 N = int(os.environ.get("BENCH_FUSED_ROWS", "10500000"))  # smoke knob
 F = 28
@@ -149,7 +152,7 @@ print(f"scan vs fused: {fused / scan:.3f}x wall, driver gap "
       f"{fused_driver * 1e3:.2f} -> {scan_driver * 1e3:.2f} ms/iter "
       f"({gap_ratio:.1f}x lower) — "
       f"{'FLIP fused_scan_iters auto to ' + str(SCAN_W) if scan < fused else 'keep per-iteration'} "
-      "(record the verdict in docs/FUSED.md + PROFILE.md)",
+      "(record the verdict in docs/FUSED.md + PERF.md)",
       flush=True)
 
 from lightgbm_tpu.ops.pallas_hist import pallas_available  # noqa: E402
@@ -158,7 +161,7 @@ if pallas_available():
     pallas, _ = run("fused+pallas", fused=True, hist_method="pallas")
     print(f"pallas vs mxu (fused): {fused / pallas:.3f}x — "
           f"{'FLIP auto to pallas' if pallas < fused else 'keep mxu'} "
-          "(record the verdict in docs/PALLAS.md + PROFILE.md)",
+          "(record the verdict in docs/PALLAS.md + PERF.md)",
           flush=True)
 else:
     print("pallas arm SKIPPED (unavailable)", flush=True)

@@ -24,6 +24,9 @@ from lightgbm_tpu.ops.grow import GrowConfig, grow_tree
 from lightgbm_tpu.ops.histogram import hist_from_rows
 from lightgbm_tpu.ops.split import SplitParams, find_best_split
 from lightgbm_tpu.ops.predict import predict_leaf_binned
+from lightgbm_tpu.utils.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 N = int(os.environ.get("BENCH_ROWS", 1_048_576))
 F = int(os.environ.get("BENCH_FEATURES", 28))

@@ -40,8 +40,7 @@ def main_comms() -> None:
     from jax.sharding import PartitionSpec as P
 
     from lightgbm_tpu.parallel import comms
-    # the jax-version shard_map shim the package already maintains
-    from lightgbm_tpu.parallel.data_parallel import shard_map
+    from jax import shard_map
     from lightgbm_tpu.parallel.mesh import make_mesh
 
     F, B, reps = 4228, 255, 8
@@ -70,7 +69,7 @@ def main_comms() -> None:
             return out[None]
 
         fn = jax.jit(shard_map(step, mesh=mesh, in_specs=P(axis),
-                               out_specs=P(axis), check_rep=False))
+                               out_specs=P(axis), check_vma=False))
         fn(hists).block_until_ready()          # compile
         t0 = time.perf_counter()
         n_meas = 3
@@ -96,7 +95,7 @@ def main_comms() -> None:
     print(f"int8 vs int16: {times['int16'] / times['int8']:.3f}x, "
           f"int8 vs f32 allreduce: {times['f32'] / times['int8']:.3f}x "
           f"— {verdict} "
-          "(record the verdict in docs/COLLECTIVES.md + PROFILE.md)",
+          "(record the verdict in docs/COLLECTIVES.md + PERF.md)",
           flush=True)
 
 
@@ -136,6 +135,8 @@ def main_quant() -> None:
 
 
 if __name__ == "__main__":
+    from lightgbm_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if "--comms" in sys.argv:
         main_comms()
     else:

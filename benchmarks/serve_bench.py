@@ -5,8 +5,8 @@ serving stack — CompiledForest + MicroBatcher, in process, no sockets
 Concurrent client threads submit fixed-size row blocks through
 ``MicroBatcher.submit`` for a fixed wall window; the bench reports
 sustained rows/s, request latency percentiles and the batcher's own
-coalescing stats as ONE JSON line on stdout (the bench.py contract,
-greppable from revive_and_measure.sh). A second traced window samples
+coalescing stats as ONE JSON line on stdout (the bench.py
+contract). A second traced window samples
 requests through the tracing plane (obs/trace.py) and reports the
 span-derived stage decomposition — queue wait / batch window / device
 dispatch — so an on-chip p99 regression localizes to a stage without
@@ -32,6 +32,7 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.obs.trace import drain_span_events
 from lightgbm_tpu.serve.batcher import MicroBatcher
 from lightgbm_tpu.serve.compile import compile_forest
+from lightgbm_tpu.utils.compile_cache import configure_compile_cache
 
 SECS = float(os.environ.get("BENCH_SERVE_SECS", "10"))
 CLIENTS = int(os.environ.get("BENCH_SERVE_CLIENTS", "8"))
@@ -71,6 +72,7 @@ def _pct(sorted_vals, q):
 
 
 def main():
+    configure_compile_cache()
     t0 = time.perf_counter()
     forest = _train_forest()
     forest.warmup()

@@ -1,8 +1,7 @@
 """Device trace of one WIDE (Allstate-shaped, EFB-bundled) iteration.
 
-Round-5 diagnostic for the ~10 ms/split fixed cost at width
-(benchmarks/PROFILE.md "131K x 4228 diagnostic"): traces one
-train_one_iter at BENCH_ROWS x BENCH_FEATURES through the real
+Diagnostic for the per-split fixed cost at width (ROADMAP S1):
+traces one train_one_iter at BENCH_ROWS x BENCH_FEATURES through the real
 engine, parses the xplane directly and prints device-time by op
 category, so the per-split fixed path can be attributed to actual
 HLOs instead of suspicion.
@@ -48,6 +47,8 @@ def make_allstate_like(n, f, seed=0, per_group=128):
 def main():
     import jax
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     X, y = make_allstate_like(N, F)
     t0 = time.time()

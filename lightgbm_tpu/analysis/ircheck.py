@@ -7,9 +7,9 @@ signatures declared in :data:`build_specs`'s per-entry table (seeded
 from ``obs/recorder.py``'s ``ENTRY_PHASES`` entries plus the shapes
 the tests/benches drive), and enforces four IR rule families:
 
-- **TPL011 dtype contract** — trace under ``jax.experimental
-  .enable_x64`` and flag any *strong* float64 aval in the jaxpr
-  (including nested jaxprs). Weak-typed rank-0 literal plumbing
+- **TPL011 dtype contract** — trace under ``jax.enable_x64`` and
+  flag any *strong* float64 aval in the jaxpr (including nested
+  jaxprs). Weak-typed rank-0 literal plumbing
   (``jnp.where(m, x, 0.0)`` routing a python float through a scalar
   ``convert_element_type``) is exempt: it lowers to f32 compute and
   pinning every literal would be noise. A ``np.float64`` constant or
@@ -366,17 +366,14 @@ def _walk_jaxprs(jaxpr):
 def _site_of(eqn, fallback, marker: str = "/lightgbm_tpu/"):
     """(relpath, lineno, func) of the user frame that traced ``eqn``
     — the first frame under ``marker`` (the analyzed tree)."""
-    try:
-        from jax._src import source_info_util
-        for fr in source_info_util.user_frames(eqn.source_info):
-            fname = fr.file_name.replace(os.sep, "/")
-            if marker in fname:
-                rel = fname.rsplit(marker, 1)[1]
-                if rel.startswith("analysis/"):
-                    continue
-                return rel, int(fr.start_line or 0), fr.function_name
-    except Exception:
-        pass
+    from jax._src import source_info_util
+    for fr in source_info_util.user_frames(eqn.source_info.traceback):
+        fname = fr.file_name.replace(os.sep, "/")
+        if marker in fname:
+            rel = fname.rsplit(marker, 1)[1]
+            if rel.startswith("analysis/"):
+                continue
+            return rel, int(fr.start_line or 0), fr.function_name
     return fallback
 
 
@@ -591,7 +588,6 @@ def run_ircheck(rules: Optional[Sequence[str]] = None,
     t0 = time.perf_counter()
     want = set(rules) & set(IR_RULE_IDS) if rules else set(IR_RULE_IDS)
     jax = ensure_cpu_jax()
-    from jax.experimental import enable_x64
     from ..parallel.comms import collective_summary
 
     budgets_path = budgets_path or default_budgets_path()
@@ -616,7 +612,7 @@ def run_ircheck(rules: Optional[Sequence[str]] = None,
         closed = jax.make_jaxpr(fn, static_argnums=static_argnums)(
             *args)
         if "TPL011" in want:
-            with enable_x64():
+            with jax.enable_x64(True):
                 closed64 = jax.make_jaxpr(
                     fn, static_argnums=static_argnums)(*args)
             findings.extend(f64_findings(closed64, spec.relpath,

@@ -382,6 +382,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         # programmatic main() callers
         from .obs.trace import main as trace_main
         return trace_main(argv[1:])
+    from .utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     try:
         params = parse_args(argv)
         cfg = Config.from_params(params)

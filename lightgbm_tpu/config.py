@@ -691,8 +691,9 @@ class Config:
     # (ops/pallas_hist.py; runs under the Pallas interpreter on CPU).
     # Flipping auto to pallas on TPU is gated on a measured iters/sec
     # win on the Higgs-shaped bench (LIGHTGBM_TPU_AUTO_PALLAS=1 opts
-    # in; see docs/PALLAS.md). Falls back mxu -> scatter under the OOM
-    # degradation ladder or when Pallas is unavailable.
+    # in; see docs/PALLAS.md). Steps down pallas -> mxu -> scatter under
+    # the OOM degradation ladder (a recorded fault event); an explicit
+    # pallas that cannot be honoured raises.
     hist_method: str = "auto"
     # MXU histogram accumulation passes: default (single-pass bf16 input /
     # f32 accumulation — the reference GPU learner's single-precision
@@ -719,7 +720,8 @@ class Config:
     # rows per streaming chunk in the compact grower's partition pass
     # (perf knob; power of two. Larger chunks amortize per-chunk fixed
     # costs but pay more window-tail padding and higher per-row sort
-    # depth — 16384 measured best on v5e, benchmarks/PROFILE.md)
+    # depth; no chunk sweep has been run on a local chip — not
+    # measured)
     chunk_rows: int = 16384
     # bulk-batching chunk size: the partition streams floor(cnt/
     # big_chunk_rows) big bodies per leaf window before the chunk_rows

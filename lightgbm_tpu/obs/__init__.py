@@ -1,17 +1,17 @@
 """Run telemetry: metrics registry, recompile/HBM tracking, JSONL events.
 
-The observability spine the perf ROADMAP items report against. Round 5's
-PROFILE.md lesson is that per-op microbenchmarks lie in both directions
-on this codebase — only in-situ measurement of the real boosting loop is
-trustworthy — so every layer here instruments the *actual* hot path and
-is a strict no-op when disabled:
+The observability spine the perf ROADMAP items report against.
+Per-op microbenchmarks have misled in both directions on this codebase
+— only in-situ measurement of the real boosting loop is trustworthy —
+so every layer here instruments the *actual* hot path and is a strict
+no-op when disabled:
 
 - :class:`MetricsRegistry` — label-keyed, thread-safe counters / gauges /
   histograms (`registry` is the process-global instance).
 - :mod:`~lightgbm_tpu.obs.jit_tracker` — registered jitted entry points
   (grow / fused-iteration / predict) expose XLA cache-size deltas, so a
   shape-change recompile shows up as a counted event, not a mystery
-  530 ms stall.
+  stall.
 - :func:`device_memory_stats` — HBM gauges via ``device.memory_stats()``
   with explicit ``None`` on backends that lack it (CPU).
 - :class:`TelemetryRecorder` — one JSONL event per boosting iteration

@@ -192,10 +192,6 @@ def _cost_analysis(fn: Callable, args: tuple, kwargs: dict) \
         ca = lowered.compile().cost_analysis()
     else:
         ca = lowered.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return None, None
     flops = ca.get("flops")
     bytes_accessed = ca.get("bytes accessed")
     return (None if flops is None else float(flops),

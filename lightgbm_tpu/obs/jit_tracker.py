@@ -1,8 +1,9 @@
 """Recompile tracking for jitted hot-path entry points.
 
 A silent XLA recompile is the single most expensive event this codebase
-can hit mid-training (PROFILE.md's 530 ms/iter regression class), and it
-never announces itself. Every jitted boosting-path entry point registers
+can hit mid-training (the Higgs-width fused step takes ~154 s to
+compile cold on a v5e — chip_smoke.py, PR 21), and it never announces
+itself. Every jitted boosting-path entry point registers
 here (``register_jit``); the per-function compile-cache size
 (``PjitFunction._cache_size``) is then a direct compile counter — a
 cache miss IS a compilation — and :class:`RecompileWatcher` turns the

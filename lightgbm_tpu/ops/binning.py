@@ -372,10 +372,10 @@ def bin_matrix(X: np.ndarray, col_indices, mappers: Sequence[BinMapper],
 
     Numerical columns go through the native C++ kernel when available
     (utils/native.py ltpu_bin_columns — the reference also bins with
-    compiled code, bin.h ValueToBin): the numpy per-column path costs
-    ~100-160 ns/value in call dispatch and strided access, which at
-    Allstate width (4228 columns) made construct the wall-clock
-    bottleneck (benchmarks/PROFILE.md round 5). Categorical columns
+    compiled code, bin.h ValueToBin): the numpy per-column path pays
+    per-call dispatch and strided access for every column, which at
+    Allstate width (4228 columns) dominates construct. Categorical
+    columns
     (dict lookups) and unsupported dtypes fall back to value_to_bin;
     results are bit-identical either way."""
     col_indices = np.asarray(col_indices, np.int64)

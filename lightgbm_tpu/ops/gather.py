@@ -1,13 +1,13 @@
 """Small-table row gathers that compile well on TPU.
 
 ``table[idx]`` with a million-row ``idx`` and a tiny table lowers to an
-XLA gather that TPUs execute one element at a time (~8.6 ms per million
-rows measured — benchmarks/PROFILE.md). The boosting loop needs exactly
+XLA gather that TPUs execute one element at a time (its cost on a
+local chip: not measured). The boosting loop needs exactly
 this shape in several places (leaf value -> row score contribution, the
 reference's ScoreUpdater::AddScore walk, score_updater.hpp:58): a [n]
 index vector into an [L <= a few hundred] table. ``gather_small``
 replaces it with L sequential full-width selects — O(L * n / lanes)
-vector work, ~30x faster at L=255 — while keeping exact dtype semantics
+vector work — while keeping exact dtype semantics
 (values are moved bit-for-bit, never re-rounded).
 """
 

@@ -43,14 +43,6 @@ from .partition_kernel import route_concentrate
 __all__ = ["GrowConfig", "TreeArrays", "grow_tree", "route_concentrate"]
 
 
-def _axis_size(name) -> int:
-    """Static mapped-axis size. ``lax.axis_size`` only exists on
-    jax>=0.4.38; 0.4.37's accessor is ``core.axis_frame`` (returns the
-    int size under shard_map)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return jax.core.axis_frame(name)
-
 NEG_INF = -jnp.inf
 
 
@@ -104,12 +96,10 @@ class GrowConfig(NamedTuple):
     chunk: int = 16384           # rows per streaming chunk (compact grower)
     # Bulk-batching chunk size: each leaf window is partitioned as
     # floor(cnt/big_chunk) BIG chunks followed by K-sized tail chunks.
-    # MEASURED NEUTRAL-TO-NEGATIVE on v5e (round 4: 158->162 ms/tree at
-    # 1M rows with 131072, 392->428 ms at 10.5M): the chunk body is
-    # throughput-bound (the bitonic sort's per-row work grows ~log^2 CK,
-    # cancelling the amortized dispatch overhead), NOT dispatch-bound as
-    # PROFILE.md round-3 option 2 hypothesized. Kept as a tuning knob;
-    # 0 (default) disables.
+    # The bitonic sort's per-row work grows ~log^2 of the chunk size,
+    # which works against the amortized per-chunk overhead; whether it
+    # pays on a local chip is not measured (ROADMAP D4). Kept as a
+    # tuning knob; 0 (default) disables.
     big_chunk: int = 0
     axis_name: Optional[str] = None
     grower: str = "compact"
@@ -168,9 +158,8 @@ class GrowConfig(NamedTuple):
     hist_pool_slots: int = 0
     # in-chunk stable partition primitive (compact grower):
     # "sort"  — one variadic lax.sort on a (side, position) key.
-    #           Default: XLA:TPU's variadic sort measures ~35us per
-    #           16K chunk in situ (xplane, benchmarks/PROFILE.md) —
-    #           NOT the chunk bottleneck.
+    #           Default; its share of a chunk on a local chip is not
+    #           measured.
     # "route" — two butterfly concentration passes (log2(K) stages of
     #           stride exchanges, LSB-first) steered by destination
     #           bits (ops/partition_kernel.py). Fewer stages on paper,
@@ -474,7 +463,7 @@ def _make_sharded_search(cfg: GrowConfig, F: int, qm: str,
     ([F, B, 2] root / [L, F, B, 2] level batch), so the scatter axis
     is positional. Must be called inside the traced program (it takes
     ``lax.axis_index``)."""
-    D_sh = _axis_size(cfg.axis_name)
+    D_sh = lax.axis_size(cfg.axis_name)
     dev_idx = lax.axis_index(cfg.axis_name)
     Fl = -(-F // D_sh)
     Fsp = Fl * D_sh
@@ -1066,8 +1055,7 @@ def _leaf_values_at_positions(leaf_begin, leaf_count, values, n):
     At each active range start, scatter the DELTA between consecutive
     begin-sorted leaves' values (an L-sized scatter — cheap), then one
     [n] cumsum materializes the value per position. No [n]-sized
-    gather: XLA:TPU serializes gathers per element (~8.6 ms per
-    million rows measured, benchmarks/PROFILE.md), while scatter-of-L
+    gather: XLA:TPU serializes gathers per element, while scatter-of-L
     + cumsum is pure vector work."""
     active = leaf_count > 0
     keys = jnp.where(active, leaf_begin, n + 1)
@@ -1450,7 +1438,7 @@ def _grow_compact_impl(cfg: GrowConfig,
             # leaf sums and shard-scaled data constraints (the
             # reference's local_config_, voting_parallel_tree_learner
             # .cpp:61-63)
-            ndev = _axis_size(ax)
+            ndev = lax.axis_size(ax)
             lh_tot = jnp.sum(hist[0], axis=0)   # feature 0 sees all rows
             sg_loc, sh_loc = lh_tot[0], lh_tot[1]
             sc_loc = jnp.round(sc * sh_loc / jnp.maximum(sh, 1e-15))
@@ -1731,7 +1719,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     # device min(f // Fl, D-1) only — each device's search mask keeps
     # just its owned columns, so hist rows and metadata stay aligned.
     if fp:
-        D_fp = _axis_size(cfg.axis_name)          # static under shard_map
+        D_fp = lax.axis_size(cfg.axis_name)          # static under shard_map
         dev_idx = lax.axis_index(cfg.axis_name)   # traced
         NWl = -(-NW // D_fp)
         Fl = NWl * pack_w

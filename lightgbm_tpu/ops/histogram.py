@@ -50,9 +50,9 @@ PACK = 4           # features per MXU pack. The matmul computes all
                    # diagonal, so FLOPs per feature scale with PACK —
                    # while the materialized one-hot bytes per feature
                    # (s_hi + s_lo*C values) don't depend on it.
-                   # Measured on v5e (benchmarks/PROFILE.md): PACK=4
-                   # beats 8 (half the FLOPs) and 2 (whose M=16 matmul
-                   # streams the MXU poorly).
+                   # PACK=4 does half the FLOPs of 8, and 2 leaves an
+                   # M=16 matmul that streams the MXU poorly; the
+                   # three are not measured on a local chip.
 S_LO = 16          # bins per low-digit group: b = S_LO*hi + lo. With
                    # PACK=4 the 16x16 split keeps the matmul N dim at
                    # PACK*S_LO*C = 128 — exactly the MXU's output lanes
@@ -97,8 +97,7 @@ def _nibble_hist_block(rows: jnp.ndarray, payload: jnp.ndarray,
     # the MXU truncates DEFAULT-precision f32 inputs to bf16 anyway,
     # and {0,1} masks commute with truncation (LOC is pay-or-zero), so
     # the result is bit-identical on TPU while the materialized
-    # one-hot traffic — the measured cost center of the whole
-    # histogram (xplane, benchmarks/PROFILE.md) — halves. Multi-pass
+    # one-hot traffic halves. Multi-pass
     # "high"/"highest" emulation needs true f32 operands, and CPU
     # matmuls don't truncate, so both keep the payload dtype there.
     bf16_pass = int_exact or (precision is None

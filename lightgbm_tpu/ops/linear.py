@@ -48,8 +48,8 @@ def linear_leaf_values(const: jnp.ndarray, coef: jnp.ndarray,
     km = feats.shape[1]
     if km == 0:
         return gather_small(const, leaves)
-    # gather_small for every [n]-sized leaf lookup: TPU small-table
-    # gathers run ~1 elt/cycle (benchmarks/PROFILE.md)
+    # gather_small for every [n]-sized leaf lookup: XLA:TPU runs
+    # small-table gathers one element at a time (ops/gather.py)
     fr = gather_small(feats, leaves)                       # [n, km]
     act = jnp.arange(km)[None, :] < gather_small(nfeat, leaves)[:, None]
     x = jnp.take_along_axis(X, fr, axis=1)

@@ -1,30 +1,31 @@
 """Hand-tiled Pallas TPU histogram kernel (``hist_method="pallas"``).
 
 The MXU nibble path (histogram.py) materializes its HI/LO one-hot
-operands through HBM — the measured cost center of the whole histogram
-(~25 us of one-hot broadcast/compare per 16K-row chunk on v5e against
-~22 us of einsum, benchmarks/PROFILE.md). This kernel builds the
-one-hot *inside* the kernel body, so it only ever exists in VMEM:
+operands through HBM. This kernel builds the one-hot *inside* the
+kernel body, so it only ever exists in VMEM:
 
+- **Layout**: rows ride the lane dimension everywhere. The wrapper
+  hands the kernel the bin matrix feature-major (``[F, S]``, its
+  narrow integer dtype kept) and the payload channel-major
+  (``[C, S]``), so every block's last dimension is a row tile (a
+  multiple of 128 lanes) and its second-to-last is either a full array
+  dimension or the 8-feature pack — the block shapes Mosaic accepts.
 - **Grid** = ``(feature_packs, row_tiles)``. The row-tile dimension is
-  innermost, so the ``[C, FPACK, B]`` output block stays VMEM-resident
+  innermost, so the ``[FPACK, C, BP]`` output block stays VMEM-resident
   across the whole row sweep of one feature pack (initialized at tile
   0, accumulated in f32 thereafter) while Pallas double-buffers the
-  ``[ROW_TILE, FPACK]`` bin-column and ``[ROW_TILE, C]`` payload blocks
-  through VMEM — the bin matrix streams HBM -> VMEM exactly once per
-  feature pack and nothing histogram-shaped ever goes back until the
-  final ``[C, F, B]`` result (a few hundred KB).
-- **Compute**: the per-tile one-hot ``[ROW_TILE, FPACK * B]`` feeds ONE
-  ``dot_general`` against the ``[ROW_TILE, C]`` payload with
-  f32 ``preferred_element_type`` — N = FPACK*B lanes (2048 at B=256:
-  16 full lane tiles), K = ROW_TILE. Features live in the N dimension,
-  so no cross-feature garbage is computed (the MXU path burns PACK x
-  PACK blocks to keep a diagonal) and no sub-lane reshape/diagonal
-  extraction is needed — the two Mosaic cliffs that killed the earlier
-  prototype (PROFILE.md "rejected routes").
-- **Tiling**: B pads up to a 128-lane multiple; ROW_TILE is sized so
-  the one-hot block stays ~4 MiB of VMEM (1024 rows at B<=128, 512 at
-  B=256), leaving room for Pallas' input double buffers.
+  ``[FPACK, ROW_TILE]`` bin block and the ``[C, ROW_TILE]`` payload
+  block through VMEM — the bin matrix streams HBM -> VMEM exactly once
+  and nothing histogram-shaped goes back until the final result.
+- **Compute**: per feature of the pack, the transposed one-hot
+  ``[BP, ROW_TILE]`` (one sublane-broadcast compare of the feature's
+  bin row against a bin iota) is contracted with the ``[C, ROW_TILE]``
+  payload over the row (lane) dimension of both — the ``q @ k^T`` form
+  of ``dot_general`` — giving a lane-dense ``[C, BP]`` partial. No
+  in-kernel reshape, no cross-feature garbage.
+- **Tiling**: B pads up to a 128-lane multiple; ROW_TILE is the largest
+  power of two <= 1024 keeping one feature's f32 one-hot within ~4 MiB
+  of VMEM (1024 rows up to B=1024, 128 rows at B=8192).
 - **Exactness**: float payloads accumulate in f32 (on TPU the MXU's
   default single-pass mode reads the f32 one-hot/payload as bf16 — the
   same numerics class as the mxu path's documented default). int8
@@ -35,12 +36,13 @@ one-hot *inside* the kernel body, so it only ever exists in VMEM:
 
 CPU correctness (tier-1) runs the SAME kernel under
 ``pallas_call(..., interpret=True)``; parity with the mxu and scatter
-paths is asserted by tests/test_pallas_hist.py. On-chip iters/sec on
-the Higgs-shaped bench (255 leaves / 255 bins) is the gate for
-flipping ``hist_method="auto"`` to pallas on TPU
-(benchmarks/fused_iter_bench.py grows the pallas arm); until a
-measured win lands in PROFILE.md, ``auto`` keeps the mxu path and
-pallas is opt-in. docs/PALLAS.md records the tiling rationale.
+paths is asserted by tests/test_pallas_hist.py. On a TPU the kernel is
+always compiled (``interpret=True`` there is an error);
+``python chip_smoke.py --kernels`` compiles it on the chip and checks
+it against the scatter histogram. Its speed against the mxu path is
+not measured: ``auto`` keeps the mxu path and pallas is opt-in until a
+benchmark cell decides (ROADMAP D2). docs/PALLAS.md records the tiling
+rationale.
 """
 
 from __future__ import annotations
@@ -56,8 +58,8 @@ from jax import lax
 __all__ = ["pallas_available", "hist_from_rows_pallas", "FPACK",
            "INT_BLOCK"]
 
-FPACK = 8        # feature columns per grid cell: FPACK * 128-padded-B
-                 # output lanes per dot (2048 at B=256 — 16 lane tiles)
+FPACK = 8        # feature rows per grid cell: the sublane extent of
+                 # the [FPACK, ROW_TILE] bin block
 INT_BLOCK = 131072   # rows per int-exact super-block: 131072 * 127
                      # = 1.66e7 < 2^24, so every f32 partial sum of an
                      # int8 payload is an exact integer
@@ -87,37 +89,36 @@ def pallas_available() -> bool:
     return _pallas_mod is not None
 
 
-def _tile_plan(bp: int):
-    """(fpack, row_tile) keeping the f32 one-hot block
-    [RT, fpack, BP] under the VMEM budget: shrink the feature pack
-    first at very wide B (bundled bin-position counts), then the row
-    tile (power of two; floor 8 = the f32 sublane minimum, reached
-    only past bp = 128K where even fpack=1 rows are that wide)."""
-    fp = FPACK
-    while fp > 1 and 128 * fp * bp * 4 > _ONEHOT_VMEM:
-        fp //= 2
-    rt = _ONEHOT_VMEM // (fp * bp * 4)      # rows fitting the budget
-    if rt < 8:
-        return fp, 8   # bp > 128K: a >1 GB histogram; floor the tile
-    return fp, min(1024, 1 << (rt.bit_length() - 1))
+def _row_tile(bp: int) -> int:
+    """Rows per grid cell: the largest power of two <= 1024 keeping one
+    feature's f32 one-hot ``[BP, RT]`` under the VMEM budget. Rows are
+    the lane dimension, so the floor is 128 — reached at bp = 8192;
+    wider than that the one-hot outgrows the budget and Mosaic decides
+    whether it still fits."""
+    rt = _ONEHOT_VMEM // (bp * 4)
+    if rt < 128:
+        return 128
+    return min(1024, 1 << (rt.bit_length() - 1))
+
+
+UNAVAILABLE_MSG = (
+    "hist_method='pallas' requested but jax.experimental.pallas is "
+    "unavailable (or LIGHTGBM_TPU_DISABLE_PALLAS=1); use "
+    "hist_method='auto'|'mxu'|'scatter'")
 
 
 def _require_pallas():
     """The imported pallas module, or a clear error when the kernel
     cannot be built here (single cache: pallas_available())."""
     if not pallas_available():
-        raise RuntimeError(
-            "hist_method='pallas' requested but jax.experimental."
-            "pallas is unavailable (or LIGHTGBM_TPU_DISABLE_PALLAS"
-            "=1); use hist_method='auto'|'mxu'|'scatter'")
+        raise RuntimeError(UNAVAILABLE_MSG)
     return _pallas_mod
 
 
-def _hist_kernel(bins_ref, pay_ref, out_ref, *, bp: int, fpack: int,
-                 row_tile: int):
+def _hist_kernel(bins_ref, pay_ref, out_ref, *, bp: int, row_tile: int):
     """One (feature-pack, row-tile) grid cell.
 
-    ``out_ref`` is the pack's [C, fpack, BP] f32 accumulator — the same
+    ``out_ref`` is the pack's [FPACK, C, BP] f32 accumulator — the same
     block for every row tile (the grid's innermost dimension), so it
     lives in VMEM across the whole row sweep."""
     pl = _require_pallas()
@@ -127,19 +128,16 @@ def _hist_kernel(bins_ref, pay_ref, out_ref, *, bp: int, fpack: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[...].astype(jnp.int32)            # [RT, fpack]
-    pay = pay_ref[...]                                # [RT, C]
-    c = pay.shape[-1]
-    iota_b = lax.broadcasted_iota(jnp.int32, (row_tile, fpack, bp), 2)
-    onehot = (bins[:, :, None] == iota_b).astype(jnp.float32)
-    # [C, fpack*BP] = pay^T @ onehot, contracting the row dimension:
-    # features ride the N (lane) dimension so nothing off-diagonal is
-    # computed, and the one-hot never leaves VMEM
-    acc = lax.dot_general(pay.astype(jnp.float32),
-                          onehot.reshape(row_tile, fpack * bp),
-                          (((0,), (0,)), ((), ())),
-                          preferred_element_type=jnp.float32)
-    out_ref[...] += acc.reshape(c, fpack, bp)
+    bins = bins_ref[...].astype(jnp.int32)            # [FPACK, RT]
+    pay = pay_ref[...]                                # [C, RT]
+    iota_b = lax.broadcasted_iota(jnp.int32, (bp, row_tile), 0)
+    for f in range(FPACK):
+        # [C, BP] = pay @ onehot_t^T, contracting the row (lane)
+        # dimension of both; the one-hot never leaves VMEM
+        onehot_t = (bins[f:f + 1, :] == iota_b).astype(jnp.float32)
+        out_ref[f] += lax.dot_general(pay, onehot_t,
+                                      (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
 
 
 def _hist_tiles(rows: jnp.ndarray, payload: jnp.ndarray, num_bins: int,
@@ -149,28 +147,25 @@ def _hist_tiles(rows: jnp.ndarray, payload: jnp.ndarray, num_bins: int,
     S, F = rows.shape
     C = payload.shape[-1]
     bp = max(128, -(-num_bins // 128) * 128)
-    fp, rt = _tile_plan(bp)
+    rt = _row_tile(bp)
     Sp = -(-S // rt) * rt
-    Fp = -(-F // fp) * fp
-    if Sp > S:
-        rows = jnp.pad(rows, ((0, Sp - S), (0, 0)))
-        payload = jnp.pad(payload, ((0, Sp - S), (0, 0)))
-    if Fp > F:
-        # pad features' histogram rows are cropped below; their bin
-        # values are irrelevant
-        rows = jnp.pad(rows, ((0, 0), (0, Fp - F)))
+    Fp = -(-F // FPACK) * FPACK
+    # pad rows carry a zero payload; pad features' histogram rows are
+    # cropped below — their bin values are irrelevant
+    bins_t = jnp.pad(rows.T, ((0, Fp - F), (0, Sp - S)))
+    pay_t = jnp.pad(payload.astype(jnp.float32).T, ((0, 0), (0, Sp - S)))
     out = pl.pallas_call(
-        functools.partial(_hist_kernel, bp=bp, fpack=fp, row_tile=rt),
-        grid=(Fp // fp, Sp // rt),
+        functools.partial(_hist_kernel, bp=bp, row_tile=rt),
+        grid=(Fp // FPACK, Sp // rt),
         in_specs=[
-            pl.BlockSpec((rt, fp), lambda i, j: (j, i)),
-            pl.BlockSpec((rt, C), lambda i, j: (j, 0)),
+            pl.BlockSpec((FPACK, rt), lambda i, j: (i, j)),
+            pl.BlockSpec((C, rt), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((C, fp, bp), lambda i, j: (0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, Fp, bp), jnp.float32),
+        out_specs=pl.BlockSpec((FPACK, C, bp), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Fp, C, bp), jnp.float32),
         interpret=interpret,
-    )(rows, payload.astype(jnp.float32))
-    return jnp.transpose(out, (1, 2, 0))[:F, :num_bins, :]
+    )(bins_t, pay_t)
+    return jnp.transpose(out, (0, 2, 1))[:F, :num_bins, :]
 
 
 def hist_from_rows_pallas(rows: jnp.ndarray, payload: jnp.ndarray,
@@ -185,13 +180,18 @@ def hist_from_rows_pallas(rows: jnp.ndarray, payload: jnp.ndarray,
       int_exact: accumulate an int8 payload to an EXACT int32 result
         (subtraction-safe) via <=INT_BLOCK-row super-blocks.
       interpret: run under the Pallas interpreter; defaults to True on
-        every non-TPU backend (the tier-1 CPU parity mode).
+        every non-TPU backend (the tier-1 CPU parity mode) and is an
+        error on a TPU, where the kernel is always compiled.
 
     Returns:
       ``[F, B, C]`` f32 (int32 when ``int_exact``).
     """
+    on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError("the Pallas histogram kernel does not run "
+                         "interpreted on a TPU (interpret=True)")
     S = rows.shape[0]
     if not int_exact:
         return _hist_tiles(rows, payload, num_bins, interpret)

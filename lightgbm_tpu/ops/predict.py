@@ -6,8 +6,7 @@ src/boosting/gbdt_prediction.cpp): one ``fori_loop`` over nodes in
 creation order (parents always precede children) decides each node for
 ALL rows at once from the node's scalar attributes, so no [n]-sized
 gathers from node tables ever occur — XLA:TPU serializes those per
-element (benchmarks/PROFILE.md), and the sweep is also ~2.4x faster
-than the gather walk on CPU.
+element.
 
 Missing-value routing matches the reference's NumericalDecision
 (tree.h:338-360): missing_type none -> NaN treated as 0; zero -> |v| <=
@@ -66,9 +65,7 @@ def _traverse(n: int, decide_node_fn, left_child, right_child):
     each step uses SCALAR node attributes (``decide_node_fn(i)``
     evaluates node i's decision for all rows at once), so there are no
     [n]-sized gathers from node tables — XLA:TPU executes those one
-    element at a time (benchmarks/PROFILE.md), which made the old
-    per-level walk ~1.6 s per million rows; this sweep is pure vector
-    selects.
+    element at a time; this sweep is pure vector selects.
     """
     nn = left_child.shape[0]
     node0 = jnp.zeros((n,), jnp.int32)

@@ -113,15 +113,6 @@ FEATURE_MAX_ROWS = 1 << 16
 DATA_MAX_BYTES = 1 << 20
 
 
-def _axis_size(name) -> int:
-    """Static mapped-axis size (jax 0.4.37: ``lax.axis_size`` does not
-    exist yet; ``core.axis_frame`` returns the int size under
-    shard_map)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return jax.core.axis_frame(name)
-
-
 # ---------------------------------------------------------------------
 # the quantized-allreduce primitive
 # ---------------------------------------------------------------------
@@ -244,7 +235,7 @@ def hist_allreduce(x: jnp.ndarray, axis_name, mode: str = "f32",
     if mode not in ("int8", "int16") \
             or not jnp.issubdtype(x.dtype, jnp.floating):
         return ret(lax.psum(x, axis_name), error_feedback)
-    D = _axis_size(axis_name)
+    D = lax.axis_size(axis_name)
     if D == 1:
         return ret(x, error_feedback)
     if strategy == "auto":
@@ -379,7 +370,7 @@ def hist_reduce_scatter(x: jnp.ndarray, axis_name, mode: str = "f32",
                                  scatter_dimension=scatter_axis,
                                  tiled=True)
         return ret(chunk, error_feedback)
-    D = _axis_size(axis_name)
+    D = lax.axis_size(axis_name)
     if D == 1:
         return ret(x, error_feedback)
 

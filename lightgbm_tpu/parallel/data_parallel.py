@@ -34,16 +34,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.8: jax.shard_map, replication checking via check_vma
-    from jax import shard_map as _shard_map
-
-    def shard_map(fn, mesh, in_specs, out_specs, check_rep):
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from ..ops.grow import GrowConfig, grow_tree_impl
 
@@ -97,7 +89,7 @@ def _build(cfg: GrowConfig, mesh: Mesh, has_monotone: bool, has_cat: bool,
                               None, nkey, bundle)
 
     sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+                        out_specs=out_specs, check_vma=False)
     return jax.jit(sharded)
 
 

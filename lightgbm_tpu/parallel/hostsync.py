@@ -91,9 +91,9 @@ def transport() -> str:
                     "device; using auto")
     import jax
 
-    # jaxlib's CPU backend (<= 0.4.x) rejects multiprocess computations
-    # ("Multiprocess computations aren't implemented on the CPU
-    # backend"), which rules the device transport out for CPU meshes
+    # CPU worlds keep the kv transport the CPU tests were written
+    # against (see parallel/mesh.py make_mesh); the installed jaxlib
+    # 0.9.0 could run the device transport over gloo
     return "kv" if jax.default_backend() == "cpu" else "device"
 
 

@@ -34,13 +34,15 @@ def make_mesh(num_devices: int = 0, axis_name: str = DATA_AXIS,
     if devices is None:
         devices = jax.devices()
         if jax.process_count() > 1 and jax.default_backend() == "cpu":
-            # jaxlib <= 0.4.x's CPU backend refuses multiprocess XLA
-            # computations outright, so a global mesh could never run
-            # a jitted collective. In a kv-transport world
-            # (parallel/hostsync.py picks kv on CPU for the same
-            # reason) every process runs the identical replicated
-            # program over its OWN local devices; the cross-rank
-            # surface is exactly the host-level sync points.
+            # CPU-test plumbing: in a multi-process CPU world
+            # (parallel/hostsync.py picks the kv transport there) every
+            # process runs the identical replicated program over its
+            # OWN local devices, and the cross-rank surface is exactly
+            # the host-level sync points. This dates from jaxlib 0.4.x,
+            # whose CPU backend refused multiprocess computations; the
+            # installed 0.9.0 runs them over gloo (a 2-process jitted
+            # sum was checked), so the branch is a choice the CPU tests
+            # were written against, no longer a backend limit.
             devices = jax.local_devices()
     if num_devices and num_devices > 0:
         devices = devices[:num_devices]

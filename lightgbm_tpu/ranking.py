@@ -151,7 +151,7 @@ class LambdarankNDCG(Objective):
         # program (ranking is excluded from the fused iteration — its
         # per-iteration host state keeps it on the eager path — so an
         # eager block-scan here would dispatch op-by-op every
-        # iteration: tpulint TPL001, the PROFILE.md 530 ms/iter class)
+        # iteration: tpulint TPL001)
         g, h = _lambdarank_grads(
             score, self.q_idx, self.q_mask, self.gain_of_row, weight,
             jnp.float32(self.sigmoid), trunc=self.trunc,

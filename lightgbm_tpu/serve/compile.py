@@ -96,8 +96,12 @@ def _predict_scores_padded(stacked: StackedTrees, X: jnp.ndarray,
         vals = jax.vmap(per_tree_vals)(jnp.arange(T))
     else:
         vals = jnp.take_along_axis(stacked.leaf_value, leaves, axis=1)
-    scores = jnp.zeros((K, X.shape[0]), vals.dtype)
-    scores = scores.at[jnp.arange(T) % K].add(vals)
+    # tree i belongs to class i % K and T is a whole number of
+    # iterations, so the per-class sum is a reshape + reduce. (The
+    # scatter-add form, fused with the gather above, aborts XLA:TPU's
+    # compiler at the 128-row bucket: "Check failed: GetGatherType(
+    # gather) == GatherType::kSublaneGather", libtpu 0.0.34.)
+    scores = vals.reshape(T // K, K, X.shape[0]).sum(axis=0)
     return scores.T                                      # [n, K]
 
 

@@ -1019,6 +1019,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"[LightGBM-TPU] [Fatal] {e}", file=sys.stderr)
         return 1
     # ---- everything below may import jax ----
+    from ..utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     rank = int(os.environ.get("LIGHTGBM_TPU_RANK") or 0)
     port = args.port + rank if args.port else 0
     telemetry_path = args.telemetry \

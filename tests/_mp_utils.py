@@ -69,27 +69,3 @@ def spawn_worker(args: Sequence[str], env: Dict[str, str],
         start_new_session=True, **popen_kwargs)
 
 
-def _cpu_backend_lacks_multiprocess() -> bool:
-    """jaxlib <= 0.4.x refuses multiprocess XLA computations on the
-    CPU backend ("Multiprocess computations aren't implemented on the
-    CPU backend"), so device-transport collective tests can only run
-    where a real accelerator mesh exists."""
-    import jax
-
-    if jax.default_backend() != "cpu":
-        return False
-    try:
-        import jaxlib
-        major, minor = (int(x) for x in
-                        jaxlib.__version__.split(".")[:2])
-        return (major, minor) < (0, 5)
-    except Exception:
-        return True
-
-
-#: mark for tests that need jit-level collectives ACROSS processes
-#: (the kv host-transport tests do not — they run everywhere)
-requires_multiprocess_computations = pytest.mark.skipif(
-    _cpu_backend_lacks_multiprocess(),
-    reason="CPU backend in this jaxlib cannot run multiprocess XLA "
-           "computations (device-transport collectives need TPU/GPU)")

@@ -4,14 +4,14 @@ metric, so no finding."""
 
 
 def build(jax, jnp):
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from lightgbm_tpu.parallel.data_parallel import shard_map
     from lightgbm_tpu.parallel.mesh import DATA_AXIS, make_mesh
     mesh = make_mesh(8, devices=jax.devices("cpu"))
-    fn = shard_map(lambda x: jax.lax.psum(x, DATA_AXIS), mesh,
+    fn = shard_map(lambda x: jax.lax.psum(x, DATA_AXIS), mesh=mesh,
                    in_specs=P(DATA_AXIS), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn, (jnp.ones((8, 32), jnp.float32),)
 
 

@@ -1,21 +1,20 @@
-"""The driver contract of bench.py: ONE parseable JSON line on stdout
-and exit code 0, regardless of backend health (BENCH_r01/r03/r04 were
-lost to stack traces or timeouts before this was hardened)."""
+"""The contract of bench.py: one process, one parseable JSON line on
+stdout that names the device it ran on, exit 0 — and on any failure a
+traceback and a non-zero exit with no result, never a record that
+could be read as a measurement."""
 
 import json
 import os
 import subprocess
 import sys
-import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "bench.py")
 
 
 def _run(env_extra, timeout):
-    # fixed minimal env: an ambient BENCH_* leak (e.g. BENCH_WORKER=1
-    # or a short BENCH_DEADLINE) would silently change which protocol
-    # path runs — same env-poisoning class the RSS test scrubs for
+    # fixed minimal env: an ambient BENCH_* leak would silently change
+    # what runs — same env-poisoning class the RSS test scrubs for
     env = {"PATH": os.environ.get("PATH", "/usr/bin:/bin"),
            "HOME": os.environ.get("HOME", "/root")}
     env.update(env_extra)
@@ -24,11 +23,12 @@ def _run(env_extra, timeout):
                           timeout=timeout)
 
 
-def test_bench_success_emits_one_json_line():
+def test_bench_success_emits_one_json_line(tmp_path):
     r = _run({"BENCH_PLATFORM": "cpu", "BENCH_ROWS": "4000",
               "BENCH_VALID": "1000", "BENCH_ITERS": "2",
               "BENCH_AUC_ITERS": "3", "BENCH_LEAVES": "7",
-              "BENCH_BINS": "15", "BENCH_DEADLINE": "700"},
+              "BENCH_BINS": "15",
+              "JAX_COMPILATION_CACHE_DIR": str(tmp_path)},
              timeout=900)
     assert r.returncode == 0, r.stderr[-1500:]
     lines = [ln for ln in r.stdout.strip().splitlines() if ln]
@@ -37,7 +37,11 @@ def test_bench_success_emits_one_json_line():
     for key in ("metric", "value", "unit", "vs_baseline"):
         assert key in rec, rec
     assert rec["value"] is not None and rec["value"] > 0
-    assert "error" not in rec
+    assert "error" not in rec and "last_measured" not in rec
+    # every result names the device it ran on
+    assert rec["platform"] == "cpu" and rec["device_kind"]
+    assert rec["device_count"] >= 1
+    assert rec["compile_cache_dir"] == str(tmp_path)
     # the embedded run-telemetry block (docs/OBSERVABILITY.md): phase
     # wall times, jit recompile count, HBM gauges (nulls on CPU)
     telem = rec["telemetry"]
@@ -49,48 +53,29 @@ def test_bench_success_emits_one_json_line():
     assert "bytes_in_use" in telem["hbm"]
 
 
-def test_probe_budget_capped_under_hostile_settings():
-    """The r04 regression class: probe retries must never outlive the
-    deadline. Even with an absurd retry budget (100 probes x 1000 s
-    timeouts) against a backend that always fails init, the probe loop
-    stops at its BENCH_DEADLINE/2 cutoff and the supervisor emits the
-    one failure line inside the deadline."""
-    t0 = time.time()
-    r = _run({"BENCH_PLATFORM": "bogus_backend",  # probe always fails
-              "BENCH_ROWS": "4000",
-              "BENCH_PROBE_RETRIES": "100",
-              "BENCH_PROBE_TIMEOUT": "1000",
-              "BENCH_PROBE_BACKOFF": "1",
-              "BENCH_DEADLINE": "90"},
+def _assert_loud_failure(r):
+    assert r.returncode != 0
+    assert "Traceback" in r.stderr
+    for ln in r.stdout.splitlines():
+        assert not ln.lstrip().startswith("{"), r.stdout
+    assert "value" not in r.stdout and "last_measured" not in r.stdout
+
+
+def test_bogus_platform_fails_loudly():
+    """A backend that cannot initialize is a traceback and a non-zero
+    exit — no probe loop, no supervisor, no failure record."""
+    _assert_loud_failure(_run({"BENCH_PLATFORM": "bogus_backend",
+                               "BENCH_ROWS": "4000"}, timeout=200))
+
+
+def test_no_tpu_without_explicit_cpu_is_an_error():
+    """JAX held to the CPU by the environment is not the explicit
+    BENCH_PLATFORM=cpu smoke: bench.py must refuse to time the host
+    and call it the benchmark."""
+    r = _run({"JAX_PLATFORMS": "cpu", "BENCH_ROWS": "4000"},
              timeout=200)
-    wall = time.time() - t0
-    assert r.returncode == 0, r.stderr[-1500:]
-    assert wall < 90, f"probe loop outlived BENCH_DEADLINE ({wall:.0f}s)"
-    lines = [ln for ln in r.stdout.strip().splitlines() if ln]
-    assert len(lines) == 1, r.stdout
-    rec = json.loads(lines[0])
-    assert rec["value"] is None and "error" in rec
-
-
-def test_bench_failure_emits_one_json_line_within_deadline():
-    """A dead backend must still produce the one-line record, inside
-    BENCH_DEADLINE, with value null and the error recorded. Forced
-    deterministically by giving the probe a zero retry budget."""
-    t0 = time.time()
-    r = _run({"BENCH_PLATFORM": "cpu", "BENCH_ROWS": "4000",
-              "BENCH_PROBE_RETRIES": "0", "BENCH_DEADLINE": "120"},
-             timeout=300)
-    wall = time.time() - t0
-    assert r.returncode == 0, r.stderr[-1500:]
-    assert wall < 120, f"exceeded BENCH_DEADLINE ({wall:.0f}s)"
-    lines = [ln for ln in r.stdout.strip().splitlines() if ln]
-    assert len(lines) == 1, r.stdout
-    rec = json.loads(lines[0])
-    assert rec["value"] is None
-    assert rec["vs_baseline"] is None
-    assert "error" in rec
-    assert "last_measured" in rec and \
-        rec["last_measured"]["value"] is not None
+    _assert_loud_failure(r)
+    assert "measures the TPU" in r.stderr
 
 
 # ---------------------------------------------------------------------

@@ -24,10 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - jax>=0.8
-    from jax import shard_map
+from jax import shard_map
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.parallel import comms
@@ -53,7 +50,7 @@ def _per_rank(fn, *arrays):
     axis = mesh.axis_names[0]
     sharded = shard_map(lambda *a: fn(axis, *a), mesh=mesh,
                         in_specs=tuple(P(axis) for _ in arrays),
-                        out_specs=P(axis), check_rep=False)
+                        out_specs=P(axis), check_vma=False)
     return np.asarray(jax.jit(sharded)(*arrays))
 
 
@@ -173,7 +170,7 @@ def test_exchange_wire_really_is_int8(monkeypatch):
 
         return comms.collective_payloads(
             shard_map(body, mesh=mesh, in_specs=P(axis),
-                      out_specs=P(axis), check_rep=False), x)
+                      out_specs=P(axis), check_vma=False), x)
 
     max_f32 = max(r["bytes"] for r in trace("f32"))
     recs8 = trace("int8")
@@ -217,7 +214,7 @@ def test_jaxpr_accounting_reproduces_r04_shape():
     sh = shard_map(fn, mesh=mesh,
                    in_specs=(P(None, axis), P(axis), P(axis), P(axis),
                              P(), P(), P()),
-                   out_specs=(P(), P(axis)), check_rep=False)
+                   out_specs=(P(), P(axis)), check_vma=False)
     recs = comms.collective_payloads(
         sh, jnp.zeros((fw, n), jnp.uint8), jnp.zeros((n,), jnp.float32),
         jnp.zeros((n,), jnp.float32), jnp.ones((n,), jnp.float32),
@@ -338,7 +335,7 @@ def test_grower_output_rank_identical_under_int8(grower):
         fn, mesh=mesh,
         in_specs=(P(None, axis), P(axis), P(axis), P(axis), P(), P(),
                   P()),
-        out_specs=(P(axis),) * 4, check_rep=False))
+        out_specs=(P(axis),) * 4, check_vma=False))
     nl, lv, sf, tb = sh(
         jnp.asarray(bins), jnp.asarray(0.5 - yv),
         jnp.full((n,), 0.25, jnp.float32), jnp.ones((n,), jnp.float32),

@@ -8,10 +8,9 @@ This is the full multi-host path: coordinator wiring
 (parallel/distributed.py), bin-mapper sync + per-process row shards
 (parallel/spmd.py), and global-array assembly for the shard_map
 learner (models/gbdt.py). The data-parallel learner dispatches jitted
-collectives across processes, which jaxlib's CPU backend refuses
-("Multiprocess computations aren't implemented on the CPU backend") —
-hence the capability gate; the host-transport chaos tests
-(test_distributed_resilience.py) cover the CPU-runnable distributed
+collectives across processes, which the installed jaxlib 0.9.0 runs on
+the CPU backend over gloo; the host-transport chaos tests
+(test_distributed_resilience.py) cover the rest of the distributed
 surface.
 """
 
@@ -22,14 +21,12 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from _mp_utils import (TESTS_DIR, drain_all, free_port,
-                       requires_multiprocess_computations, spawn_worker,
+from _mp_utils import (TESTS_DIR, drain_all, free_port, spawn_worker,
                        worker_base_env)
 
 pytestmark = pytest.mark.mp
 
 
-@requires_multiprocess_computations
 @pytest.mark.timeout(600)
 def test_two_process_data_parallel_matches_single_process(tmp_path):
     port = free_port()

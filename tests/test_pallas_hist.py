@@ -81,19 +81,19 @@ def test_float_parity_u16_wide_bins():
     np.testing.assert_allclose(got, ref, **FLOAT_TOL)
 
 
-def test_wide_bins_shrinks_feature_pack():
-    """B in the thousands (bundled EFB bin positions): the tile plan
-    halves the feature pack so the VMEM one-hot block stays bounded;
-    results must be unchanged."""
-    from lightgbm_tpu.ops.pallas_hist import _tile_plan
-    fp, rt = _tile_plan(2048)
-    assert fp < 8 and rt >= 128 and 128 * fp * 2048 * 4 <= 4 * 2 ** 20
-    # the budget holds at every realistic padded width, including the
-    # fp==1 regime where only the row tile is left to shrink
-    for bp in (128, 256, 1024, 4096, 16384, 131072):
-        fp_b, rt_b = _tile_plan(bp)
-        assert rt_b * fp_b * bp * 4 <= 4 * 2 ** 20, (bp, fp_b, rt_b)
-        assert rt_b >= 8 and rt_b & (rt_b - 1) == 0
+def test_wide_bins_shrink_row_tile():
+    """B in the thousands (bundled EFB bin positions): the row tile
+    shrinks so one feature's VMEM one-hot block stays bounded; results
+    must be unchanged."""
+    from lightgbm_tpu.ops.pallas_hist import _row_tile
+    assert _row_tile(256) == 1024 and _row_tile(2048) == 512
+    # the budget holds at every padded width down to the 128-lane
+    # floor of the row tile (reached at bp = 8192)
+    for bp in (128, 256, 1024, 4096, 8192):
+        rt = _row_tile(bp)
+        assert rt * bp * 4 <= 4 * 2 ** 20, (bp, rt)
+        assert rt >= 128 and rt & (rt - 1) == 0
+    assert _row_tile(131072) == 128
     rs = np.random.RandomState(13)
     S, F, B = 900, 3, 1500
     rows = rs.randint(0, B, (S, F)).astype(np.uint16)
@@ -271,16 +271,19 @@ def test_resolve_hist_method_matrix(monkeypatch):
     monkeypatch.setenv("LIGHTGBM_TPU_AUTO_PALLAS", "1")
     assert resolve_hist_method("auto", "tpu", True) == "pallas"
     assert resolve_hist_method("auto", "cpu", True) == "scatter"
-    # unavailable pallas: auto and the explicit request both fall back
+    # unavailable pallas: auto falls back, an explicit request that
+    # cannot be honoured raises
     assert resolve_hist_method("auto", "tpu", False) == "mxu"
-    assert resolve_hist_method("pallas", "tpu", False) == "mxu"
-    assert resolve_hist_method("pallas", "cpu", False) == "scatter"
+    for backend in ("tpu", "cpu"):
+        with pytest.raises(RuntimeError, match="hist_method='pallas'"):
+            resolve_hist_method("pallas", backend, False)
 
 
 def test_kill_switch_disables_pallas(monkeypatch):
     monkeypatch.setenv("LIGHTGBM_TPU_DISABLE_PALLAS", "1")
     assert not pallas_available()
-    assert resolve_hist_method("pallas", "cpu") == "scatter"
+    with pytest.raises(RuntimeError, match="DISABLE_PALLAS"):
+        resolve_hist_method("pallas", "cpu")
     monkeypatch.delenv("LIGHTGBM_TPU_DISABLE_PALLAS")
     assert pallas_available()
 

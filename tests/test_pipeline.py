@@ -414,7 +414,7 @@ def test_daemon_maps_shed_to_typed_reply(binary_model):
             @staticmethod
             def result():
                 raise SheddingError("request shed under load: test")
-        state.batcher.submit = lambda rows: _ShedFut()
+        state.batcher.submit = lambda rows, trace=None: _ShedFut()
         r = handle_request({"rows": X[:2].tolist()}, state)
         assert r.get("shed") and r.get("overloaded") and "error" in r
         assert state.stats()["shed_replies"] == 1

@@ -63,8 +63,8 @@ def test_route_concentrate_randomized_large():
 def test_route_pair_kernel_matches_xla_route():
     """The Pallas pair kernel (ops/partition_kernel.py route_pair) in
     interpret mode against the XLA route — the oracle relationship the
-    module docstring promises. (On-TPU the kernel is currently slower
-    than the in-situ sort and unused; see benchmarks/PROFILE.md.)"""
+    module docstring promises. (On the v5e the kernel compiles and
+    matches — chip_smoke.py --kernels, PR 21; no config reaches it.)"""
     from lightgbm_tpu.ops.partition_kernel import (route_pair,
                                                    stack_cols,
                                                    unstack_cols)

@@ -449,7 +449,7 @@ def test_handle_request_overload_maps_to_error(binary_model):
     bst, X = binary_model
     state, _ = _make_state(bst)
     try:
-        def full(_rows):
+        def full(_rows, trace=None):
             raise QueueFullError("serve queue full: test")
         state.batcher.submit = full
         r = handle_request({"rows": X[:2].tolist()}, state)

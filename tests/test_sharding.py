@@ -37,10 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - jax>=0.8
-    from jax import shard_map
+from jax import shard_map
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.basic import LightGBMError
@@ -121,7 +118,7 @@ def test_f32_reduce_scatter_chunk_is_psum_slice_bitwise():
 
     chunks = np.asarray(jax.jit(shard_map(
         body, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-        check_rep=False))(jnp.asarray(x)))
+        check_vma=False))(jnp.asarray(x)))
     ref = x.sum(axis=0)                       # [16, 9, 2]
     got = chunks.reshape(16, 9, 2)            # 8 ranks x 2-row chunks
     assert np.array_equal(got, ref)
@@ -147,7 +144,7 @@ def test_int_reduce_scatter_close_and_ef_resumes(mode):
 
     c1, c2 = jax.jit(shard_map(
         body, mesh=mesh, in_specs=P(axis),
-        out_specs=(P(axis), P(axis)), check_rep=False))(jnp.asarray(x))
+        out_specs=(P(axis), P(axis)), check_vma=False))(jnp.asarray(x))
     ref = x.sum(axis=0)
     got1 = np.asarray(c1).reshape(16, 9, 2)
     got2 = np.asarray(c2).reshape(16, 9, 2)

@@ -145,7 +145,7 @@ def test_every_hot_path_lax_loop_is_jit_reachable():
     ops/ to the full rule scope: zero non-baselined TPL001s."""
     res = _cached_lint(("TPL001",))
     assert not res.findings, (
-        "eager-dispatch risk (PROFILE.md 530 ms/iter class):\n  "
+        "eager-dispatch risk (one device launch per loop-body op):\n  "
         + "\n  ".join(f"{f.relpath}:{f.lineno}: {f.fid}"
                       for f in res.findings))
 
