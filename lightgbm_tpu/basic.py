@@ -573,7 +573,7 @@ class Dataset:
         from .utils.timer import timed
         tl.depth = 1
         try:
-            with timed("dataset/construct"):
+            with timed("dataset/construct", job=True):
                 return self._construct_impl()
         finally:
             tl.depth = 0
@@ -598,83 +598,85 @@ class Dataset:
         if chunk_src is not None:
             return self._construct_streaming(cfg, chunk_src, label,
                                              weight, group)
-        if isinstance(data, (str, Path)):
-            if cfg.two_round and self.reference is None:
-                cat_set = set()
-                cat_ok = True
-                for src in (self.categorical_feature,
-                            cfg.categorical_feature):
-                    if src in ("auto", "", None):
-                        continue
-                    if isinstance(src, str):
-                        src = [c for c in src.split(",") if c]
-                    if isinstance(src, (list, tuple)):
-                        try:
-                            cat_set |= {int(c) for c in src}
+        from .utils.timer import timed
+        with timed("dataset/construct/load", job=True):
+            if isinstance(data, (str, Path)):
+                if cfg.two_round and self.reference is None:
+                    cat_set = set()
+                    cat_ok = True
+                    for src in (self.categorical_feature,
+                                cfg.categorical_feature):
+                        if src in ("auto", "", None):
                             continue
-                        except (TypeError, ValueError):
-                            pass
-                    # name-based spec needs the parsed header; the
-                    # eager loader resolves it
-                    cat_ok = False
-                out = _two_round_load(str(data), cfg, cat_set,
-                                      feature_name) if cat_ok else None
-                if out is not None:
-                    return self._finish_two_round(cfg, out, label,
-                                                  weight, group,
-                                                  cat_set)
-            X, y, w, q = _load_text_file(str(data), cfg)
-            if label is None:
-                label = y
-            if weight is None and w is not None:
-                weight = w
-            if group is None and q is not None:
-                group = q
-        else:
-            try:
-                import pandas as pd
-                is_pandas = isinstance(data, pd.DataFrame)
-            except ImportError:
-                is_pandas = False
-            if is_pandas:
-                X, names, cat_idx, self._pandas_categorical = _extract_pandas(
-                    data, self.categorical_feature)
-                if feature_name == "auto":
-                    feature_name = names
+                        if isinstance(src, str):
+                            src = [c for c in src.split(",") if c]
+                        if isinstance(src, (list, tuple)):
+                            try:
+                                cat_set |= {int(c) for c in src}
+                                continue
+                            except (TypeError, ValueError):
+                                pass
+                        # name-based spec needs the parsed header; the
+                        # eager loader resolves it
+                        cat_ok = False
+                    out = _two_round_load(str(data), cfg, cat_set,
+                                          feature_name) if cat_ok else None
+                    if out is not None:
+                        return self._finish_two_round(cfg, out, label,
+                                                      weight, group,
+                                                      cat_set)
+                X, y, w, q = _load_text_file(str(data), cfg)
+                if label is None:
+                    label = y
+                if weight is None and w is not None:
+                    weight = w
+                if group is None and q is not None:
+                    group = q
+            else:
                 try:
                     import pandas as pd
-                    if isinstance(label, (pd.Series, pd.DataFrame)):
-                        label = label.to_numpy().ravel()
+                    is_pandas = isinstance(data, pd.DataFrame)
                 except ImportError:
-                    pass
-            elif type(data).__module__.split(".")[0] == "pyarrow":
-                # Arrow ingest (the C-data-interface path of the
-                # reference, include/LightGBM/arrow.h): Tables /
-                # RecordBatches column-by-column, chunked arrays
-                # concatenated; per-column to_numpy is zero-copy for
-                # non-null numeric chunks
-                X, names = _extract_arrow(data)
-                if feature_name == "auto" and names:
-                    feature_name = names
-            elif hasattr(data, "tocsr") or hasattr(data, "toarray"):
-                X = np.asarray(data.todense(), dtype=np.float64)
-            elif isinstance(data, np.ndarray):
-                # float32 is kept WITHOUT a whole-matrix float64 copy:
-                # every consumer (find_bin, bin_values, _raw_numeric)
-                # casts per column, so upcasting here would only
-                # double peak host RSS — at Allstate-bench scale
-                # (2M x 4228) that is the difference between ~44 GB
-                # and OOM. Mirrors the reference accepting float32
-                # buffers (C_API_DTYPE_FLOAT32, c_api.h).
-                X = data if data.dtype == np.float32 \
-                    else np.asarray(data, dtype=np.float64)
-                if X.ndim == 1:
-                    X = X[:, None]
-            elif isinstance(data, (list, tuple)):
-                X = np.asarray(data, dtype=np.float64)
-            else:
-                raise LightGBMError(
-                    f"Cannot construct Dataset from {type(data)}")
+                    is_pandas = False
+                if is_pandas:
+                    X, names, cat_idx, self._pandas_categorical = \
+                        _extract_pandas(data, self.categorical_feature)
+                    if feature_name == "auto":
+                        feature_name = names
+                    try:
+                        import pandas as pd
+                        if isinstance(label, (pd.Series, pd.DataFrame)):
+                            label = label.to_numpy().ravel()
+                    except ImportError:
+                        pass
+                elif type(data).__module__.split(".")[0] == "pyarrow":
+                    # Arrow ingest (the C-data-interface path of the
+                    # reference, include/LightGBM/arrow.h): Tables /
+                    # RecordBatches column-by-column, chunked arrays
+                    # concatenated; per-column to_numpy is zero-copy for
+                    # non-null numeric chunks
+                    X, names = _extract_arrow(data)
+                    if feature_name == "auto" and names:
+                        feature_name = names
+                elif hasattr(data, "tocsr") or hasattr(data, "toarray"):
+                    X = np.asarray(data.todense(), dtype=np.float64)
+                elif isinstance(data, np.ndarray):
+                    # float32 is kept WITHOUT a whole-matrix float64 copy:
+                    # every consumer (find_bin, bin_values, _raw_numeric)
+                    # casts per column, so upcasting here would only
+                    # double peak host RSS — at Allstate-bench scale
+                    # (2M x 4228) that is the difference between ~44 GB
+                    # and OOM. Mirrors the reference accepting float32
+                    # buffers (C_API_DTYPE_FLOAT32, c_api.h).
+                    X = data if data.dtype == np.float32 \
+                        else np.asarray(data, dtype=np.float64)
+                    if X.ndim == 1:
+                        X = X[:, None]
+                elif isinstance(data, (list, tuple)):
+                    X = np.asarray(data, dtype=np.float64)
+                else:
+                    raise LightGBMError(
+                        f"Cannot construct Dataset from {type(data)}")
 
         if label is None:
             raise LightGBMError("Label should not be None")
@@ -708,33 +710,19 @@ class Dataset:
             self._feature_names = ref._feature_names
             full_mappers = ref._full_mappers
         else:
-            max_bin = cfg.max_bin
-            sample_cnt = min(cfg.bin_construct_sample_cnt, n)
-            if sample_cnt < n:
-                rng = np.random.RandomState(cfg.data_random_seed)
-                sample_rows = rng.choice(n, size=sample_cnt, replace=False)
-            else:
-                sample_rows = slice(None)
-            full_mappers = []
-            for j in range(F):
-                mb = max_bin
-                if cfg.max_bin_by_feature and j < len(cfg.max_bin_by_feature):
-                    mb = cfg.max_bin_by_feature[j]
-                m = find_bin(
-                    X[sample_rows, j], mb,
-                    min_data_in_bin=cfg.min_data_in_bin,
-                    bin_type=(BinType.CATEGORICAL if j in self._cat_idx
-                              else BinType.NUMERICAL),
-                    use_missing=cfg.use_missing,
-                    zero_as_missing=cfg.zero_as_missing)
-                full_mappers.append(m)
+            with timed("dataset/construct/find_bins", job=True,
+                       attrs={"rows": int(n), "features": int(F)}):
+                full_mappers = self._find_bins(cfg, X)
             used = [j for j, m in enumerate(full_mappers) if not m.is_trivial]
             self._used_features = np.asarray(used, dtype=np.int32)
             self.mappers = [full_mappers[j] for j in used]
         self._full_mappers = full_mappers
 
         from .ops.binning import bin_matrix
-        self._bins = bin_matrix(X, self._used_features, self.mappers)
+        with timed("dataset/construct/bin_rows", job=True,
+                   attrs={"rows": int(n),
+                          "features": len(self.mappers)}):
+            self._bins = bin_matrix(X, self._used_features, self.mappers)
         self._F = len(self.mappers)
         # linear trees fit on raw numerical values (the reference keeps
         # raw data when linear_tree is set — Dataset raw_data_, dataset.h).
@@ -767,6 +755,30 @@ class Dataset:
         if self.free_raw_data:
             self.data = None
         return self
+
+    def _find_bins(self, cfg, X) -> list:
+        """The row sample and one ``find_bin`` per column (the eager
+        path's bin boundaries; span ``dataset/construct/find_bins``)."""
+        n, F = X.shape
+        sample_cnt = min(cfg.bin_construct_sample_cnt, n)
+        if sample_cnt < n:
+            rng = np.random.RandomState(cfg.data_random_seed)
+            sample_rows = rng.choice(n, size=sample_cnt, replace=False)
+        else:
+            sample_rows = slice(None)
+        full_mappers = []
+        for j in range(F):
+            mb = cfg.max_bin
+            if cfg.max_bin_by_feature and j < len(cfg.max_bin_by_feature):
+                mb = cfg.max_bin_by_feature[j]
+            full_mappers.append(find_bin(
+                X[sample_rows, j], mb,
+                min_data_in_bin=cfg.min_data_in_bin,
+                bin_type=(BinType.CATEGORICAL if j in self._cat_idx
+                          else BinType.NUMERICAL),
+                use_missing=cfg.use_missing,
+                zero_as_missing=cfg.zero_as_missing))
+        return full_mappers
 
     def _resolve_streaming_cats(self, cfg, src) -> set:
         """Categorical-feature resolution for chunk sources: integer

@@ -11,7 +11,8 @@ from __future__ import annotations
 import copy
 import os
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple, Union)
 
 import numpy as np
 
@@ -157,17 +158,46 @@ def train(params: Dict[str, Any], train_set: Dataset,
         num_boost_round = int(params["num_iterations"])
     params["num_iterations"] = num_boost_round
     cfg = Config.from_params(params)
+    # host spans (utils/timer.py, docs/OBSERVABILITY.md "Tracing"):
+    # train/job roots the call's trace; train/init is everything before
+    # the first round (Booster and engine init: bins to the device,
+    # packing, init score; callbacks and captures set up). Job-level,
+    # so recorded with telemetry off: a clock pair and an append each.
     with scoped_verbosity(cfg.verbosity):
-        return _train_impl(params, cfg, train_set, num_boost_round,
-                           valid_sets, valid_names, feval, init_model,
-                           keep_training_booster, callbacks, fobj,
-                           resume_from)
+        job = None
+        try:
+            with timed("train/job", job=True, trace_root=True):
+                with timed("train/init", job=True):
+                    job = _train_setup(params, cfg, train_set,
+                                       num_boost_round, valid_sets,
+                                       valid_names, feval, init_model,
+                                       callbacks, fobj, resume_from)
+                return _train_rounds(params, cfg, fobj, feval, job)
+        finally:
+            # after train/job has closed, so that the recorder's last
+            # drain carries the job's own span into its stream
+            if job is not None:
+                _finish_callbacks(job.callbacks)
 
 
-def _train_impl(params: Dict[str, Any], cfg: Config, train_set: Dataset,
-                num_boost_round: int, valid_sets, valid_names, feval,
-                init_model, keep_training_booster, callbacks,
-                fobj, resume_from=None) -> Booster:
+class _TrainJob(NamedTuple):
+    """What ``_train_setup`` hands the round loop."""
+    booster: Booster
+    callbacks: List[Callable]
+    cbs_before: List[Callable]
+    cbs_after: List[Callable]
+    fault_plan: Any
+    begin_iteration: int
+    end_iteration: int
+    evaluate: bool                   # any valid set, the train set included
+    is_valid_contain_train: bool
+    env_capture: Optional[EnvCapture]
+
+
+def _train_setup(params: Dict[str, Any], cfg: Config, train_set: Dataset,
+                 num_boost_round: int, valid_sets, valid_names, feval,
+                 init_model, callbacks, fobj,
+                 resume_from=None) -> _TrainJob:
     if cfg.objective == "custom" and fobj is None:
         raise LightGBMError(
             "objective=none requires a custom objective function (fobj)")
@@ -283,61 +313,81 @@ def _train_impl(params: Dict[str, Any], cfg: Config, train_set: Dataset,
         else init_iteration
     end_iteration = max(begin_iteration,
                         init_iteration + num_boost_round)
-    evaluation_result_list: List[Tuple] = []
     # env-driven device captures (LIGHTGBM_TPU_TRACE_TO whole-run /
     # LIGHTGBM_TPU_XPROF=dir:iters=A-B window); None — and zero
     # per-iteration cost — when neither knob is set
-    env_capture = EnvCapture.from_env()
+    return _TrainJob(booster, callbacks, cbs_before, cbs_after,
+                     fault_plan, begin_iteration, end_iteration,
+                     bool(valid_sets or is_valid_contain_train),
+                     is_valid_contain_train, EnvCapture.from_env())
+
+
+def _train_rounds(params: Dict[str, Any], cfg: Config, fobj, feval,
+                  job: _TrainJob) -> Booster:
+    (booster, callbacks, cbs_before, cbs_after, fault_plan,
+     begin_iteration, end_iteration, evaluate, is_valid_contain_train,
+     env_capture) = job
+    evaluation_result_list: List[Tuple] = []
     try:
         for i in range(begin_iteration, end_iteration):
             fault_plan.maybe_kill(i)
             fault_plan.maybe_distributed_fault(i)
             if env_capture is not None:
                 env_capture.before_iteration(i)
-            if booster._engine is not None:
-                # fused-scan lookahead (docs/FUSED.md): the engine
-                # loop is the only place that knows the callback set
-                # and end_iteration, so it bounds how far one scan
-                # window may run ahead of the per-iteration cadence.
-                # valid_sets=[train_set] keeps engine.valid_sets empty
-                # (scan stays eligible) but this loop then evaluates
-                # the TRAIN score inline every metric_freq iterations
-                # — windows must end on that cadence too.
-                booster._engine._scan_horizon = _scan_lookahead(
-                    callbacks, i, end_iteration,
-                    engine_iteration=int(booster._engine.iter_),
-                    eval_every=(max(1, cfg.metric_freq)
-                                if is_valid_contain_train else None))
-            for cb in cbs_before:
-                cb(callback_mod.CallbackEnv(
-                    model=booster, params=params, iteration=i,
-                    begin_iteration=begin_iteration,
-                    end_iteration=end_iteration,
-                    evaluation_result_list=None))
-            finished = booster.update(fobj=fobj)
+            # per-round spans: one flag check each with nothing live
+            with timed("train/round"):
+                if booster._engine is not None:
+                    # fused-scan lookahead (docs/FUSED.md): the engine
+                    # loop is the only place that knows the callback
+                    # set and end_iteration, so it bounds how far one
+                    # scan window may run ahead of the per-iteration
+                    # cadence. valid_sets=[train_set] keeps
+                    # engine.valid_sets empty (scan stays eligible) but
+                    # this loop then evaluates the TRAIN score inline
+                    # every metric_freq iterations — windows must end
+                    # on that cadence too.
+                    booster._engine._scan_horizon = _scan_lookahead(
+                        callbacks, i, end_iteration,
+                        engine_iteration=int(booster._engine.iter_),
+                        eval_every=(max(1, cfg.metric_freq)
+                                    if is_valid_contain_train else None))
+                if cbs_before:
+                    with timed("callbacks/before"):
+                        for cb in cbs_before:
+                            cb(callback_mod.CallbackEnv(
+                                model=booster, params=params, iteration=i,
+                                begin_iteration=begin_iteration,
+                                end_iteration=end_iteration,
+                                evaluation_result_list=None))
+                with timed("train/update"):
+                    finished = booster.update(fobj=fobj)
 
-            evaluation_result_list = []
-            if (i + 1) % max(1, cfg.metric_freq) == 0 or \
-                    i == end_iteration - 1:
-                if valid_sets or is_valid_contain_train:
+                evaluation_result_list = []
+                if evaluate and ((i + 1) % max(1, cfg.metric_freq) == 0
+                                 or i == end_iteration - 1):
                     with timed("engine/eval"):
                         if is_valid_contain_train:
                             evaluation_result_list.extend(
                                 booster.eval_train(feval))
                         evaluation_result_list.extend(
                             booster.eval_valid(feval))
-            try:
-                for cb in cbs_after:
-                    cb(callback_mod.CallbackEnv(
-                        model=booster, params=params, iteration=i,
-                        begin_iteration=begin_iteration,
-                        end_iteration=end_iteration,
-                        evaluation_result_list=evaluation_result_list))
-            except callback_mod.EarlyStopException as es:
-                booster.best_iteration = es.best_iteration + 1
-                evaluation_result_list = es.best_score
-                # roll the model back to best_iteration for storage parity
-                break
+                try:
+                    if cbs_after:
+                        with timed("callbacks/after"):
+                            for cb in cbs_after:
+                                cb(callback_mod.CallbackEnv(
+                                    model=booster, params=params,
+                                    iteration=i,
+                                    begin_iteration=begin_iteration,
+                                    end_iteration=end_iteration,
+                                    evaluation_result_list=(
+                                        evaluation_result_list)))
+                except callback_mod.EarlyStopException as es:
+                    booster.best_iteration = es.best_iteration + 1
+                    evaluation_result_list = es.best_score
+                    # roll the model back to best_iteration for
+                    # storage parity
+                    break
             if env_capture is not None:
                 env_capture.after_iteration(i)
             if finished:
@@ -360,7 +410,6 @@ def _train_impl(params: Dict[str, Any], cfg: Config, train_set: Dataset,
         if env_capture is not None:
             # finalize capture files even when the loop raised
             env_capture.close()
-        _finish_callbacks(callbacks)
 
     if booster.best_iteration <= 0:
         booster.best_iteration = booster.current_iteration()

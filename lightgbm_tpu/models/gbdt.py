@@ -28,6 +28,7 @@ import numpy as np
 
 from ..config import Config
 from ..obs import register_jit
+from ..obs.scopes import scope
 from ..obs.trace import FUSED_SCAN_PHASE
 from ..objectives import Objective
 from ..resilience.faults import FaultPlan, is_resource_exhausted
@@ -208,21 +209,24 @@ def _fused_iter_step(ctx: _StepCtx, score, it, shrink, row_w, fmask,
     (``_get_scan_fn``) calls it per window slot — one implementation,
     every fused path."""
     obj, K = ctx.obj, ctx.K
-    g, h = obj.grad_hess(score if K > 1 else score[0], label, weight)
-    if K == 1:
-        g, h = g[None, :], h[None, :]
-    if ctx.inj_grad is not None:
-        g = jnp.where(jnp.any(it == ctx.inj_grad),
-                      jnp.float32(jnp.nan), g)
-    if ctx.inj_hess is not None:
-        h = jnp.where(jnp.any(it == ctx.inj_hess),
-                      jnp.float32(jnp.nan), h)
-    # non-finite guard, fused into this one program via the same
-    # pure-jnp helper the eager path uses: the isfinite reductions cost
-    # a single pass; the resulting flag rides back with the tree
-    # outputs and is checked one iteration late on the host (no
-    # per-iteration device sync)
-    g, h, gh_flag = _gh_flag_clamp(g, h, ctx.nf_policy)
+    # device scopes (obs/scopes.py): metadata only, so that a trace's
+    # ops can be put down to the iteration's phases
+    with scope("boost/gradients"):
+        g, h = obj.grad_hess(score if K > 1 else score[0], label, weight)
+        if K == 1:
+            g, h = g[None, :], h[None, :]
+        if ctx.inj_grad is not None:
+            g = jnp.where(jnp.any(it == ctx.inj_grad),
+                          jnp.float32(jnp.nan), g)
+        if ctx.inj_hess is not None:
+            h = jnp.where(jnp.any(it == ctx.inj_hess),
+                          jnp.float32(jnp.nan), h)
+        # non-finite guard, fused into this one program via the same
+        # pure-jnp helper the eager path uses: the isfinite reductions
+        # cost a single pass; the resulting flag rides back with the
+        # tree outputs and is checked one iteration late on the host
+        # (no per-iteration device sync)
+        g, h, gh_flag = _gh_flag_clamp(g, h, ctx.nf_policy)
     # identical key schedule to the eager path (fold_in is a pure
     # device op, so tracing it keeps streams bit-equal)
     qk_it = jax.random.fold_in(ctx.base_key, it) if ctx.quant else None
@@ -234,18 +238,21 @@ def _fused_iter_step(ctx: _StepCtx, score, it, shrink, row_w, fmask,
     for k in range(K):
         qk = jax.random.fold_in(qk_it, k) if ctx.quant else None
         nk = jax.random.fold_in(nk_it, k) if ctx.bynode else None
-        dev_tree, row_leaf = grow_tree_impl(
-            ctx.gcfg, bins_T, g[k], h[k], row_w, fmask, fnb, fnan,
-            monotone, feat_is_cat, qk, igroups, forced, None, nk,
-            bundle)
-        dev_tree, flag_k = _leaf_value_guard(dev_tree, gh_flag,
-                                             ctx.nf_policy)
-        vec, cmask = pack_tree_device(dev_tree)
-        contrib = gather_small(dev_tree.leaf_value, row_leaf)
-        # a no-growth tree is replaced by a constant at flush
-        # (AsConstantTree): contribute nothing now
-        contrib = jnp.where(dev_tree.num_leaves > 1, contrib, 0.0)
-        new_score = new_score.at[k].add(contrib * shrink)
+        with scope("boost/grow"):
+            dev_tree, row_leaf = grow_tree_impl(
+                ctx.gcfg, bins_T, g[k], h[k], row_w, fmask, fnb, fnan,
+                monotone, feat_is_cat, qk, igroups, forced, None, nk,
+                bundle)
+        with scope("boost/tree_pack"):
+            dev_tree, flag_k = _leaf_value_guard(dev_tree, gh_flag,
+                                                 ctx.nf_policy)
+            vec, cmask = pack_tree_device(dev_tree)
+        with scope("boost/score_update"):
+            contrib = gather_small(dev_tree.leaf_value, row_leaf)
+            # a no-growth tree is replaced by a constant at flush
+            # (AsConstantTree): contribute nothing now
+            contrib = jnp.where(dev_tree.num_leaves > 1, contrib, 0.0)
+            new_score = new_score.at[k].add(contrib * shrink)
         outs.append((vec, cmask, dev_tree.num_leaves))
         flags.append(flag_k)
     return new_score, outs, jnp.stack(flags)
@@ -1549,8 +1556,13 @@ class GBDTBooster:
     def _get_fused_fn(self):
         if self._fused_fn is not None:
             return self._fused_fn
-        self._fused_tree_proto()
-        ctx = self._step_ctx()
+        from ..utils.timer import timed
+        # job-level span: once an engine (and once more per OOM
+        # rebuild). The proto is an abstract evaluation of the whole
+        # grower, i.e. a second trace of it before the jit's own
+        with timed("train/build_step", job=True):
+            self._fused_tree_proto()
+            ctx = self._step_ctx()
 
         def step(score, it, shrink, row_w, fmask, bins_T, fnb, fnan,
                  label, weight, monotone, feat_is_cat, igroups, forced,
@@ -1911,12 +1923,13 @@ class GBDTBooster:
                     self._bundle_dev),
                 "fused iteration")
         self.score = new_score
-        self._push_guard_flags(it, guard_flags)
-        fold_now = it == 0 and self._fold_bias
-        for k, (vec, cmask, num_leaves) in enumerate(outs):
-            bias = float(self.init_score[k]) if fold_now else 0.0
-            self._defer_tree(vec, cmask, self._fused_proto, num_leaves,
-                             self._shrinkage, bias)
+        with timed("tree/defer"):
+            self._push_guard_flags(it, guard_flags)
+            fold_now = it == 0 and self._fold_bias
+            for k, (vec, cmask, num_leaves) in enumerate(outs):
+                bias = float(self.init_score[k]) if fold_now else 0.0
+                self._defer_tree(vec, cmask, self._fused_proto,
+                                 num_leaves, self._shrinkage, bias)
         self.iter_ += 1
         return False
 
@@ -1943,6 +1956,10 @@ class GBDTBooster:
                        custom_hess: Optional[np.ndarray] = None) -> bool:
         """One boosting iteration (TrainOneIter, gbdt.cpp:344).
         Returns True if no tree could be grown (training finished)."""
+        # phase annotations: the USE_TIMETAG points of GBDT::TrainOneIter
+        # (gbdt.cpp:221-492) — see utils/timer.py
+        from ..utils.timer import timed
+
         cfg = self.cfg
         it = self.iter_
 
@@ -1960,8 +1977,12 @@ class GBDTBooster:
 
         # non-finite guard flags from the previous (async) program,
         # checked one iteration late like the tree queue below —
-        # raises/records per nonfinite_policy (resilience/)
-        self._drain_guard_flags()
+        # raises/records per nonfinite_policy (resilience/).
+        # boosting/drain: the two reads of the PREVIOUS round's results
+        # (guard flags here, leaf counts below) — where a host that
+        # runs ahead of the device waits for it
+        with timed("boosting/drain"):
+            self._drain_guard_flags()
 
         # checkpoint-restored no-growth marker: the snapshot's final
         # iteration grew nothing, so an uninterrupted run's next
@@ -1984,7 +2005,8 @@ class GBDTBooster:
         # value) carries that across out-of-band drains — a checkpoint
         # callback draining between iterations must not eat it.
         if self._nl_async:
-            nls = [int(np.asarray(x)) for x in self._nl_async]
+            with timed("boosting/drain"):
+                nls = [int(np.asarray(x)) for x in self._nl_async]
             self._nl_async = []
             fault_recent, self._fault_recent = self._fault_recent, False
             if custom_grad is None and not fault_recent \
@@ -2013,10 +2035,6 @@ class GBDTBooster:
             drop_idx = self._dart_select_drop()
             if drop_idx:
                 self._dart_apply_drop(drop_idx)
-
-        # phase annotations: the USE_TIMETAG points of GBDT::TrainOneIter
-        # (gbdt.cpp:221-492) — see utils/timer.py
-        from ..utils.timer import timed
 
         with timed("boosting/gradients"):
             if custom_grad is not None:

@@ -31,7 +31,13 @@ no-op when disabled:
   jax-free spans (``{"event": "span"}``) across the whole
   train -> publish -> serve lifecycle, clock-skew-corrected and
   merged into Perfetto-loadable Chrome trace JSON plus named
-  critical paths by ``python -m lightgbm_tpu trace <dir>``.
+  critical paths by ``python -m lightgbm_tpu trace <dir>``. The
+  program's own sections (``utils.timer.timed``) are real spans there.
+- :mod:`~lightgbm_tpu.obs.scopes` / :mod:`~lightgbm_tpu.obs.xplane` —
+  the device side: the round's ops named by layer
+  (``jax.named_scope`` from one declared list), :func:`op_scopes` — the
+  op -> scope table read back from an entry's executable — and the
+  by-scope reading of a device trace (``trace <dir> --xplane``).
 
 See docs/OBSERVABILITY.md for the event schema and workflow.
 """
@@ -53,6 +59,7 @@ from .schemas import (ENV_VARS, EVENT_NAMES, EVENTS, FAULT_EVENT_KINDS,
                       fault_event_kinds, injectable_fault_kinds,
                       one_shot_fault_kinds, required_keys)
 from .registry import Counter, Gauge, Histogram, MetricsRegistry, registry
+from .scopes import DEVICE_SCOPES, op_scopes
 from .trace import (SPAN_EVENT_KEYS, current_context, drain_span_events,
                     new_span_id, new_trace_id, record_span,
                     set_current_trace, span, span_events_snapshot)
@@ -77,4 +84,5 @@ __all__ = [
     "SPAN_EVENT_KEYS", "record_span", "span", "drain_span_events",
     "span_events_snapshot", "new_trace_id", "new_span_id",
     "current_context", "set_current_trace",
+    "DEVICE_SCOPES", "op_scopes",
 ]

@@ -38,7 +38,7 @@ import weakref
 from typing import Callable, Dict, Tuple
 
 __all__ = ["register_jit", "jit_cache_sizes", "total_recompiles",
-           "jit_declarations", "RecompileWatcher"]
+           "jit_declarations", "live_entries", "RecompileWatcher"]
 
 _lock = threading.Lock()
 # (name, seq) -> weakref to the jitted callable; weak so per-booster
@@ -79,7 +79,11 @@ def register_jit(name: str, fn: Callable,
                 if prev is not None else max_signatures
     if not hasattr(fn, "_cache_size"):
         return fn
-    from .cost import CostTracked, cost_wrap_enabled
+    from .cost import (CostTracked, cost_wrap_enabled,
+                       install_compile_listeners)
+    # compile-stage spans and the persistent cache's hit / miss
+    # counters (obs/cost.py): one listener pair a process
+    install_compile_listeners()
     with _lock:
         for (tracked_name, _), r in _tracked.items():
             if tracked_name != name:
@@ -119,6 +123,16 @@ def jit_cache_sizes() -> Dict[Tuple[str, int], int]:
             for key in dead:
                 _tracked.pop(key, None)
     return out
+
+
+def live_entries(name: str) -> list:
+    """The live callables registered under ``name``, oldest first (a
+    rebuilt fused step registers anew; ``obs.op_scopes`` reads the
+    newest that has run)."""
+    with _lock:
+        items = sorted((seq, ref) for (n, seq), ref in _tracked.items()
+                       if n == name)
+    return [fn for fn in (ref() for _, ref in items) if fn is not None]
 
 
 def total_recompiles() -> int:

@@ -247,6 +247,15 @@ METRICS = {
         "kind": "gauge", "labels": ("entry",),
         "doc": "cost-model bytes accessed of the newest compiled "
                "program"},
+    "compile_cache_hits": {
+        "kind": "counter", "labels": (),
+        "doc": "compiles the persistent compile cache answered "
+               "(jax.monitoring /jax/compilation_cache/cache_hits)"},
+    "compile_cache_misses": {
+        "kind": "counter", "labels": (),
+        "doc": "compiles the persistent compile cache could not "
+               "answer, so the backend compiled "
+               "(/jax/compilation_cache/cache_misses)"},
     # serve daemon (serve/daemon.py)
     "serve_swaps": {
         "kind": "counter", "labels": (),

@@ -1,0 +1,380 @@
+"""Device scopes: the boosting round's ops named by layer.
+
+A TPU trace names an ``XLA Ops`` event by its HLO text without
+metadata (``%fusion.211 = f32[17,64,64,2]... fusion(...)``): the
+compiler's numbering, new with every compile and silent about the
+layer. The layer is in the compiled module's ``op_name`` metadata,
+which carries every :func:`jax.named_scope` the op was traced under —
+and only the program can reach that. So:
+
+- the program names its layers with :func:`scope` / :func:`scoped`
+  (metadata only: the optimized HLO is the same program), from ONE
+  list, :data:`DEVICE_SCOPES`;
+- :func:`op_scopes` reads the table ``{"fusion.211": "grow/hist/build",
+  "copy.1297": "grow/partition/payload", ...}`` back from the
+  executable a registered entry point last ran; ``python -m
+  lightgbm_tpu trace <dir> --xplane <trace-dir>`` (obs/xplane.py) lays
+  it over a device trace.
+
+The hazard the list guards against: JAX's persistent compile cache
+strips debug info from its key, so a warm cache hands back the
+executable of whoever wrote the entry, with the WRITER's metadata —
+no scopes (an entry from before this module) or old ones (after a
+rename). :func:`op_scopes` therefore refuses (``None`` and one log
+line, never a wrong table) an executable that carries no scope or one
+this list does not declare.
+"""
+
+from __future__ import annotations
+
+import functools
+import re
+from typing import Callable, Dict, Optional, Sequence, Tuple
+
+__all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
+           "scoped", "scope_of_op_name", "scopes_from_hlo_text",
+           "op_scopes", "write_op_scopes"]
+
+#: every device scope the program opens, innermost wins. ``boost/*``:
+#: the phases of one fused iteration (models/gbdt.py
+#: ``_fused_iter_step``). ``grow/*``: the grower's layers (ops/grow.py,
+#: ops/histogram.py, ops/split.py) — the partition's parts (the
+#: per-chunk go-left decision and counts, the (side, position) key sort,
+#: the row gathers and word writes that apply it, the (g, h) payload's
+#: slices and writes), histogram build and sibling
+#: subtraction, the split scan, and ``grow/fixed``: what a split costs
+#: whatever its rows (tree and leaf bookkeeping, masks, bounds).
+DEVICE_SCOPES: Tuple[str, ...] = (
+    "boost/gradients",
+    "boost/grow",
+    "boost/score_update",
+    "boost/tree_pack",
+    "grow/partition/route",
+    "grow/partition/key_sort",
+    "grow/partition/gather",
+    "grow/partition/payload",
+    "grow/hist/build",
+    "grow/hist/subtract",
+    "grow/split_scan",
+    "grow/fixed",
+)
+
+#: the registered entry points whose programs carry these scopes (a
+#: program-owned capture writes a table for each that has run)
+SCOPED_ENTRIES: Tuple[str, ...] = ("gbdt/fused_iter", "gbdt/fused_scan",
+                                   "ops/grow_tree", "parallel/dp_grow")
+
+_SCOPE_SEGS = tuple(tuple(s.split("/")) for s in DEVICE_SCOPES)
+_ROOTS = frozenset(segs[0] for segs in _SCOPE_SEGS)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a declared scope. Trace-time only
+    (never in a round's dispatch path)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"device scope {name!r} is not declared in "
+                         "obs/scopes.py DEVICE_SCOPES")
+    import jax
+    return jax.named_scope(name)
+
+
+def scoped(name: str) -> Callable:
+    """Decorator form of :func:`scope` for whole traced functions."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
+def scope_of_op_name(op_name: str) -> Tuple[Optional[str], bool]:
+    """``(innermost declared scope or None, every scope declared?)``
+    of one ``op_name`` path (``jit(step)/boost/grow/while/body/grow/
+    partition/key_sort/sort``). A scope starts at a segment that is a
+    declared root (``boost``, ``grow``); what follows must spell a
+    declared scope, or the path carries an undeclared one."""
+    segs = op_name.split("/")
+    found, ok, i = None, True, 0
+    while i < len(segs):
+        if segs[i] not in _ROOTS:
+            i += 1
+            continue
+        match = None
+        for cand in _SCOPE_SEGS:
+            if tuple(segs[i:i + len(cand)]) == cand \
+                    and (match is None or len(cand) > len(match)):
+                match = cand
+        if match is None:
+            ok = False
+            i += 1
+        else:
+            found = "/".join(match)
+            i += len(match)
+    return found, ok
+
+
+class ScopeTable(dict):
+    """``{op: scope}``; ``derived`` holds the ops whose scope is not
+    their own ``op_name``'s but was derived (:func:`scopes_from_hlo_text`)."""
+    derived: frozenset = frozenset()
+
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_CALLED_RE = re.compile(
+    r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
+_INDEX_RE = re.compile(r"\bindex=(\d+)")
+#: opcodes that only move or re-view a value: without metadata of their
+#: own they belong to whatever produced the value
+_MOVERS = frozenset((
+    "copy", "copy-start", "copy-done", "bitcast", "reshape", "transpose",
+    "slice", "slice-start", "slice-done", "pad", "get-tuple-element"))
+
+
+def _balanced(text: str, at: int) -> int:
+    """Index just past the parenthesis group opening at ``text[at]``."""
+    depth = 0
+    for i in range(at, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def _parse_hlo(text: str):
+    """``(insts, roots, callers)`` of an HLO text dump:
+    ``insts[name] = (computation, opcode, [operands], called, index,
+    op_name)``, ``roots[computation] = name``, ``callers[computation] =
+    name of the instruction that calls it``."""
+    insts, roots, callers = {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        stripped = line.strip()
+        if not stripped or stripped.startswith("//"):
+            continue
+        if line[0] not in " \t":
+            # a computation's header (``%name (...) -> ... {``,
+            # ``ENTRY %name ...``) or its closing brace
+            if stripped.endswith("{"):
+                head = stripped.split()
+                name = head[1] if head[0] == "ENTRY" else head[0]
+                comp = name.lstrip("%")
+            continue
+        is_root = stripped.startswith("ROOT ")
+        body = stripped[5:] if is_root else stripped
+        name, sep, rest = body.partition(" = ")
+        if not sep:
+            continue
+        name = name.strip().lstrip("%")
+        # skip the result type: a tuple's is a parenthesis group
+        at = _balanced(rest, 0) if rest.startswith("(") \
+            else rest.find(" ")
+        if at < 0:
+            continue
+        tail = rest[at:].lstrip()
+        paren = tail.find("(")
+        if paren < 0:
+            continue
+        opcode = tail[:paren]
+        close = _balanced(tail, paren)
+        operands = _OPERAND_RE.findall(tail[paren:close])
+        attrs = tail[close:]
+        om = _OP_NAME_RE.search(attrs)
+        im = _INDEX_RE.search(attrs)
+        called = _CALLED_RE.findall(attrs)
+        insts[name] = (comp, opcode, operands, called,
+                       int(im.group(1)) if im else None,
+                       om.group(1) if om else None)
+        if is_root:
+            roots[comp] = name
+        for c in called:
+            callers[c] = name
+    return insts, roots, callers
+
+
+def scopes_from_hlo_text(text: str, derive: bool = True
+                         ) -> Optional[ScopeTable]:
+    """The op -> scope table of one optimized HLO module's text.
+
+    Direct: every instruction (of every computation, fused ones
+    included) whose own ``op_name`` lies under a declared scope.
+    ``None`` when no op carries a scope, or when one carries an
+    undeclared scope.
+
+    Derived (``derive``; listed in the table's ``derived``): the
+    compiler leaves what it inserts itself without metadata — layout
+    copies, async copy pairs, fusions whose root lost it — and on the
+    chip that is where most of a round can be (the float32 payload's
+    relayout copy). Such an op takes, in this order: a fusion, the scope
+    of its fused computation's root, else the commonest scope inside it;
+    an op that only moves a value (``copy``, ``copy-start/done``,
+    ``bitcast``, ``slice``, ...), the scope of what produced the value,
+    followed through tuples and out of ``while`` loops to the body's
+    root operand; anything else, the scope of the instruction that
+    calls its computation (a loop body's op -> the loop's scope)."""
+    insts, roots, callers = _parse_hlo(text)
+    direct: Dict[str, str] = {}
+    for name, inst in insts.items():
+        if inst[5] is None:
+            continue
+        found, ok = scope_of_op_name(inst[5])
+        if not ok:
+            from ..utils.log import log_warning
+            log_warning(f"op_scopes: op {name} carries a scope "
+                        f"DEVICE_SCOPES does not declare ({inst[5]!r}): "
+                        "an executable from a compile cache another "
+                        "version wrote; no table")
+            return None
+        if found is not None:
+            direct[name] = found
+    if not direct:
+        from ..utils.log import log_warning
+        log_warning("op_scopes: the executable carries no device scope "
+                    "(a compile-cache entry from before the scopes, or "
+                    "metadata stripped); no table")
+        return None
+    table = ScopeTable(direct)
+    if not derive:
+        return table
+
+    inside: Dict[str, Dict[str, int]] = {}      # computation -> scope counts
+    for name, sc in direct.items():
+        counts = inside.setdefault(insts[name][0], {})
+        counts[sc] = counts.get(sc, 0) + 1
+
+    def produced_by(name: str, seen: set) -> Optional[str]:
+        """Scope of what produced the value ``name`` holds."""
+        for _ in range(32):
+            if name in seen or name not in insts:
+                return None
+            seen.add(name)
+            comp, opcode, operands, called, index, _ = insts[name]
+            # a tuple element's own op_name is only its loop's: the
+            # value is looked THROUGH it before that is taken
+            if name in direct and opcode != "get-tuple-element":
+                return direct[name]
+            if opcode == "fusion" and called:
+                return of_fusion(called[0])
+            if opcode == "get-tuple-element" and operands \
+                    and index is not None:
+                src = insts.get(operands[0])
+                if src is not None and src[1] == "while" and src[3]:
+                    # out of a loop: the body's root operand
+                    body = [c for c in src[3] if roots.get(c)
+                            and insts[roots[c]][1] == "tuple"]
+                    if body:
+                        root_ops = insts[roots[body[0]]][2]
+                        if index < len(root_ops):
+                            name = root_ops[index]
+                            continue
+                if src is not None and src[1] == "tuple" \
+                        and index < len(src[2]):
+                    name = src[2][index]
+                    continue
+                if src is not None and src[1] == "parameter" \
+                        and roots.get(comp) \
+                        and insts[roots[comp]][1] == "tuple" \
+                        and index < len(insts[roots[comp]][2]):
+                    # loop-carried: the previous iteration's producer
+                    name = insts[roots[comp]][2][index]
+                    continue
+                return direct.get(name)
+            if opcode in _MOVERS and operands:
+                name = operands[0]
+                continue
+            return None
+        return None
+
+    def of_fusion(comp: str) -> Optional[str]:
+        root = roots.get(comp)
+        if root in direct:
+            return direct[root]
+        counts = inside.get(comp)
+        if counts:
+            return max(sorted(counts), key=lambda sc: counts[sc])
+        return None
+
+    def of_caller(comp: Optional[str]) -> Optional[str]:
+        for _ in range(16):
+            caller = callers.get(comp)
+            if caller is None:
+                return None
+            if caller in direct:
+                return direct[caller]
+            comp = insts[caller][0]
+        return None
+
+    derived = set()
+    for name, (comp, opcode, operands, called, _, _) in insts.items():
+        if name in direct or opcode in ("parameter", "constant"):
+            continue
+        sc = None
+        if opcode == "fusion" and called:
+            sc = of_fusion(called[0])
+        elif opcode in _MOVERS:
+            sc = produced_by(name, set())
+        if sc is None:
+            sc = of_caller(comp)
+        if sc is not None:
+            table[name] = sc
+            derived.add(name)
+    table.derived = frozenset(derived)
+    return table
+
+
+def op_scopes(entry: str) -> Optional[ScopeTable]:
+    """``{op: scope}`` for the executable the registered entry point
+    ``entry`` (``"gbdt/fused_iter"``) last compiled, from the compiled
+    module's ``op_name`` metadata (and, for the ops the compiler left
+    without any, derived: the table's ``derived``). Built on request — a re-lowering at
+    the last call's avals and a compile the persistent cache answers
+    where it is on — never in a round's path. ``None`` where no live
+    entry of that name has run, where the executable cannot be reached,
+    or where it carries no scope or an undeclared one."""
+    from .jit_tracker import live_entries
+    for fn in reversed(live_entries(entry)):
+        avals = getattr(fn, "last_avals", None)
+        if avals is None:
+            continue
+        args, kwargs = avals
+        try:
+            text = fn.unwrapped.lower(*args, **kwargs).compile().as_text()
+        except Exception as e:
+            from ..utils.log import log_warning
+            log_warning(f"op_scopes: cannot reach {entry!r}'s "
+                        f"executable ({type(e).__name__}: {e})")
+            return None
+        return scopes_from_hlo_text(text)
+    return None
+
+
+def write_op_scopes(directory: str,
+                    entries: Sequence[str] = SCOPED_ENTRIES) -> Optional[str]:
+    """Write ``op_scopes.json`` (``{entry: {"ops": {op: scope},
+    "derived": [op, ...]}}``) beside a program-owned capture, for each
+    of ``entries`` that has run and has a table. Returns the path, or
+    ``None`` when none had one."""
+    import json
+    import os
+    from .jit_tracker import live_entries
+    doc = {}
+    for entry in entries:
+        if not any(getattr(fn, "last_avals", None) is not None
+                   for fn in live_entries(entry)):
+            continue
+        table = op_scopes(entry)
+        if table:
+            doc[entry] = {"ops": dict(table),
+                          "derived": sorted(table.derived)}
+    if not doc:
+        return None
+    path = os.path.join(directory, "op_scopes.json")
+    os.makedirs(directory, exist_ok=True)
+    with open(path, "w", encoding="utf-8") as fh:
+        json.dump(doc, fh, indent=0, sort_keys=True)
+    return path

@@ -28,11 +28,22 @@ Span model
   correlate to the publishing generation, and the serve protocol
   carries an optional ``trace`` field end to end.
 
+- The program's own sections are opened by ``utils.timer.timed``
+  (the one span primitive): every active ``timed`` section records a
+  REAL span here — true start, true end, parent = the enclosing
+  ``timed`` span of the same thread (:func:`begin_span` /
+  :func:`end_span` keep that stack), one ``trace_id`` per
+  ``lgb.train`` call — and, while a profiler capture is live, enters a
+  ``jax.profiler.TraceAnnotation``, which puts the same section on the
+  device trace's clock. Job-level spans (``dataset/construct/*``,
+  ``train/job``, ``train/init``, ``compile/*``: a few dozen a job, none
+  a round) are always recorded; per-round spans only while a capture,
+  ``Timer.enable()`` or the telemetry recorder is live.
+
 Cost contract: recording a span is one clock pair + one locked list
 append, sampled/aggregated per iteration or per request — NEVER per
-row, and nothing here is called from ``# tpulint: hot`` drivers (the
-per-iteration spans are derived in the telemetry recorder from
-``Timer.snapshot()`` deltas the hot path already pays for).
+row. With nothing live, a per-round ``timed`` section is one flag
+check.
 
 Threading contract (tpulint TPL008 over obs/): the span buffer is
 appended from trainer/handler/watcher threads and drained from
@@ -65,6 +76,7 @@ __all__ = ["SPAN_EVENT_KEYS", "FUSED_SCAN_PHASE", "BLOCKING_PHASES",
            "make_span", "record_span", "span", "drain_span_events",
            "span_events_snapshot", "current_context",
            "set_current_trace", "format_context",
+           "begin_span", "end_span", "open_span_context",
            "record_iteration_spans", "load_spans",
            "correct_clock_skew", "chrome_trace", "critical_paths",
            "render_critical_paths", "main"]
@@ -94,8 +106,9 @@ BLOCKING_PHASES = (FUSED_SCAN_PHASE,)
 TRACE_CTX_ENV = "LIGHTGBM_TPU_TRACE_CTX"
 
 #: span-buffer cap, same shape as obs/cost.py's event cap: a consumer
-#: that never drains must not grow memory forever (the newest spans
-#: win nothing — appends beyond the cap are dropped, drains restart it)
+#: that never drains must not grow memory forever. The OLDEST spans go
+#: first (as obs/cost.py's events do): a process nobody drains still
+#: holds its last job's spans whole
 _SPANS_CAP = 4096
 
 _spans_lock = threading.Lock()
@@ -105,6 +118,15 @@ _spans_dropped = 0
 # (trace_id, span_id) of the process-current trace; False = env not
 # parsed yet, None = parsed and absent
 _current: Any = False
+# True while the process-current trace is one a ``train/job`` root
+# minted for itself (begin_span(trace_root=True)), not one it was given
+# (the env var, set_current_trace): the next job replaces a minted
+# trace and joins a given one
+_current_minted = False
+
+# the open ``timed`` spans of each thread, innermost last:
+# [(trace_id, span_id)]. Thread-local, so no lock.
+_open = threading.local()
 
 
 def new_trace_id() -> str:
@@ -154,10 +176,64 @@ def current_context() -> Optional[Dict[str, str]]:
 def set_current_trace(trace_id: Optional[str],
                       span_id: Optional[str] = None) -> None:
     """Set (or with ``None`` clear) the process-current trace."""
-    global _current
+    global _current, _current_minted
     with _spans_lock:
         _current = None if trace_id is None \
             else (trace_id, span_id or new_span_id())
+        _current_minted = False
+
+
+def begin_span(trace_root: bool = False
+               ) -> Tuple[str, str, Optional[str]]:
+    """Open a span on this thread's stack: ``(trace_id, span_id,
+    parent_id)``. The parent is the innermost open span of the thread;
+    a span with none joins the process-current trace (the pipeline's
+    ``LIGHTGBM_TPU_TRACE_CTX``) or roots its own. ``trace_root`` marks
+    the span that IS a job (``train/job``): with no trace given to the
+    process it mints one and makes it process-current, so the job's
+    iterations and a publication after it share the trace, and the next
+    job mints its own."""
+    global _current, _current_minted
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    span_id = new_span_id()
+    if stack:
+        trace_id, parent_id = stack[-1]
+    else:
+        ctx = current_context()
+        with _spans_lock:
+            minted = _current_minted
+        if ctx is not None and not (trace_root and minted):
+            trace_id, parent_id = ctx["trace_id"], ctx["span_id"]
+        else:
+            trace_id, parent_id = new_trace_id(), None
+            if trace_root:
+                with _spans_lock:
+                    _current = (trace_id, span_id)
+                    _current_minted = True
+    stack.append((trace_id, span_id))
+    return trace_id, span_id, parent_id
+
+
+def end_span() -> None:
+    stack = getattr(_open, "stack", None)
+    if stack:
+        stack.pop()
+
+
+def open_span_context() -> Tuple[Optional[str], Optional[str]]:
+    """``(trace_id, span_id)`` of this thread's innermost open span,
+    else of the process-current trace, else ``(None, None)``: what a
+    span recorded after the fact (obs/cost.py's compile stages) hangs
+    itself under."""
+    stack = getattr(_open, "stack", None)
+    if stack:
+        return stack[-1]
+    ctx = current_context()
+    if ctx is not None:
+        return ctx["trace_id"], ctx["span_id"]
+    return None, None
 
 
 def make_span(name: str, t_start: float,
@@ -204,10 +280,10 @@ def record_span(name: str, t_start: float,
     ev = make_span(name, t_start, t_end, trace_id=trace_id,
                    span_id=span_id, parent_id=parent_id, attrs=attrs)
     with _spans_lock:
-        if len(_spans) < _SPANS_CAP:
-            _spans.append(ev)
-        else:
-            _spans_dropped += 1
+        _spans.append(ev)
+        if len(_spans) > _SPANS_CAP:
+            _spans_dropped += len(_spans) - _SPANS_CAP
+            del _spans[:len(_spans) - _SPANS_CAP]
     return ev["span_id"]
 
 
@@ -271,57 +347,64 @@ def span_events_snapshot() -> List[Dict[str, Any]]:
         return list(_spans)
 
 
+#: the job's own frame: never adopted by an iteration
+_JOB_FRAME_SPANS = ("train/job", "train/init", "train/round",
+                    "train/iteration")
+
+
 def record_iteration_spans(event: Dict[str, Any], t_start: float,
                            t_end: float) -> None:
-    """Derive the per-iteration spans from one telemetry iteration
-    event (obs/recorder.py): a ``train/iteration`` parent covering
-    [t_start, t_end] plus one ``phase/<label>`` child per Timer phase
-    delta, laid out sequentially (phase clocks are per-label
-    accumulators, not timestamps — relative placement inside the
-    iteration is synthetic, the durations are real).
+    """The telemetry recorder's span of one iteration (obs/recorder.py):
+    ``train/iteration`` over [t_start, t_end], which ADOPTS the real
+    spans the iteration's ``timed`` sections recorded — the spans that
+    started inside the interval and hang directly under a span still
+    open on this thread (the engine loop's ``train/round``, not yet
+    recorded) are re-parented to it. Nothing is laid out: every child
+    keeps its true start and duration.
 
     On fused-scan iterations the parent also carries the dispatch-gap
-    decomposition: ``host_gap_s`` = iteration wall minus the blocking
-    ``boosting/fused_scan`` phase — the host driver time the
+    decomposition: ``host_gap_s`` = iteration wall minus the interval's
+    real ``boosting/fused_scan`` spans — the host driver time the
     ``fused_scan_iters auto`` flip gate requires to be ~0 inside a
     window (an upper bound off-chip, where per-iteration programs
     execute synchronously inside the dispatch call).
 
-    Costs one clock pair + a handful of locked appends per ITERATION
-    — nothing here runs inside the hot-marked drivers."""
-    ctx = current_context()
-    if ctx is None:
-        # a bare train() run still groups its iterations in one trace
-        set_current_trace(new_trace_id())
+    Costs one clock pair + one locked pass over the pending spans per
+    ITERATION — nothing here runs inside the hot-marked drivers."""
+    stack = list(getattr(_open, "stack", None) or ())
+    if stack:
+        # under the job (the outermost open span), beside its rounds
+        trace_id, parent_id = stack[0]
+        open_ids = {sid for _, sid in stack}
+    else:
         ctx = current_context()
+        if ctx is None:
+            # a bare record_iteration() outside any train() still
+            # groups its iterations in one trace
+            set_current_trace(new_trace_id())
+            ctx = current_context()
+        trace_id, parent_id = ctx["trace_id"], ctx["span_id"]
+        open_ids = {parent_id, None}
     attrs: Dict[str, Any] = {"iteration": event.get("iteration")}
     scan = event.get("scan")
-    phases = event.get("phases") or {}
-
-    def _total(v: Dict[str, Any]) -> float:
-        # single-process deltas carry total; SPMD-aggregated carry
-        # mean (per-process) + min/max
-        return float(v.get("total", v.get("mean", 0.0)))
-
+    span_id = new_span_id()
+    blocking = 0.0
+    with _spans_lock:
+        for s in _spans:
+            if s["trace_id"] != trace_id \
+                    or not t_start <= s["mono"] <= t_end:
+                continue
+            if s["name"] in BLOCKING_PHASES:    # at whatever depth
+                blocking += s["dur"]
+            if s["parent_id"] in open_ids \
+                    and s["name"] not in _JOB_FRAME_SPANS:
+                s["parent_id"] = span_id
     if scan:
-        blocking = sum(_total(phases[lb]) for lb in BLOCKING_PHASES
-                       if lb in phases)
         attrs["scan"] = scan
         attrs["host_gap_s"] = round(
             max((t_end - t_start) - blocking, 0.0), 6)
-    parent = record_span("train/iteration", t_start, t_end,
-                         trace_id=ctx["trace_id"],
-                         parent_id=ctx["span_id"], attrs=attrs)
-    cursor = t_start
-    for label in sorted(phases):
-        dur = _total(phases[label])
-        if dur <= 0.0:
-            continue
-        record_span(f"phase/{label}", cursor, cursor + dur,
-                    trace_id=ctx["trace_id"], parent_id=parent,
-                    attrs={"count": int(phases[label]
-                                        .get("count", 0))})
-        cursor += dur
+    record_span("train/iteration", t_start, t_end, trace_id=trace_id,
+                span_id=span_id, parent_id=parent_id, attrs=attrs)
 
 
 # ---------------------------------------------------------------------
@@ -551,6 +634,7 @@ def render_critical_paths(paths: List[Dict[str, Any]]) -> str:
 
 _TRACE_HELP = """\
 usage: python -m lightgbm_tpu trace <telemetry-dir> [--out FILE]
+                                   [--xplane TRACE_DIR [--scopes FILE]]
 
 Merge every telemetry stream under the directory (x.jsonl plus the
 fleet's .rankN / .fleet suffixes, recursively), collect the
@@ -563,13 +647,60 @@ span's paired wall/monotonic timestamps, and:
   iteration -> publish -> manifest-validated swap -> first request
   served by the new model, with clock-corrected latencies.
 
+With --xplane TRACE_DIR (a profiler capture: trace_to,
+LIGHTGBM_TPU_TRACE_TO / LIGHTGBM_TPU_XPROF, or any jax.profiler session
+the program ran under) it also reads the device trace by layer: device
+self time by named scope (a `while` does not count its body twice),
+the unscoped remainder, and each idle gap over 1 ms put down to the
+innermost program span covering it. The op -> scope table is
+TRACE_DIR/op_scopes.json, which a program-owned capture writes beside
+its trace (--scopes FILE names another; obs.op_scopes(entry) builds
+one). With --xplane a directory without span events is no error.
+
 Span schema, propagation map and the Perfetto workflow:
-docs/OBSERVABILITY.md "Tracing". This command never imports jax.
+docs/OBSERVABILITY.md "Tracing". Without --xplane this command never
+imports jax.
 
 exit codes:
   0  spans merged and exported (even if no complete critical path)
-  1  no span events found, unreadable directory, or corrupt stream
+  1  no span events found, unreadable directory, corrupt stream, or no
+     readable capture under --xplane
 """
+
+
+def _take_option(argv: List[str], flag: str) -> Tuple[bool, Optional[str]]:
+    """Pop ``flag VALUE`` from argv: ``(ok, value or None)``; not ok
+    when the flag is there without its value."""
+    if flag not in argv:
+        return True, None
+    i = argv.index(flag)
+    if i + 1 >= len(argv):
+        print(f"trace: {flag} needs an argument", file=sys.stderr)
+        return False, None
+    value = argv[i + 1]
+    del argv[i:i + 2]
+    return True, value
+
+
+def _xplane_report(trace_dir: str, scopes_path: Optional[str]) -> int:
+    from . import xplane
+    try:
+        capture = xplane.load(xplane.find_xplane(trace_dir))
+    except (OSError, ValueError) as e:
+        print(f"[LightGBM-TPU] [Fatal] cannot read a capture under "
+              f"{trace_dir}: {e}", file=sys.stderr)
+        return 1
+    path = scopes_path or os.path.join(trace_dir, "op_scopes.json")
+    table = None
+    if os.path.exists(path):
+        table = xplane.load_op_scopes(path)
+    elif scopes_path:
+        print(f"[LightGBM-TPU] [Fatal] no such table: {scopes_path}",
+              file=sys.stderr)
+        return 1
+    print(xplane.render_report(xplane.report(capture, table)))
+    return 0
+
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -577,18 +708,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     if argv and argv[0] in ("-h", "--help"):
         print(_TRACE_HELP)
         return 0
-    out_path = None
-    if "--out" in argv:
-        i = argv.index("--out")
-        if i + 1 >= len(argv):
-            print("trace: --out needs a file argument",
-                  file=sys.stderr)
-            return 1
-        out_path = argv[i + 1]
-        del argv[i:i + 2]
+    ok_o, out_path = _take_option(argv, "--out")
+    ok_x, xplane_dir = _take_option(argv, "--xplane")
+    ok_s, scopes_path = _take_option(argv, "--scopes")
+    if not (ok_o and ok_x and ok_s):
+        return 1
     if len(argv) != 1:
         print("usage: python -m lightgbm_tpu trace <telemetry-dir> "
-              "[--out FILE]", file=sys.stderr)
+              "[--out FILE] [--xplane TRACE_DIR [--scopes FILE]]",
+              file=sys.stderr)
         return 1
     directory = argv[0]
     if not os.path.isdir(directory):
@@ -608,7 +736,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     if not spans:
         print(f"no span events in any *.jsonl under {directory}",
               file=sys.stderr)
-        return 1
+        return _xplane_report(xplane_dir, scopes_path) \
+            if xplane_dir else 1
     offsets = correct_clock_skew(spans)
     doc = chrome_trace(spans)
     out_path = out_path or os.path.join(directory, "trace.json")
@@ -633,6 +762,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         print("no publish spans: critical paths need a traced "
               "publish -> swap -> serve lifecycle (run the pipeline "
               "with tracing on)")
+    if xplane_dir:
+        print()
+        return _xplane_report(xplane_dir, scopes_path)
     return 0
 
 

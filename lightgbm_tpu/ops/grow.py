@@ -39,6 +39,7 @@ from .split import (SplitParams, SplitResult, constrained_output,
                     gain_at_output, leaf_gain, leaf_output)
 
 from .partition_kernel import route_concentrate
+from ..obs.scopes import scope
 
 __all__ = ["GrowConfig", "TreeArrays", "grow_tree", "route_concentrate"]
 
@@ -1931,15 +1932,16 @@ def _grow_compact_impl(cfg: GrowConfig,
         (rows past ``limit`` relative to the chunk start), accumulate
         on the MXU. Shared by the post-partition child pass and the
         pool-miss window recompute."""
-        blk_b = _local_hist_rows(bins2, pos0, CK)
-        blk_p = lax.dynamic_slice(
-            pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
-        valid = jnp.arange(CK) < jnp.clip(limit, 0, CK)
-        hp = blk_p * valid[:, None].astype(blk_p.dtype)
-        if quant:
-            return hist_from_rows_int(blk_b, hp, B, hmethod), valid
-        return hist_from_rows(blk_b, hp, B, hmethod,
-                              cfg.hist_precision), valid
+        with scope("grow/hist/build"):
+            blk_b = _local_hist_rows(bins2, pos0, CK)
+            blk_p = lax.dynamic_slice(
+                pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+            valid = jnp.arange(CK) < jnp.clip(limit, 0, CK)
+            hp = blk_p * valid[:, None].astype(blk_p.dtype)
+            if quant:
+                return hist_from_rows_int(blk_b, hp, B, hmethod), valid
+            return hist_from_rows(blk_b, hp, B, hmethod,
+                                  cfg.hist_precision), valid
 
     def part_apply(bins2, pay2, ord2, lazy_used, src, start, cnt,
                    f, t, dl, isc, cm, est_left_small, comm_ef):
@@ -2007,9 +2009,11 @@ def _grow_compact_impl(cfg: GrowConfig,
                  l_off, r_off, nlib, nib) = carry
                 off = base_off + c * CK
                 pos0 = src_base + off
-                blk_w = _bins_slice(bins2, pos0, CK)
-                blk_p = lax.dynamic_slice(
-                    pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+                with scope("grow/partition/gather"):
+                    blk_w = _bins_slice(bins2, pos0, CK)
+                with scope("grow/partition/payload"):
+                    blk_p = lax.dynamic_slice(
+                        pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
                 split_col = _extract_col(blk_w,
                                          bundle_of[f] if bundled else f)
                 gl = chunk_goleft(split_col, f, t, dl, isc, cm)
@@ -2047,8 +2051,10 @@ def _grow_compact_impl(cfg: GrowConfig,
                     # two butterfly concentrations: lefts compact to the
                     # block FRONT, rights directly to the block END (no
                     # rotate needed — the offset is part of the route).
-                    lops = route_concentrate(cols, vl, jnp.int32(0))
-                    rops = route_concentrate(cols, valid & ~gl, CK - r_c)
+                    with scope("grow/partition/gather"):
+                        lops = route_concentrate(cols, vl, jnp.int32(0))
+                        rops = route_concentrate(cols, valid & ~gl,
+                                                 CK - r_c)
                     lb = jnp.stack(lops[:NW], axis=1)
                     lp = _unpack_pay(lops[NW:NW + NPAY])
                     rb = jnp.stack(rops[:NW], axis=1)
@@ -2070,10 +2076,12 @@ def _grow_compact_impl(cfg: GrowConfig,
                     # the payload-carrying sort wins — hence the gate).
                     side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
                     key = side * CK + iota_c
-                    perm = lax.sort((key, iota_c.astype(jnp.int32)),
-                                    num_keys=1)[1]
-                    s_r = lax.rem(l_c + r_c, jnp.asarray(CK, jnp.int32))
-                    perm_r = rot(perm, s_r)
+                    with scope("grow/partition/key_sort"):
+                        perm = lax.sort((key, iota_c.astype(jnp.int32)),
+                                        num_keys=1)[1]
+                        s_r = lax.rem(l_c + r_c,
+                                      jnp.asarray(CK, jnp.int32))
+                        perm_r = rot(perm, s_r)
                     # fold the payload (and ord) into the word block so
                     # ONE row gather moves everything; the (g, h) pair
                     # is already a single u32 word on the TPU paths
@@ -2090,8 +2098,9 @@ def _grow_compact_impl(cfg: GrowConfig,
                         parts.append(blk_o[:, None])
                     blk_all = parts[0] if len(parts) == 1 \
                         else jnp.concatenate(parts, axis=1)
-                    la = jnp.take(blk_all, perm, axis=0)
-                    ra = jnp.take(blk_all, perm_r, axis=0)
+                    with scope("grow/partition/gather"):
+                        la = jnp.take(blk_all, perm, axis=0)
+                        ra = jnp.take(blk_all, perm_r, axis=0)
                     PW = 0 if pw is None else 1
                     lb, rb = la[:, :NW], ra[:, :NW]
                     if quant:
@@ -2101,8 +2110,9 @@ def _grow_compact_impl(cfg: GrowConfig,
                         lp = _unpack_pay((la[:, NW],))
                         rp = _unpack_pay((ra[:, NW],))
                     else:
-                        lp = jnp.take(blk_p, perm, axis=0)
-                        rp = jnp.take(blk_p, perm_r, axis=0)
+                        with scope("grow/partition/gather"):
+                            lp = jnp.take(blk_p, perm, axis=0)
+                            rp = jnp.take(blk_p, perm_r, axis=0)
                     if track:
                         lo = la[:, NW + PW]
                         ro = ra[:, NW + PW]
@@ -2111,7 +2121,8 @@ def _grow_compact_impl(cfg: GrowConfig,
                     # all row data by a (side, position) key
                     side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
                     key = side * CK + iota_c
-                    ops = _sort_by_key(key, cols)
+                    with scope("grow/partition/key_sort"):
+                        ops = _sort_by_key(key, cols)
                     lb = jnp.stack(ops[1:1 + NW], axis=1)
                     lp = _unpack_pay(ops[1 + NW:1 + NW + NPAY])
                     # rights [l_c, l_c+r_c) rotated to the block END
@@ -2122,13 +2133,18 @@ def _grow_compact_impl(cfg: GrowConfig,
                         ro = rot(lo, s_r)
                 # lefts [0, l_c) forward in place; rights packed
                 # backward from the window end in the other half
-                bins2 = _bins_write(bins2, src_base + l_off, lb, ml)
-                pay2 = write(pay2, src_base + l_off, lp, ml)
-                bins2 = _bins_write(bins2, o_r, rb, mr)
-                pay2 = write(pay2, o_r, rp, mr)
+                with scope("grow/partition/gather"):
+                    bins2 = _bins_write(bins2, src_base + l_off, lb, ml)
+                with scope("grow/partition/payload"):
+                    pay2 = write(pay2, src_base + l_off, lp, ml)
+                with scope("grow/partition/gather"):
+                    bins2 = _bins_write(bins2, o_r, rb, mr)
+                with scope("grow/partition/payload"):
+                    pay2 = write(pay2, o_r, rp, mr)
                 if track:
-                    ord2 = write(ord2, src_base + l_off, lo, ml)
-                    ord2 = write(ord2, o_r, ro, mr)
+                    with scope("grow/partition/gather"):
+                        ord2 = write(ord2, src_base + l_off, lo, ml)
+                        ord2 = write(ord2, o_r, ro, mr)
                 return (bins2, pay2, ord2, lazy_used,
                         l_off + l_c, r_off + r_c, nlib, nib)
 
@@ -2139,14 +2155,18 @@ def _grow_compact_impl(cfg: GrowConfig,
         # "kill the chunk serialization" item); the remainder streams in
         # K-row bodies so small leaves never pay a BK-sized op
         carry = (bins2, pay2, ord2, lazy_used, zero, zero, zero, zero)
-        if use_big:
-            nb_big = lax.div(cnt, jnp.asarray(BK, jnp.int32))
-            carry = lax.fori_loop(0, nb_big, make_body(BK, zero), carry)
-            tail_off = nb_big * BK
-        else:
-            tail_off = zero
-        carry = lax.fori_loop(0, window_chunks(cnt - tail_off),
-                              make_body(K, tail_off), carry)
+        # what of a chunk no inner scope names: the go-left decision,
+        # the counts, the loop
+        with scope("grow/partition/route"):
+            if use_big:
+                nb_big = lax.div(cnt, jnp.asarray(BK, jnp.int32))
+                carry = lax.fori_loop(0, nb_big, make_body(BK, zero),
+                                      carry)
+                tail_off = nb_big * BK
+            else:
+                tail_off = zero
+            carry = lax.fori_loop(0, window_chunks(cnt - tail_off),
+                                  make_body(K, tail_off), carry)
         (bins2, pay2, ord2, lazy_used, n_left, _,
          n_left_ib, n_ib) = carry
 
@@ -2190,16 +2210,17 @@ def _grow_compact_impl(cfg: GrowConfig,
             return hist_body
 
         carry_h = (acc0, jnp.zeros((F_orig,), dtype))
-        if use_big:
-            nh_big = lax.div(est_cnt, jnp.asarray(BK, jnp.int32))
-            carry_h = lax.fori_loop(0, nh_big, make_hist_body(BK, zero),
-                                    carry_h)
-            h_off = nh_big * BK
-        else:
-            h_off = zero
-        est_hist, est_nu = lax.fori_loop(
-            0, window_chunks(est_cnt - h_off), make_hist_body(K, h_off),
-            carry_h)
+        with scope("grow/hist/build"):
+            if use_big:
+                nh_big = lax.div(est_cnt, jnp.asarray(BK, jnp.int32))
+                carry_h = lax.fori_loop(0, nh_big,
+                                        make_hist_body(BK, zero), carry_h)
+                h_off = nh_big * BK
+            else:
+                h_off = zero
+            est_hist, est_nu = lax.fori_loop(
+                0, window_chunks(est_cnt - h_off),
+                make_hist_body(K, h_off), carry_h)
 
         # exact global in-bag child counts replace the search-time
         # hessian-ratio estimates (SplitInner update_cnt,
@@ -2227,14 +2248,15 @@ def _grow_compact_impl(cfg: GrowConfig,
 
             return body
 
-        if use_big:
-            nb = lax.div(cnt, jnp.asarray(BK, jnp.int32))
-            acc0 = lax.fori_loop(0, nb, make_body(BK, 0), acc0)
-            b_off = nb * BK
-        else:
-            b_off = jnp.asarray(0, jnp.int32)
-        return hist_psum(lax.fori_loop(0, window_chunks(cnt - b_off),
-                                       make_body(K, b_off), acc0))
+        with scope("grow/hist/build"):
+            if use_big:
+                nb = lax.div(cnt, jnp.asarray(BK, jnp.int32))
+                acc0 = lax.fori_loop(0, nb, make_body(BK, 0), acc0)
+                b_off = nb * BK
+            else:
+                b_off = jnp.asarray(0, jnp.int32)
+            return hist_psum(lax.fori_loop(
+                0, window_chunks(cnt - b_off), make_body(K, b_off), acc0))
 
     # the streamed copy of the bin matrix lives PACKED: u32 words of
     # pack_w bin columns each (u8 arrays carry a (4,1) sub-byte tiling
@@ -2585,9 +2607,10 @@ def _grow_compact_impl(cfg: GrowConfig,
         tree = _apply_split_to_tree(tree, best, leaf, R, ns, p,
                                     nl_ex, nr_ex)
 
-        other_hist = subtract_histogram(parent_hist, est_hist)
-        left_hist = jnp.where(est_left_small, est_hist, other_hist)
-        right_hist = jnp.where(est_left_small, other_hist, est_hist)
+        with scope("grow/hist/subtract"):
+            other_hist = subtract_histogram(parent_hist, est_hist)
+            left_hist = jnp.where(est_left_small, est_hist, other_hist)
+            right_hist = jnp.where(est_left_small, other_hist, est_hist)
         if pooled:
             # store the children: the left child inherits the parent's
             # slot when cached; otherwise (and for the right child) the
@@ -2619,7 +2642,9 @@ def _grow_compact_impl(cfg: GrowConfig,
             hists = hists.at[s_l].set(left_hist).at[s_r].set(right_hist)
             pool_st = (leaf2slot, slot2leaf, lru)
         else:
-            hists = hists.at[leaf].set(left_hist).at[R].set(right_hist)
+            with scope("grow/hist/subtract"):
+                hists = hists.at[leaf].set(left_hist).at[R].set(
+                    right_hist)
 
         # context for the pooled re-search paths (hist per leaf from
         # slot or window recompute)
@@ -2723,19 +2748,20 @@ def _grow_compact_impl(cfg: GrowConfig,
         pen2 = None if pen_l is None else stack2(pen_l, pen_r)
         bounds2 = None if bounds_l is None else tuple(
             stack2(a, b) for a, b in zip(bounds_l, bounds_r))
-        r2 = jax.vmap(
-            best_for,
-            in_axes=(0, 0, 0, 0,
-                     None if mask2 is None else 0,
-                     None if pen2 is None else 0,
-                     0, None,
-                     None if bounds2 is None
-                     else tuple(0 for _ in bounds2)))(
-            stack2(hist_f(left_hist), hist_f(right_hist)),
-            stack2(best.left_sum_g[leaf], best.right_sum_g[leaf]),
-            stack2(best.left_sum_h[leaf], best.right_sum_h[leaf]),
-            stack2(nl_ex, nr_ex), mask2, pen2,
-            stack2(wl_out, wr_out), new_depth, bounds2)
+        with scope("grow/split_scan"):
+            r2 = jax.vmap(
+                best_for,
+                in_axes=(0, 0, 0, 0,
+                         None if mask2 is None else 0,
+                         None if pen2 is None else 0,
+                         0, None,
+                         None if bounds2 is None
+                         else tuple(0 for _ in bounds2)))(
+                stack2(hist_f(left_hist), hist_f(right_hist)),
+                stack2(best.left_sum_g[leaf], best.right_sum_g[leaf]),
+                stack2(best.left_sum_h[leaf], best.right_sum_h[leaf]),
+                stack2(nl_ex, nr_ex), mask2, pen2,
+                stack2(wl_out, wr_out), new_depth, bounds2)
         rl = jax.tree.map(lambda a: a[0], r2)
         rr = jax.tree.map(lambda a: a[1], r2)
         best = best.store(leaf, rl, can_go_deeper)
@@ -2941,7 +2967,10 @@ def _grow_compact_impl(cfg: GrowConfig,
         return (state.num_splits < L - 1) \
             & (jnp.max(state.best.gain) > 0.0)
 
-    state = lax.while_loop(can_grow, do_split, state)
+    # everything of a split that no inner scope names is its fixed
+    # cost: tree and leaf bookkeeping, masks, bounds, the loop itself
+    with scope("grow/fixed"):
+        state = lax.while_loop(can_grow, do_split, state)
     if bundled:
         # bundle columns can't be re-routed by the predictor (the tree
         # references ORIGINAL features); merge the per-leaf windows

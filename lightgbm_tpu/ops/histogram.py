@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import scoped
+
 __all__ = ["build_histogram", "subtract_histogram", "hist_from_rows",
            "hist_from_rows_int", "PACK"]
 
@@ -177,6 +179,7 @@ def _hist_from_rows_impl(rows: jnp.ndarray, payload: jnp.ndarray,
     return h[:F, :num_bins, :]
 
 
+@scoped("grow/hist/build")
 def hist_from_rows(rows: jnp.ndarray, payload: jnp.ndarray,
                    num_bins: int, method: str = "mxu",
                    precision: str = "default") -> jnp.ndarray:
@@ -199,6 +202,7 @@ def hist_from_rows(rows: jnp.ndarray, payload: jnp.ndarray,
                                 acc, _PRECISIONS[precision])
 
 
+@scoped("grow/hist/build")
 def hist_from_rows_int(rows: jnp.ndarray, payload: jnp.ndarray,
                        num_bins: int, method: str = "mxu") -> jnp.ndarray:
     """Quantized histogram: int8 payload, exact int32 result
@@ -220,6 +224,7 @@ def _hist_scatter(bins_T: jnp.ndarray, gh: jnp.ndarray, num_bins: int,
     return hists
 
 
+@scoped("grow/hist/build")
 def build_histogram(bins_T: jnp.ndarray,
                     grad: jnp.ndarray,
                     hess: jnp.ndarray,
@@ -248,6 +253,7 @@ def build_histogram(bins_T: jnp.ndarray,
     return _hist_scatter(bins_T, gh, num_bins)
 
 
+@scoped("grow/hist/subtract")
 def subtract_histogram(parent: jnp.ndarray, child: jnp.ndarray) -> jnp.ndarray:
     """The histogram-subtraction trick: sibling = parent - child
     (serial_tree_learner.cpp:473-520)."""

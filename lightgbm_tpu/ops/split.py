@@ -18,6 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import scoped
+
 __all__ = ["SplitParams", "SplitResult", "find_best_split"]
 
 K_EPS = 1e-15
@@ -276,6 +278,7 @@ def _cat_split_eval(hist, parent_g, parent_h, parent_cnt,
     return gains_oh, gains_fwd, gains_bwd, csum_f, csum_b, aux
 
 
+@scoped("grow/split_scan")
 def find_best_split(hist: jnp.ndarray,
                     parent_g: jnp.ndarray,
                     parent_h: jnp.ndarray,
@@ -497,6 +500,7 @@ def find_best_split(hist: jnp.ndarray,
     return result
 
 
+@scoped("grow/split_scan")
 def find_best_split_bundled(hist: jnp.ndarray,
                             parent_g: jnp.ndarray,
                             parent_h: jnp.ndarray,

@@ -3,23 +3,32 @@
 The reference compiles a global ``Common::Timer`` + RAII ``FunctionTimer``
 into every hot-path phase and logs a sorted per-label wall-time table at
 process exit (/root/reference/include/LightGBM/utils/common.h:973-1057,
-instrumentation points listed in SURVEY.md §5). On TPU the device runs
-asynchronously from Python, so two complementary mechanisms are provided:
+instrumentation points listed in SURVEY.md §5). Here ``timed(label)`` is
+the ONE way the program opens a host span, and an active section does
+three things with one clock pair:
 
-- ``Timer`` / ``timed(label)``: host wall-clock aggregation per label.
-  Because dispatch is async, a label's time only reflects device work if
-  the section itself synchronizes (the train loop's per-iteration sync
-  points do). Enabled with env ``LIGHTGBM_TPU_TIMETAG=1`` or
-  ``Timer.enable()``; ``Timer.log_summary()`` prints the sorted table and
-  ``Timer.snapshot()`` returns it machine-readable (the telemetry
-  recorder diffs consecutive snapshots into per-iteration phase times).
-- inside an active ``trace_to`` capture, every timed section also enters
-  a ``jax.profiler.TraceAnnotation`` so the phases show up as named
-  spans in the tensorboard/xplane view even when host timing is off.
+- records a REAL span into ``obs/trace.py``'s buffer: true start, true
+  end, parent = the enclosing ``timed`` span, one trace id per
+  ``lgb.train`` call (docs/OBSERVABILITY.md "Tracing");
+- enters a ``jax.profiler.TraceAnnotation`` while a profiler capture is
+  live (``trace_to``, the env captures, or a session started outside
+  through the Python API), so the span is on the device trace's clock;
+- adds to the per-label ``Timer`` table when ``LIGHTGBM_TPU_TIMETAG=1``
+  or ``Timer.enable()`` (``Timer.log_summary()`` prints it sorted,
+  ``Timer.snapshot()`` returns it; the telemetry recorder diffs
+  consecutive snapshots into per-iteration phase times).
 
-When neither timing nor tracing is active, ``timed`` yields immediately:
-no jax import, no TraceAnnotation construction, no clock reads — the
-instrumented loop must cost nothing with telemetry off.
+These are HOST spans: the device runs asynchronously, so a label's time
+reflects device work only if the section itself synchronizes.
+``boosting/fused_iter`` is the enqueue of a round's program (~2 ms),
+not the round; the round's device time is the device trace's.
+
+Gating. ``timed(label, job=True)`` marks a job-level span (construct's
+parts, ``train/job``, ``train/init``: a few dozen a job, none a round):
+always recorded, a clock pair and one locked append each. Every other
+section is per-round: with no capture, no ``Timer.enable()`` and no
+telemetry recorder live, ``timed`` returns a shared null context — one
+flag check, no jax import, no clock read.
 """
 
 from __future__ import annotations
@@ -116,19 +125,53 @@ def _get_jax():
     return _jax
 
 
-@contextmanager
-def _timed_active(label: str) -> Iterator[None]:
-    jax = _get_jax()
+# obs/trace.py resolved once on first active use (obs imports nothing
+# of utils.timer at import time; the reverse import stays lazy so this
+# module still loads before the package's other layers)
+_trace_mod = None
 
-    with jax.profiler.TraceAnnotation(label):
-        if not Timer._enabled:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            Timer.add(label, time.perf_counter() - t0)
+
+def _get_trace():
+    global _trace_mod
+    if _trace_mod is None:
+        from ..obs import trace
+        _trace_mod = trace
+    return _trace_mod
+
+
+class _Span:
+    """One active ``timed`` section (see the module docstring)."""
+
+    __slots__ = ("label", "attrs", "_trace_root", "_ann", "_ids", "_t0")
+
+    def __init__(self, label: str, attrs, trace_root: bool,
+                 annotate: bool):
+        self.label = label
+        self.attrs = attrs
+        self._trace_root = trace_root
+        self._ann = _get_jax().profiler.TraceAnnotation(label) \
+            if annotate else None
+
+    def __enter__(self):
+        self._ids = _get_trace().begin_span(self._trace_root)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr = _get_trace()
+        tr.end_span()
+        if Timer._enabled:
+            Timer.add(self.label, t1 - self._t0)
+        trace_id, span_id, parent_id = self._ids
+        tr.record_span(self.label, self._t0, t1, trace_id=trace_id,
+                       span_id=span_id, parent_id=parent_id,
+                       attrs=self.attrs)
+        return False
 
 
 # resolved lazily: the jax profiler's session slot, so timed() also
@@ -160,14 +203,19 @@ def _external_trace_active() -> bool:
         return False
 
 
-def timed(label: str):
-    """Time a phase and, inside a trace capture (ours or an externally
-    started jax profiler session), annotate it. A strict no-op (shared
-    null context) when neither timing nor tracing is active."""
-    if not Timer._enabled and not _tracing \
-            and not _external_trace_active():
+def timed(label: str, job: bool = False, attrs=None,
+          trace_root: bool = False):
+    """Open the host span ``label`` (module docstring). Per-round
+    sections (the default) are a strict no-op — the shared null
+    context — unless host timing, a capture (ours or an externally
+    started jax profiler session) or the telemetry recorder is live;
+    ``job=True`` sections are always recorded. ``attrs`` (a dict) is
+    stamped onto the span; ``trace_root`` marks the span that is a job
+    of its own trace (``train/job``)."""
+    live = Timer._enabled or _tracing or _external_trace_active()
+    if not live and not job:
         return _NULL
-    return _timed_active(label)
+    return _Span(label, attrs, trace_root, annotate=live)
 
 
 @contextmanager
@@ -175,7 +223,8 @@ def trace_to(log_dir: str) -> Iterator[None]:
     """Capture a full device trace (jax.profiler.trace wrapper) — view
     with tensorboard's profile plugin, or any xplane.pb reader. While a
     capture is live, ``timed`` sections emit TraceAnnotation spans even
-    with host timing off."""
+    with host timing off. When it ends, the op -> scope table of the
+    programs that ran is written beside it (``op_scopes.json``)."""
     global _tracing
     jax = _get_jax()
 
@@ -187,6 +236,16 @@ def trace_to(log_dir: str) -> Iterator[None]:
     finally:
         with Timer._lock:
             _tracing -= 1
+        # the op -> scope table of the programs that ran, beside the
+        # trace: `python -m lightgbm_tpu trace <dir> --xplane <log_dir>`
+        # reads it (obs/scopes.py)
+        try:
+            from ..obs.scopes import write_op_scopes
+            write_op_scopes(log_dir)
+        except Exception as e:
+            from .log import log_warning
+            log_warning(f"trace_to: no op_scopes.json beside "
+                        f"{log_dir!r} ({type(e).__name__}: {e})")
 
 
 def parse_xprof_spec(spec: str):

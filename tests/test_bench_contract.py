@@ -97,35 +97,52 @@ def test_tracing_off_iteration_path_is_structurally_free():
     assert EnvCapture.from_env({}) is None
 
 
-def test_span_derivation_within_overhead_budget():
-    """The ONLY tracing work an instrumented iteration adds is
-    record_iteration_spans (recorder-side, off the hot path). Budget:
-    <=1% of the seed's ~130 ms/iter fused iteration = 1.3 ms. Assert
-    a generous half of that per call on a realistic phase table so a
-    regression (per-row spans, clock storms) fails loudly while CI
+def test_traced_round_spans_within_overhead_budget():
+    """What tracing adds to one round when it is ON: the round's real
+    spans (``train/round`` and its seven ``timed`` children, each a
+    clock pair, an annotation and a locked append) plus the recorder's
+    ``record_iteration_spans`` adopting them. Budget: <=1% of the
+    seed's ~130 ms/iter fused iteration = 1.3 ms; assert a generous
+    half of that per round so a regression (per-row spans, clock
+    storms, a scan over an undrained buffer) fails loudly while CI
     jitter does not."""
     import time as _time
 
     from lightgbm_tpu.obs.trace import (drain_span_events,
                                         record_iteration_spans,
                                         set_current_trace)
-    event = {"iteration": 5, "scan": {"window": 8},
-             "phases": {f"phase{i}": {"total": 0.01, "count": 4}
-                        for i in range(8)}}
-    event["phases"]["boosting/fused_scan"] = {"total": 0.08,
-                                              "count": 1}
+    from lightgbm_tpu.utils.timer import Timer, timed
+    labels = ("callbacks/before", "boosting/drain", "boosting/bagging",
+              "boosting/fused_scan", "tree/defer", "engine/eval",
+              "callbacks/after")
+
+    def one_round(i):
+        t0 = _time.perf_counter()
+        with timed("train/round"):
+            for label in labels:
+                with timed(label):
+                    pass
+        record_iteration_spans({"iteration": i, "scan": {"window": 8}},
+                               t0, _time.perf_counter())
+        return drain_span_events()      # the recorder drains each round
+
     set_current_trace(None)
-    record_iteration_spans(event, 0.0, 0.13)  # warm the path
-    n = 50
-    t0 = _time.perf_counter()
-    for _ in range(n):
-        record_iteration_spans(event, 0.0, 0.13)
-    per_call = (_time.perf_counter() - t0) / n
-    drain_span_events()
-    set_current_trace(None)
-    assert per_call < 0.65e-3, (
-        f"span derivation costs {per_call * 1e3:.3f} ms/iteration — "
-        "over the 1% tracing-overhead budget (1.3 ms) headroom")
+    Timer.enable()
+    try:
+        evs = one_round(0)              # warm the path
+        assert len(evs) == len(labels) + 2
+        n = 50
+        t0 = _time.perf_counter()
+        for i in range(n):
+            one_round(i)
+        per_round = (_time.perf_counter() - t0) / n
+    finally:
+        Timer.enable(False)
+        Timer.reset()
+        set_current_trace(None)
+    assert per_round < 0.65e-3, (
+        f"a traced round's spans cost {per_round * 1e3:.3f} ms — over "
+        "the 1% tracing-overhead budget (1.3 ms) headroom")
 
 
 def test_span_event_schema_is_documented():

@@ -357,8 +357,11 @@ def test_serve_metrics_verb_returns_openmetrics_text():
         assert samples["lightgbm_tpu_serve_shed_total"][()] == 1.0
         assert samples["lightgbm_tpu_serve_p99_ms"][()] == 9.5
         assert samples["lightgbm_tpu_serve_qps"][()] is not None
-        mkey = (("model", "abcd1234"),)
-        assert samples["lightgbm_tpu_serve_model_info"][mkey] == 1.0
+        # model id AND publication sha ride the labels (docs/
+        # OBSERVABILITY.md; resilience/elastic.py reads both); no
+        # manifest here, so the sha is empty
+        mkey = (("model", "abcd1234"), ("sha", ""))
+        assert samples["lightgbm_tpu_serve_model_info"] == {mkey: 1.0}
     finally:
         state.close()
 

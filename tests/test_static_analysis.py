@@ -647,12 +647,12 @@ def test_stripping_the_span_buffer_lock_fails(tmp_path):
     record_span on the thread side of the call graph."""
     import shutil
     anchor = ("    with _spans_lock:\n"
-              "        if len(_spans) < _SPANS_CAP:\n")
+              "        _spans.append(ev)\n")
     with open(os.path.join(PKG, "obs", "trace.py"),
               encoding="utf-8") as fh:
         src = fh.read()
     mutated = src.replace(
-        anchor, "    if True:\n        if len(_spans) < _SPANS_CAP:\n")
+        anchor, "    if True:\n        _spans.append(ev)\n")
     assert mutated != src, "mutation did not apply to obs/trace.py"
     for rel in ("serve/daemon.py", "serve/batcher.py"):
         dst = tmp_path / rel

@@ -207,10 +207,12 @@ def test_process_fault_log_pollution_is_isolated_b(tmp_path):
                  rounds=rounds, valid=False)
     lines = [ln for ln in open(path).read().splitlines() if ln]
     events = [json.loads(ln) for ln in lines]
-    # compile events are this RUN's own cost attribution, not leakage;
-    # fault events here would be the cross-test pollution
+    # compile events and spans are this RUN's own cost attribution and
+    # tracing, not leakage; fault events here would be the cross-test
+    # pollution
     assert [e["event"] for e in events
-            if e["event"] != "compile"] == ["iteration"] * rounds
+            if e["event"] not in ("compile", "span")] \
+        == ["iteration"] * rounds
 
 
 def test_telemetry_records_fused_path_tree_stats(tmp_path):

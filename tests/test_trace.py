@@ -146,42 +146,94 @@ def test_context_env_malformed_is_absent(monkeypatch):
 # 2. per-iteration span derivation
 # ---------------------------------------------------------------------
 
-def test_record_iteration_spans_phases_and_parenting():
+def _timed_nest():
+    """train/job > train/round > (boosting/bagging, boosting/fused_iter
+    > tree/defer), each a real ``timed`` section with a little work."""
+    import time
+    from lightgbm_tpu.utils.timer import timed
+    with timed("train/job", job=True, trace_root=True):
+        with timed("train/round"):
+            with timed("boosting/bagging"):
+                time.sleep(0.002)
+            time.sleep(0.001)
+            with timed("boosting/fused_iter"):
+                with timed("tree/defer"):
+                    time.sleep(0.002)
+
+
+def test_timed_spans_real_parenting_and_real_starts():
+    """Nested ``timed`` sections are real spans: parent = the enclosing
+    section, one trace id for the job, true starts and ends (a child
+    lies inside its parent, siblings in the order they ran, the gap
+    between them kept) — nothing is laid out after the fact."""
+    from lightgbm_tpu.utils.timer import Timer
+    Timer.enable()
+    try:
+        _timed_nest()
+    finally:
+        Timer.enable(False)
+    by = {e["name"]: e for e in T.drain_span_events()}
+    assert set(by) == {"train/job", "train/round", "boosting/bagging",
+                       "boosting/fused_iter", "tree/defer"}
+    assert len({e["trace_id"] for e in by.values()}) == 1
+    assert by["train/job"]["parent_id"] is None
+    for child, parent in (("train/round", "train/job"),
+                          ("boosting/bagging", "train/round"),
+                          ("boosting/fused_iter", "train/round"),
+                          ("tree/defer", "boosting/fused_iter")):
+        c, p_ = by[child], by[parent]
+        assert c["parent_id"] == p_["span_id"], child
+        assert p_["mono"] <= c["mono"]
+        assert c["mono"] + c["dur"] <= p_["mono"] + p_["dur"] + 1e-9
+    bag, fused = by["boosting/bagging"], by["boosting/fused_iter"]
+    assert bag["dur"] >= 0.002 and by["tree/defer"]["dur"] >= 0.002
+    # the millisecond slept between the siblings is still between them
+    assert fused["mono"] >= bag["mono"] + bag["dur"] + 0.001
+    assert not any(n.startswith("phase/") for n in by)
+
+
+def test_record_iteration_spans_adopts_real_children_and_scan_host_gap():
+    """The recorder's ``train/iteration`` adopts the real spans of its
+    interval (here recorded at top level, as ``record_iteration`` called
+    outside ``train()`` sees them) and reads the scan host gap from the
+    real blocking span, not from accumulator deltas."""
+    import time
+    from lightgbm_tpu.utils.timer import Timer, timed
     T.set_current_trace("f" * 16, "9" * 16)
-    event = {"iteration": 3,
-             "phases": {"hist/build": {"total": 0.010, "count": 4},
-                        "split/find": {"total": 0.020, "count": 4},
-                        "zero/skip": {"total": 0.0, "count": 0}}}
-    T.record_iteration_spans(event, 100.0, 100.05)
+    Timer.enable()
+    try:
+        t0 = time.perf_counter()
+        with timed(T.FUSED_SCAN_PHASE):
+            time.sleep(0.004)
+        with timed("tree/defer"):
+            pass
+        time.sleep(0.003)
+        t1 = time.perf_counter()
+    finally:
+        Timer.enable(False)
+    T.record_iteration_spans({"iteration": 7, "scan": {"window": 8},
+                              "phases": {"ignored": {"total": 9.0,
+                                                     "count": 1}}},
+                             t0, t1)
     evs = T.drain_span_events()
-    parent = evs[0]
-    assert parent["name"] == "train/iteration"
+    parent = [e for e in evs if e["name"] == "train/iteration"][0]
     assert parent["trace_id"] == "f" * 16
     assert parent["parent_id"] == "9" * 16
-    assert parent["attrs"]["iteration"] == 3
-    assert "host_gap_s" not in parent["attrs"]  # not a scan iteration
-    kids = evs[1:]
-    assert [k["name"] for k in kids] == ["phase/hist/build",
-                                         "phase/split/find"]
-    assert all(k["parent_id"] == parent["span_id"] for k in kids)
-    # sequential layout: children tile [t_start, ...) back to back
-    assert kids[0]["mono"] == pytest.approx(100.0)
-    assert kids[1]["mono"] == pytest.approx(100.010)
-
-
-def test_record_iteration_spans_scan_host_gap():
-    T.set_current_trace(None)
-    event = {"iteration": 7, "scan": {"window": 8},
-             "phases": {T.FUSED_SCAN_PHASE:
-                        {"total": 0.080, "count": 1}}}
-    T.record_iteration_spans(event, 0.0, 0.1)
-    evs = T.drain_span_events()
-    parent = evs[0]
+    assert parent["attrs"]["iteration"] == 7
     assert parent["attrs"]["scan"] == {"window": 8}
-    # iteration wall 100ms minus 80ms blocking fused_scan = 20ms gap
-    assert parent["attrs"]["host_gap_s"] == pytest.approx(0.02)
-    # a bare run (no pipeline context) still groups under ONE trace
-    assert len(parent["trace_id"]) == 16
+    kids = [e for e in evs if e["parent_id"] == parent["span_id"]]
+    assert sorted(k["name"] for k in kids) == [T.FUSED_SCAN_PHASE,
+                                               "tree/defer"]
+    scan = [k for k in kids if k["name"] == T.FUSED_SCAN_PHASE][0]
+    assert t0 <= scan["mono"] and scan["dur"] >= 0.004
+    # wall minus the REAL blocking span (the 9 s in `phases` is not read)
+    assert parent["attrs"]["host_gap_s"] == pytest.approx(
+        (t1 - t0) - scan["dur"], abs=1e-5)
+    assert parent["attrs"]["host_gap_s"] >= 0.003
+    # not a scan iteration: no gap attribute
+    T.record_iteration_spans({"iteration": 8}, t1, t1 + 0.01)
+    plain = T.drain_span_events()[0]
+    assert "host_gap_s" not in plain["attrs"]
 
 
 def test_fused_scan_phase_is_single_source_of_truth():
@@ -588,3 +640,207 @@ def test_span_keys_are_the_schema_registry():
     from lightgbm_tpu.obs import schemas
     assert T.SPAN_EVENT_KEYS == \
         tuple(schemas.EVENTS["span"]["required"])
+
+
+# ---------------------------------------------------------------------
+# 5. one span model: job-level spans, compile stages, the device trace
+#    read by layer (`trace --xplane`)
+# ---------------------------------------------------------------------
+
+def _tiny_train(rounds=3):
+    import lightgbm_tpu as lgb
+    rs = np.random.RandomState(3)
+    X = rs.randn(900, 6)
+    y = (X[:, 0] - X[:, 2] > 0).astype(float)
+    return lgb.train({"objective": "binary", "num_leaves": 7,
+                      "max_bin": 31, "verbose": -1},
+                     lgb.Dataset(X, label=y), rounds)
+
+
+JOB_SPANS = {"train/job", "train/init", "train/build_step",
+             "dataset/construct",
+             "dataset/construct/load", "dataset/construct/find_bins",
+             "dataset/construct/bin_rows", "compile/gbdt/fused_iter",
+             "compile/trace", "compile/lower", "compile/backend",
+             "compile/cost_capture"}
+ROUND_SPANS = {"train/round", "train/update", "callbacks/before",
+               "callbacks/after",
+               "boosting/drain", "boosting/bagging", "boosting/fused_iter",
+               "tree/defer", "engine/eval"}
+
+
+def test_job_level_spans_with_telemetry_off_and_no_round_span():
+    """Telemetry off, no capture, no Timer: the job-level spans are
+    there (a few dozen appends a job, with real parents and one trace
+    id) and not one per-round span was recorded."""
+    from lightgbm_tpu.utils.timer import Timer
+    assert not Timer.enabled()
+    T.drain_span_events()
+    bst = _tiny_train()
+    evs = T.drain_span_events()
+    names = {e["name"] for e in evs}
+    assert JOB_SPANS <= names, JOB_SPANS - names
+    assert not names & ROUND_SPANS
+    assert len(evs) < 40
+    by = {e["name"]: e for e in evs}
+    ids = {e["span_id"]: e["name"] for e in evs}
+    assert len({e["trace_id"] for e in evs}) == 1
+    assert by["train/job"]["parent_id"] is None
+    assert ids[by["train/init"]["parent_id"]] == "train/job"
+    assert ids[by["train/build_step"]["parent_id"]] == "train/job"
+    assert ids[by["dataset/construct"]["parent_id"]] == "train/init"
+    for part in ("load", "find_bins", "bin_rows"):
+        assert ids[by[f"dataset/construct/{part}"]["parent_id"]] \
+            == "dataset/construct"
+    # with no per-round span open, the compile hangs under the job
+    comp = by["compile/gbdt/fused_iter"]
+    assert ids[comp["parent_id"]] == "train/job"
+    for stage in ("trace", "lower", "backend", "cost_capture"):
+        st = by[f"compile/{stage}"]
+        assert st["parent_id"] == comp["span_id"]
+        assert comp["mono"] - 1e-6 <= st["mono"]
+        assert st["mono"] + st["dur"] <= comp["mono"] + comp["dur"] + 1e-6
+    assert by["compile/backend"]["attrs"]["cache"] in ("hit", "miss",
+                                                       "uncached")
+    assert by["train/init"]["dur"] < by["train/job"]["dur"]
+    assert bst.num_trees() == 3
+    # a second job roots its own trace
+    _tiny_train(1)
+    again = [e for e in T.drain_span_events() if e["name"] == "train/job"]
+    assert again[0]["trace_id"] != by["train/job"]["trace_id"]
+
+
+def test_round_spans_recorded_while_timer_live_and_adopted(tmp_path):
+    """With the telemetry recorder on, every round's spans are real and
+    the recorder's train/iteration adopts them."""
+    import lightgbm_tpu as lgb
+    rs = np.random.RandomState(4)
+    X = rs.randn(900, 6)
+    y = (X[:, 0] > 0).astype(float)
+    path = str(tmp_path / "t.jsonl")
+    lgb.train({"objective": "binary", "num_leaves": 7, "max_bin": 31,
+               "verbose": -1}, lgb.Dataset(X, label=y), 3,
+              callbacks=[lgb.telemetry(path)])
+    evs = [json.loads(ln) for ln in open(path) if ln.strip()]
+    spans = [e for e in evs if e["event"] == "span"]
+    names = [s["name"] for s in spans]
+    assert names.count("train/iteration") == 3
+    assert names.count("train/round") == 3
+    assert names.count("boosting/fused_iter") == 3
+    assert not any(n.startswith("phase/") for n in names)
+    its = {s["span_id"]: s for s in spans if s["name"] == "train/iteration"}
+    job = [s for s in spans if s["name"] == "train/job"][0]
+    assert all(s["parent_id"] == job["span_id"] for s in its.values())
+    # the iteration adopts what hung under the open train/round
+    # (train/update, callbacks/*); their own children stay theirs
+    upd = {s["span_id"]: s for s in spans if s["name"] == "train/update"}
+    assert len(upd) == 3
+    assert all(s["parent_id"] in its for s in upd.values())
+    fused = [s for s in spans if s["name"] == "boosting/fused_iter"]
+    assert all(s["parent_id"] in upd for s in fused)
+    for s in fused:
+        it = its[upd[s["parent_id"]]["parent_id"]]
+        assert it["mono"] <= s["mono"] <= it["mono"] + it["dur"]
+
+
+def test_compile_cache_counters_follow_jax_monitoring():
+    """The persistent cache's hit / miss events feed the two registry
+    counters, whoever compiled; a stage event outside any tracked entry
+    is nobody's."""
+    from lightgbm_tpu.obs import cost
+    from lightgbm_tpu.obs.registry import registry
+    cost.install_compile_listeners()
+
+    def value(name):
+        fam = registry.snapshot().get(name)
+        return fam["series"][0]["value"] if fam else 0
+
+    h0, m0 = value("compile_cache_hits"), value("compile_cache_misses")
+    from jax import monitoring
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event_duration_secs(
+        "/jax/core/compile/backend_compile_duration", 0.5)
+    assert value("compile_cache_hits") == h0 + 1
+    assert value("compile_cache_misses") == m0 + 2
+    assert not [e for e in T.span_events_snapshot()
+                if e["name"].startswith("compile/")]
+
+
+def _hand_built_capture():
+    """One device, ops in ms: a `while` [0, 10) holding a sort [1, 3)
+    and a fusion [4, 9), which holds nothing; a relayout copy [10, 13);
+    a gap; then an unscoped op [20, 21). Host: train/round over it all,
+    boosting/fused_iter [0, 14), callbacks/after [14, 22) holding a
+    runtime event in the gap."""
+    ms = 1e-3
+    ops = [("%while.1 = (s32[]) while(%t), body=%b", 0.0, 10 * ms),
+           ("%sort.5 = (u32[8]) sort(%k, %i)", 1 * ms, 2 * ms),
+           ("%fusion.211 = f32[17,64] fusion(%a)", 4 * ms, 5 * ms),
+           ("%copy.1297 = f32[8,2]{0,1} copy(%g)", 10 * ms, 3 * ms),
+           ("%neg.3 = f32[8] negate(%x)", 20 * ms, 1 * ms)]
+    host = [("train/round", -1 * ms, 24 * ms),
+            ("boosting/fused_iter", -0.5 * ms, 14.5 * ms),
+            ("callbacks/after", 14 * ms, 8 * ms),
+            ("PjRtFuture::Await", 14.5 * ms, 5 * ms),
+            ("perfbench_round", -0.2 * ms, 23 * ms)]
+    table = {"while.1": "grow/fixed", "sort.5": "grow/partition/key_sort",
+             "fusion.211": "grow/hist/build",
+             "copy.1297": "grow/partition/payload"}
+    return {"devices": {"/device:TPU:0": ops}, "host": host}, table
+
+
+def test_xplane_by_scope_nesting_union_and_gap_attribution():
+    from lightgbm_tpu.obs import xplane
+    capture, table = _hand_built_capture()
+    rep = xplane.report(capture, table)
+    dev = rep["devices"][0]
+    sc = dev["by_scope"]
+    # the while does not count its body twice: 10 - 2 - 5 = 3 ms of its own
+    assert sc["grow/fixed"] == pytest.approx(3e-3)
+    assert sc["grow/partition/key_sort"] == pytest.approx(2e-3)
+    assert sc["grow/hist/build"] == pytest.approx(5e-3)
+    assert sc["grow/partition/payload"] == pytest.approx(3e-3)
+    assert sc[xplane.UNSCOPED] == pytest.approx(1e-3)
+    # busy = the union of [0, 13) and [20, 21), not the sum of durations
+    assert dev["busy_s"] == pytest.approx(14e-3)
+    assert dev["self_s"] == pytest.approx(14e-3)
+    assert dev["window_s"] == pytest.approx(21e-3)
+    assert dev["scoped_share"] == pytest.approx(13 / 14)
+    # one gap over 1 ms, put down to the innermost PROGRAM span covering
+    # its middle, the runtime's own event beside it; never the driver's
+    (gap,) = dev["idle_gaps"]
+    assert gap["gap_s"] == pytest.approx(7e-3)
+    assert gap["span"] == "callbacks/after"
+    assert gap["host"] == "PjRtFuture::Await"
+    text = xplane.render_report(rep)
+    assert "grow/partition/payload" in text and "(unscoped)" in text
+    assert "in callbacks/after > PjRtFuture::Await" in text
+    # without a table every op is unscoped, and the report says so
+    bare = xplane.report(capture, None)
+    assert bare["devices"][0]["scoped_share"] == 0.0
+    assert "no op -> scope table" in xplane.render_report(bare)
+
+
+def test_trace_cli_xplane_reads_the_table_beside_the_capture(
+        tmp_path, monkeypatch, capsys):
+    from lightgbm_tpu.obs import xplane
+    capture, table = _hand_built_capture()
+    tdir = tmp_path / "prof"
+    tdir.mkdir()
+    (tdir / "op_scopes.json").write_text(json.dumps(
+        {"gbdt/fused_iter": {"ops": table, "derived": ["copy.1297"]}}))
+    monkeypatch.setattr(xplane, "find_xplane", lambda d: str(tdir / "x"))
+    monkeypatch.setattr(xplane, "load", lambda path: capture)
+    spans = tmp_path / "telemetry"
+    spans.mkdir()
+    # no span stream: with --xplane that is no error
+    assert T.main([str(spans), "--xplane", str(tdir)]) == 0
+    out = capsys.readouterr().out
+    assert "92.86% of self time under a named scope" in out
+    assert "grow/hist/build" in out and "idle" in out
+    assert T.main([str(spans)]) == 1
+    assert T.main([str(spans), "--xplane"]) == 1
+    assert T.main([str(spans), "--xplane", str(tdir), "--scopes",
+                   str(tmp_path / "nope.json")]) == 1
