@@ -33,7 +33,8 @@ from ..obs.trace import FUSED_SCAN_PHASE
 from ..objectives import Objective
 from ..resilience.faults import FaultPlan, is_resource_exhausted
 from ..ops.gather import gather_small
-from ..ops.grow import GrowConfig, TreeArrays, grow_tree, grow_tree_impl
+from ..ops.grow import (GrowConfig, TreeArrays, grow_tree, grow_tree_impl,
+                        last_plan as grow_plan)
 from ..ops.predict import predict_leaf_binned
 from ..ops.renew import renew_leaf_values
 from ..ops.split import SplitParams
@@ -546,6 +547,7 @@ class GBDTBooster:
         # _train_one_iter_fused)
         self._fused_fn = None
         self._fused_proto = None
+        self._grow_plan = {}
         self._row_w_ones = None
         self._fmask_cached = None
         # multi-iteration scan state (docs/FUSED.md): compiled window
@@ -1542,6 +1544,7 @@ class GBDTBooster:
         # NB: abstract stand-ins only — _feature_mask() here would
         # consume a host-RNG draw and desync the stream vs eager
         fmask_sds = jax.ShapeDtypeStruct((self.F,), jnp.bool_)
+        grow_plan.clear()
         proto, _ = jax.eval_shape(
             functools.partial(grow_tree_impl, gcfg),
             self.bins_T, sds, sds, sds,
@@ -1551,6 +1554,9 @@ class GBDTBooster:
             self.interaction_groups, self.forced, None,
             key_sds if bynode else None, self._bundle_dev)
         self._fused_proto = proto
+        # what the grower resolved in that trace (empty for the growers
+        # that partition nothing): the train/build_step span's attrs
+        self._grow_plan = dict(grow_plan)
         return proto
 
     def _get_fused_fn(self):
@@ -1560,8 +1566,10 @@ class GBDTBooster:
         # job-level span: once an engine (and once more per OOM
         # rebuild). The proto is an abstract evaluation of the whole
         # grower, i.e. a second trace of it before the jit's own
-        with timed("train/build_step", job=True):
+        attrs = {}
+        with timed("train/build_step", job=True, attrs=attrs):
             self._fused_tree_proto()
+            attrs.update(self._grow_plan)
             ctx = self._step_ctx()
 
         def step(score, it, shrink, row_w, fmask, bins_T, fnb, fnan,

@@ -1000,7 +1000,11 @@ class _CompactState(NamedTuple):
                              # positions start at b*(n+2K) + K (K rows
                              # of pad on both sides of each half absorb
                              # full-chunk write tails)
-    pay2: jnp.ndarray        # [2*(n+2K), 2] f32/i8 — (g, h) payload
+    pay2: jnp.ndarray        # [2*(n+2K), 2] i8/bf16/f32 — (g, h) payload
+                             # rows; in the wide partition the f32
+                             # payload is PLANAR instead: one 1-D
+                             # f32[2 * 2*(n+2K)], all g then all h (g of
+                             # position p at [p], h at [2*(n+2K) + p])
     ord2: jnp.ndarray        # [2*(n+2K)] u32 — original row id, top
                              # bit = in-bag flag
     leaf_buf: jnp.ndarray    # [L] i32 — which half (0/1) holds each
@@ -1082,6 +1086,15 @@ def _row_leaf_from_order(order, leaf_of_pos):
     per element."""
     _, row_leaf = lax.sort((order, leaf_of_pos), num_keys=1)
     return row_leaf
+
+
+# What the compact grower resolved the last time it was traced: how a
+# chunk is partitioned (``partition``: wide | sort | route) and the
+# form of the (g, h) payload (``payload``: int8 | bf16 | f32-planar |
+# f32). Written at trace time, so it describes a compile, not a call;
+# the engine stamps it onto its ``train/build_step`` span
+# (models/gbdt.py, docs/OBSERVABILITY.md).
+last_plan: dict = {}
 
 
 # Variadic-sort width management for the chunk partition. The TPU
@@ -1848,7 +1861,11 @@ def _grow_compact_impl(cfg: GrowConfig,
     # IDENTICAL on TPU while halving payload bytes in every chunk
     # slice/sort/write (and packing the pair into one u32 sort column).
     # Exact float sums (root totals, leaf renewal) read the original
-    # f32 gw2, never pay2. CPU keeps f32: its matmuls don't truncate.
+    # f32 gw2, never pay2. Everything else keeps f32: the CPU (its
+    # matmuls don't truncate), the scatter method, and on the TPU
+    # hist_precision=high|highest. The f32 pair is two sort columns on
+    # the narrow path and two u32 words of the gathered row on the wide
+    # one (pay_planar below).
     bf16_pay = (not quant) and jax.default_backend() == "tpu" \
         and cfg.hist_method != "scatter" and cfg.hist_precision == "default"
     if quant:
@@ -1900,6 +1917,21 @@ def _grow_compact_impl(cfg: GrowConfig,
     wide_part = (not route) \
         and NW + NPAY + (1 if track else 0) > _SORT_SINGLE_MAX \
         and 2 * (n + 2 * PAD) * NW < 2 ** 31
+    # The f32 (g, h) payload of the wide partition is resident PLANAR:
+    # one 1-D f32[2 * 2*SEG], all g then all h. A 1-D buffer has one
+    # possible layout, so the partition loop and the histogram loop
+    # cannot disagree on it: as a 2-D [2*SEG, 2] carry the first kept
+    # it row-major (minor dimension 2 padded to 128 lanes, 64x) and the
+    # second rows-minor, and XLA:TPU copied the whole buffer between
+    # them once a split (58% of a 6.6M x 67 round on the v5e). Flat
+    # INTERLEAVED (as bins2 is) also drops the copy but the per-chunk
+    # de-interleave of a 2-wide row compiled 8x slower at twice the
+    # code. The int8 and bf16 pairs are one word and stay 2-D.
+    pay_planar = wide_part and NPAY == 2
+    last_plan.update(
+        partition="route" if route else "wide" if wide_part else "sort",
+        payload="int8" if quant else "bf16" if bf16_pay
+        else "f32-planar" if pay_planar else "f32")
 
     def _bins_slice(w32, pos0, CK):
         """[CK, NW] chunk of the packed words at row offset pos0
@@ -1926,6 +1958,36 @@ def _grow_compact_impl(cfg: GrowConfig,
         return lax.dynamic_update_slice(arr, out.reshape(-1),
                                         (off * NW,))
 
+    def write(arr, off, block, m):
+        """Masked RMW block write at a dynamic row offset."""
+        if arr.ndim == 2:
+            z = jnp.zeros((), off.dtype)
+            cur = lax.dynamic_slice(arr, (off, z),
+                                    (block.shape[0], arr.shape[1]))
+            out = jnp.where(m[:, None], block, cur)
+            return lax.dynamic_update_slice(arr, out, (off, z))
+        cur = lax.dynamic_slice(arr, (off,), (block.shape[0],))
+        out = jnp.where(m, block, cur)
+        return lax.dynamic_update_slice(arr, out, (off,))
+
+    def _pay_slice(pay2, pos0, CK):
+        """[CK, 2] (g, h) chunk of the payload at row offset pos0."""
+        if pay_planar:
+            return jnp.stack(
+                [lax.dynamic_slice(pay2, (pos0 + c * 2 * SEG,), (CK,))
+                 for c in range(C)], axis=1)
+        return lax.dynamic_slice(
+            pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+
+    def _pay_write(pay2, off, block, m):
+        """Masked RMW of a [CK, 2] (g, h) block at row offset ``off``
+        (the planar form writes each component as ord2 is written)."""
+        if pay_planar:
+            for c in range(C):
+                pay2 = write(pay2, off + c * 2 * SEG, block[:, c], m)
+            return pay2
+        return write(pay2, off, block, m)
+
     def chunk_hist(bins2, pay2, pos0, limit, CK):
         """Histogram of one CK-row chunk at dynamic row offset ``pos0``:
         slice the packed bin words + payload, mask the window tail
@@ -1934,8 +1996,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         pool-miss window recompute."""
         with scope("grow/hist/build"):
             blk_b = _local_hist_rows(bins2, pos0, CK)
-            blk_p = lax.dynamic_slice(
-                pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+            blk_p = _pay_slice(pay2, pos0, CK)
             valid = jnp.arange(CK) < jnp.clip(limit, 0, CK)
             hp = blk_p * valid[:, None].astype(blk_p.dtype)
             if quant:
@@ -1987,18 +2048,6 @@ def _grow_compact_impl(cfg: GrowConfig,
         zero = jnp.asarray(0, jnp.int32)
         acc0 = jnp.zeros((FB, B, C), jnp.int32 if quant else dtype)
 
-        def write(arr, off, block, m):
-            """Masked RMW block write at a dynamic row offset."""
-            if arr.ndim == 2:
-                z = jnp.zeros((), off.dtype)
-                cur = lax.dynamic_slice(arr, (off, z),
-                                        (block.shape[0], arr.shape[1]))
-                out = jnp.where(m[:, None], block, cur)
-                return lax.dynamic_update_slice(arr, out, (off, z))
-            cur = lax.dynamic_slice(arr, (off,), (block.shape[0],))
-            out = jnp.where(m, block, cur)
-            return lax.dynamic_update_slice(arr, out, (off,))
-
         def make_body(CK, base_off):
             """Partition-chunk body over CK rows starting at window
             offset ``base_off + c*CK`` (base_off may be traced)."""
@@ -2012,8 +2061,7 @@ def _grow_compact_impl(cfg: GrowConfig,
                 with scope("grow/partition/gather"):
                     blk_w = _bins_slice(bins2, pos0, CK)
                 with scope("grow/partition/payload"):
-                    blk_p = lax.dynamic_slice(
-                        pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+                    blk_p = _pay_slice(pay2, pos0, CK)
                 split_col = _extract_col(blk_w,
                                          bundle_of[f] if bundled else f)
                 gl = chunk_goleft(split_col, f, t, dl, isc, cm)
@@ -2083,36 +2131,40 @@ def _grow_compact_impl(cfg: GrowConfig,
                                       jnp.asarray(CK, jnp.int32))
                         perm_r = rot(perm, s_r)
                     # fold the payload (and ord) into the word block so
-                    # ONE row gather moves everything; the (g, h) pair
-                    # is already a single u32 word on the TPU paths
-                    # (bf16 pair / quant int8 pair), and the f32 CPU
-                    # pair bitcasts to two u32 words
-                    if quant:
-                        pw = _pack_pay(blk_p)[0].astype(jnp.uint32)[:, None]
-                    elif bf16_pay:
-                        pw = _pack_pay(blk_p)[0][:, None]
-                    else:
-                        pw = None                  # separate-gather pay
-                    parts = [blk_w] + ([pw] if pw is not None else [])
-                    if track:
-                        parts.append(blk_o[:, None])
-                    blk_all = parts[0] if len(parts) == 1 \
-                        else jnp.concatenate(parts, axis=1)
+                    # ONE row gather a side moves everything: the int8
+                    # and bf16 (g, h) pairs are one u32 word, the f32
+                    # pair bitcasts to two
+                    with scope("grow/partition/payload"):
+                        if quant:
+                            pw = _pack_pay(blk_p)[0].astype(
+                                jnp.uint32)[:, None]
+                        elif bf16_pay:
+                            pw = _pack_pay(blk_p)[0][:, None]
+                        else:
+                            pw = lax.bitcast_convert_type(blk_p,
+                                                          jnp.uint32)
+                    PW = pw.shape[1]
                     with scope("grow/partition/gather"):
+                        blk_all = jnp.concatenate(
+                            [blk_w, pw]
+                            + ([blk_o[:, None]] if track else []), axis=1)
                         la = jnp.take(blk_all, perm, axis=0)
                         ra = jnp.take(blk_all, perm_r, axis=0)
-                    PW = 0 if pw is None else 1
                     lb, rb = la[:, :NW], ra[:, :NW]
-                    if quant:
-                        lp = _unpack_pay((la[:, NW].astype(jnp.uint16),))
-                        rp = _unpack_pay((ra[:, NW].astype(jnp.uint16),))
-                    elif bf16_pay:
-                        lp = _unpack_pay((la[:, NW],))
-                        rp = _unpack_pay((ra[:, NW],))
-                    else:
-                        with scope("grow/partition/gather"):
-                            lp = jnp.take(blk_p, perm, axis=0)
-                            rp = jnp.take(blk_p, perm_r, axis=0)
+                    with scope("grow/partition/payload"):
+                        if quant:
+                            lp = _unpack_pay(
+                                (la[:, NW].astype(jnp.uint16),))
+                            rp = _unpack_pay(
+                                (ra[:, NW].astype(jnp.uint16),))
+                        elif bf16_pay:
+                            lp = _unpack_pay((la[:, NW],))
+                            rp = _unpack_pay((ra[:, NW],))
+                        else:
+                            lp = lax.bitcast_convert_type(
+                                la[:, NW:NW + PW], blk_p.dtype)
+                            rp = lax.bitcast_convert_type(
+                                ra[:, NW:NW + PW], blk_p.dtype)
                     if track:
                         lo = la[:, NW + PW]
                         ro = ra[:, NW + PW]
@@ -2136,11 +2188,11 @@ def _grow_compact_impl(cfg: GrowConfig,
                 with scope("grow/partition/gather"):
                     bins2 = _bins_write(bins2, src_base + l_off, lb, ml)
                 with scope("grow/partition/payload"):
-                    pay2 = write(pay2, src_base + l_off, lp, ml)
+                    pay2 = _pay_write(pay2, src_base + l_off, lp, ml)
                 with scope("grow/partition/gather"):
                     bins2 = _bins_write(bins2, o_r, rb, mr)
                 with scope("grow/partition/payload"):
-                    pay2 = write(pay2, o_r, rp, mr)
+                    pay2 = _pay_write(pay2, o_r, rp, mr)
                 if track:
                     with scope("grow/partition/gather"):
                         ord2 = write(ord2, src_base + l_off, lo, ml)
@@ -2383,7 +2435,10 @@ def _grow_compact_impl(cfg: GrowConfig,
         tree=tree, best=best, hists=hists,
         # the wide partition stores the words FLAT (see wide_part)
         bins2=bins2_0.reshape(-1) if wide_part else bins2_0,
-        pay2=jnp.pad(pay0, ((PAD, PAD + SEG), (0, 0))),
+        # ... and the f32 payload PLANAR (see pay_planar)
+        pay2=jnp.concatenate(
+            [jnp.pad(pay0[:, c], (PAD, PAD + SEG)) for c in range(C)])
+        if pay_planar else jnp.pad(pay0, ((PAD, PAD + SEG), (0, 0))),
         ord2=jnp.pad(ord0, (PAD, PAD + SEG)) if track else ord0,
         leaf_buf=jnp.zeros((L,), jnp.int32),
         leaf_begin=jnp.zeros((L,), jnp.int32),

@@ -12,6 +12,8 @@ variadic-sort path remains as "sort". These tests pin:
   holds the masked/compact pair to.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -156,52 +158,102 @@ def test_grower_nibble_packed_low_bin():
                                masked.predict(X[:400]), rtol=1e-5)
 
 
-def test_grower_wide_gather_equals_sort(monkeypatch):
-    """The wide partition (sort (key, iota) + row gathers of the packed
-    words; grow.py make_body) must be bit-identical to the
-    payload-carrying sort it replaces past _SORT_SINGLE_MAX operands.
-    F=64 u8 -> NW=16 word columns engages the gather path at the
-    default threshold; forcing the threshold sky-high re-takes the
-    sort path on the identical inputs."""
+# The wide partition's cases (grow.py make_body, the ``wide_part`` arm).
+# F=64 u8 columns -> NW=16 packed words: with the two payload operands
+# that is past _SORT_SINGLE_MAX, so the gather path engages at the
+# default threshold. Each case: GrowConfig fields, then (F, n).
+_WIDE_CASES = {
+    # float32 payload, no row tracking: the words and the two payload
+    # words are all the gathered row holds
+    "plain": (dict(track_rows=False), (64, 5000)),
+    # + ord2 (bagging / GOSS / EFB): ord sits behind the payload words
+    "tracked": (dict(track_rows=True), (64, 4096)),
+    # the benchmark cell's histogram: the MXU kernel reads the [CK, 2]
+    # block the two planar slices stack
+    "mxu_high": (dict(track_rows=False, hist_method="mxu",
+                      hist_precision="high"), (64, 5000)),
+    "tracked_mxu_high": (dict(track_rows=True, hist_method="mxu",
+                              hist_precision="high"), (64, 4096)),
+    # the Criteo width (NW=17, three pad columns in the last word) and a
+    # row count that is no multiple of the chunk
+    "criteo_width_ragged": (dict(track_rows=False, hist_method="mxu",
+                                 hist_precision="high"), (67, 5003)),
+    # fewer histogram slots than leaves: the pool-miss window_hist
+    # re-reads a leaf's window of the payload
+    "pooled": (dict(track_rows=False, hist_pool_slots=4), (64, 5000)),
+    # the one-word int8 pair shares the arm's concatenate and gather
+    # (sort A/B only: the masked grower does not quantize as this does)
+    "int8": (dict(track_rows=True, quantized=True, stochastic=False),
+             (64, 4096)),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_case(name, variant):
+    """(tree, row_leaf, plan) of one case grown ``wide`` (as shipped),
+    by the variadic ``sort`` (threshold raised) or by the ``masked``
+    grower. Each under a FRESH jit: the module's ``grow_tree`` keys its
+    trace on (cfg, shapes), so a call after patching _SORT_SINGLE_MAX
+    would re-run the program traced before it."""
     import lightgbm_tpu.ops.grow as growmod
+    fields, (F, n) = _WIDE_CASES[name]
     rs = np.random.RandomState(7)
-    F, n = 64, 5000
     bins_T = jnp.asarray(rs.randint(0, 64, size=(F, n), dtype=np.uint8))
     grad = jnp.asarray(rs.randn(n).astype(np.float32))
     hess = jnp.asarray((np.abs(rs.randn(n)) + 0.1).astype(np.float32))
-    t_g, rl_g = _grow("sort", bins_T, grad, hess)
-    monkeypatch.setattr(growmod, "_SORT_SINGLE_MAX", 10_000)
-    t_s, rl_s = _grow("sort", bins_T, grad, hess)
-    assert np.array_equal(np.asarray(rl_g), np.asarray(rl_s))
-    for a, b in zip(t_g, t_s):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+    cfg = GrowConfig(**{**dict(
+        num_leaves=31, num_bins=64,
+        split=SplitParams(min_data_in_leaf=20.0), hist_method="scatter",
+        grower="masked" if variant == "masked" else "compact",
+        chunk=512, partition="sort"), **fields})
+    growmod.last_plan.clear()
+    with pytest.MonkeyPatch.context() as mp:
+        if variant == "sort":
+            mp.setattr(growmod, "_SORT_SINGLE_MAX", 10_000)
+        tree, row_leaf = jax.jit(
+            functools.partial(growmod.grow_tree_impl, cfg))(
+            bins_T, grad, hess, jnp.ones((n,), jnp.float32),
+            jnp.ones((F,), bool), jnp.full((F,), 64, jnp.int32),
+            jnp.full((F,), -1, jnp.int32))
+    return (jax.tree_util.tree_map(np.asarray, tree),
+            np.asarray(row_leaf), dict(growmod.last_plan))
 
 
-def test_grower_wide_gather_equals_sort_tracked_bf16(monkeypatch):
-    """Same A/B with the ord2-tracking + packed-payload variant (the
-    bundled/TPU configuration folds pay and ord into the gathered word
-    block — exercise that lane too)."""
-    import lightgbm_tpu.ops.grow as growmod
-    rs = np.random.RandomState(8)
-    F, n = 64, 4096
-    bins_T = jnp.asarray(rs.randint(0, 64, size=(F, n), dtype=np.uint8))
-    grad = jnp.asarray(rs.randn(n).astype(np.float32))
-    hess = jnp.asarray((np.abs(rs.randn(n)) + 0.1).astype(np.float32))
+@pytest.mark.parametrize("case", list(_WIDE_CASES))
+def test_grower_wide_gather_equals_sort(case):
+    """The wide partition (sort (key, iota) + ONE row gather a side of
+    the packed words with the payload's words behind them) must be
+    bit-identical to the payload-carrying sort it replaces past
+    _SORT_SINGLE_MAX operands; forcing the threshold sky-high re-takes
+    the sort path on the identical inputs. The float32 payload is held
+    planar (1-D, all g then all h) on the wide side and as [rows, 2] on
+    the sort side: data movement only, so not one bit may differ."""
+    t_g, rl_g, plan_g = _wide_case(case, "wide")
+    t_s, rl_s, plan_s = _wide_case(case, "sort")
+    int8 = case == "int8"
+    assert plan_g == {"partition": "wide",
+                      "payload": "int8" if int8 else "f32-planar"}
+    assert plan_s == {"partition": "sort",
+                      "payload": "int8" if int8 else "f32"}
+    assert np.array_equal(rl_g, rl_s)
+    for name, a, b in zip(t_g._fields, t_g, t_s):
+        assert np.array_equal(a, b), name
 
-    def grow_tracked():
-        cfg = GrowConfig(num_leaves=31, num_bins=64,
-                         split=SplitParams(), hist_method="scatter",
-                         grower="compact", chunk=512, partition="sort",
-                         track_rows=True)
-        return grow_tree(cfg, bins_T, grad, hess,
-                         jnp.ones((n,), jnp.float32),
-                         jnp.ones((F,), bool),
-                         jnp.full((F,), 64, jnp.int32),
-                         jnp.full((F,), -1, jnp.int32))
 
-    t_g, rl_g = grow_tracked()
-    monkeypatch.setattr(growmod, "_SORT_SINGLE_MAX", 10_000)
-    t_s, rl_s = grow_tracked()
-    assert np.array_equal(np.asarray(rl_g), np.asarray(rl_s))
-    for a, b in zip(t_g, t_s):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+@pytest.mark.parametrize("case", [c for c in _WIDE_CASES if c != "int8"])
+def test_grower_wide_gather_equals_masked(case):
+    """... and equal to the masked grower's tree (which partitions
+    nothing) by tests/test_grower_equivalence.py's bar: structure and
+    row assignment exact, sums to float32 rounding."""
+    t_g, rl_g, _ = _wide_case(case, "wide")
+    t_m, rl_m, plan_m = _wide_case(case, "masked")
+    assert plan_m == {}
+    assert int(t_m.num_leaves) == int(t_g.num_leaves) == 31
+    for name in ("split_feature", "threshold_bin", "default_left",
+                 "left_child", "right_child", "leaf_count", "leaf_parent"):
+        np.testing.assert_array_equal(getattr(t_m, name),
+                                      getattr(t_g, name), err_msg=name)
+    for name in ("leaf_value", "split_gain", "leaf_weight"):
+        np.testing.assert_allclose(getattr(t_m, name), getattr(t_g, name),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+    np.testing.assert_array_equal(rl_m, rl_g)

@@ -710,6 +710,33 @@ def test_job_level_spans_with_telemetry_off_and_no_round_span():
     assert again[0]["trace_id"] != by["train/job"]["trace_id"]
 
 
+@pytest.mark.parametrize("params,cols,want", [
+    # 6 columns pack to 2 words: the payload-carrying variadic sort
+    ({}, 6, {"partition": "sort", "payload": "f32"}),
+    # 48 columns pack to 12 words; with the float32 pair that is past
+    # _SORT_SINGLE_MAX: key sort + row gathers, the pair held planar
+    ({}, 48, {"partition": "wide", "payload": "f32-planar"}),
+    ({"use_quantized_grad": True}, 48,
+     {"partition": "wide", "payload": "int8"}),
+    # the masked grower partitions nothing and says nothing
+    ({"grower": "masked"}, 6, {}),
+])
+def test_build_step_span_names_partition_and_payload(params, cols, want):
+    """``train/build_step`` carries what the grower resolved when the
+    step was traced: how a chunk is partitioned and the payload's form
+    (a per-compile fact, so a job-level attribute)."""
+    import lightgbm_tpu as lgb
+    rs = np.random.RandomState(5)
+    X = rs.randn(900, cols)
+    y = (X[:, 0] - X[:, 2] > 0).astype(float)
+    T.drain_span_events()
+    lgb.train({"objective": "binary", "num_leaves": 7, "max_bin": 31,
+               "verbose": -1, **params}, lgb.Dataset(X, label=y), 1)
+    span, = [e for e in T.drain_span_events()
+             if e["name"] == "train/build_step"]
+    assert span["attrs"] == want
+
+
 def test_round_spans_recorded_while_timer_live_and_adopted(tmp_path):
     """With the telemetry recorder on, every round's spans are real and
     the recorder's train/iteration adopts them."""

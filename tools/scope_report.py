@@ -91,7 +91,7 @@ def main(argv=None):
             dev["top_ops"] = [
                 [op, s, (table or {}).get(op), op in derived]
                 for op, s in sorted(per_op.items(),
-                                    key=lambda kv: -kv[1])[:16]]
+                                    key=lambda kv: -kv[1])[:40]]
             # what the host was doing across each gap: every host event
             # that overlaps it, outermost first
             t_first = min(s for _, s, _ in ops)
