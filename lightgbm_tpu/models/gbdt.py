@@ -756,6 +756,30 @@ class GBDTBooster:
         # score matrix follows the residency (sharded checkpoint
         # save/restore goes through placement.fetch_global)
         self.score = self._place_score(self.score)
+        # ... and so does the rest of the row state, once, here: label,
+        # row weights and the no-bagging ones vector, row-sharded like
+        # the matrix. Gradients are then born sharded (elementwise on
+        # the score and the label), and no round pads and reshards
+        # 4 bytes a row of each from device 0 (S4). With row padding
+        # the [n] vectors cannot take the matrix's [n + pad] sharding,
+        # so such a job keeps the per-round pad
+        self._rows_placed = (residency == "device"
+                             and self.mesh is not None and not self._pad)
+        self._reduction_sites = None
+        self._comm_nl: List = []
+        if self._rows_placed:
+            from ..parallel.mesh import shard_rows
+            from ..utils.timer import timed
+            with timed("train/place_rows", job=True,
+                       attrs={"rows": self.n,
+                              "devices": int(self.mesh.devices.size)}):
+                self.label = shard_rows(
+                    self.mesh, np.asarray(ds.get_label(), np.float32))
+                if w is not None:
+                    self.weight = shard_rows(
+                        self.mesh, np.asarray(w, np.float32))
+                self._row_w_ones = shard_rows(
+                    self.mesh, np.ones((self.n,), np.float32))
 
         seed = cfg.seed if cfg.seed is not None else 0
         self._base_key = jax.random.PRNGKey(seed)
@@ -769,6 +793,7 @@ class GBDTBooster:
         from ..parallel.data_parallel import make_dp_grow_fn
 
         cfg = self.cfg
+        self._reduction_sites = None      # re-read from the new trace
         return register_jit("parallel/dp_grow", make_dp_grow_fn(
             self.grow_cfg, self.mesh, self.monotone is not None,
             self.feat_is_cat is not None,
@@ -898,6 +923,39 @@ class GBDTBooster:
         (the fused path checks one iteration late); called by the train
         loop before returning the booster."""
         self._drain_guard_flags()
+        self._drain_reduction_counts()
+
+    def _drain_reduction_counts(self) -> None:
+        """Count the reductions of the mesh path's finished trees whose
+        leaf counts have reached the host (their async copies started a
+        round ago, like ``_nl_async``'s)."""
+        if self._comm_nl:
+            pending, self._comm_nl = self._comm_nl, []
+            for nl in pending:
+                self._count_reductions(int(np.asarray(nl)))
+
+    def _count_reductions(self, num_leaves: int) -> None:
+        """``hist_reductions`` / ``hist_wire_bytes{wire}`` for one
+        execution of the grow program that ended with ``num_leaves``
+        leaves: each histogram reduction site the program was traced
+        with (parallel/comms.py ``traced_reductions``) times how often
+        it ran — once a tree, once a split (``num_leaves - 1``), or,
+        the level grower's scatter batch, once a level (a balanced
+        tree's: the one site whose trips the leaf count does not give
+        exactly). A pool-miss recompute is traced but not counted."""
+        if not self._reduction_sites:
+            return
+        import math
+        from ..obs.registry import registry
+        splits = max(int(num_leaves) - 1, 0)
+        runs = {"tree": 1, "split": splits,
+                "level": math.ceil(math.log2(splits + 1))}
+        for per, wire, nbytes in self._reduction_sites:
+            k = runs.get(per, 0)
+            if k:
+                registry.counter("hist_reductions").inc(k)
+                registry.counter("hist_wire_bytes", wire=wire).inc(
+                    k * nbytes)
 
     def _run_with_oom_degrade(self, thunk, what: str):
         """Run a grow/fused dispatch with graceful OOM degradation:
@@ -906,6 +964,7 @@ class GBDTBooster:
         halving), rebuild the affected jitted programs and retry;
         re-raise as a clear LightGBMError once nothing is left to
         shed."""
+        rungs = 0
         while True:
             try:
                 self._fault_plan.maybe_oom(self.iter_)
@@ -913,12 +972,20 @@ class GBDTBooster:
             except Exception as e:
                 if not is_resource_exhausted(e):
                     raise
-                if not self._degrade_after_oom(e, what):
+                # under a mesh one rung, then the error: every rung is a
+                # recompile of the sharded grower (minutes at a chip's
+                # fill), and an allocation no rung can fit took 1,354 s
+                # to say so on one chip (ROADMAP S10)
+                spent = self.mesh is not None and rungs >= 1
+                if spent or not self._degrade_after_oom(e, what):
                     from ..basic import LightGBMError
                     raise LightGBMError(
                         f"device RESOURCE_EXHAUSTED in {what} at "
                         f"iteration {self.iter_} and no degradation "
-                        f"left to try: {e}") from e
+                        f"left to try"
+                        + (" under a mesh (one rung)" if spent else "")
+                        + f": {e}") from e
+                rungs += 1
 
     def _degrade_after_oom(self, exc, what: str) -> bool:
         """Apply one degradation step; False when exhausted."""
@@ -1444,6 +1511,8 @@ class GBDTBooster:
                 bag = (u < cfg.bagging_fraction).astype(jnp.float32)
             self._cached_bag = bag
             return bag
+        if self._rows_placed:
+            return self._row_w_ones          # placed at init, never donated
         return jnp.ones((n,), jnp.float32)
 
     _cached_bag: Optional[jnp.ndarray] = None
@@ -1991,6 +2060,7 @@ class GBDTBooster:
         # runs ahead of the device waits for it
         with timed("boosting/drain"):
             self._drain_guard_flags()
+            self._drain_reduction_counts()
 
         # checkpoint-restored no-growth marker: the snapshot's final
         # iteration grew nothing, so an uninterrupted run's next
@@ -2109,10 +2179,21 @@ class GBDTBooster:
                     args = args + (jax.random.fold_in(node_key, k),)
                 if self._bundle_dev is not None:
                     args = args + self._bundle_dev
+                first = self._reduction_sites is None
+                if first:
+                    from ..parallel import comms
+                    grow_plan.clear()
+                    del comms.traced_reductions[:]
                 with timed("tree_learner/grow"):
                     dev_tree, row_leaf = self._run_with_oom_degrade(
                         lambda: self._grow_fn(*args), "distributed grow")
-                row_leaf = row_leaf[: self.n]
+                if first:
+                    # what the grower resolved and which reductions it
+                    # traced, from the trace the first call just made
+                    self._grow_plan = dict(grow_plan)
+                    self._reduction_sites = tuple(comms.traced_reductions)
+                if self._pad:
+                    row_leaf = row_leaf[: self.n]
             else:
                 cegb_arrays = None
                 if self.cegb_enabled:
@@ -2156,6 +2237,7 @@ class GBDTBooster:
                     (dev_tree.num_leaves, k_flag))
                 num_leaves = int(nl_host)
                 sync_flag |= int(flag_host)
+                self._count_reductions(num_leaves)
             if num_leaves <= 1:
                 # constant tree; carries the boost_from_average bias when
                 # it is the first iteration (gbdt.cpp models_.size() check /
@@ -2220,6 +2302,8 @@ class GBDTBooster:
                     dev_tree)
                 self._defer_tree(vec, cmask, proto, dev_tree.num_leaves,
                                  shrinkage, bias)
+                if self.mesh is not None:
+                    self._comm_nl.append(dev_tree.num_leaves)
                 tree = None
             else:
                 if cfg.linear_tree:

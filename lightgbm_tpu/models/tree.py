@@ -254,15 +254,28 @@ class Tree:
         return t
 
 
+#: an int32 crosses in the f32 vector as two exact halves (the row
+#: counts of a mesh pass float32's 2**24): value = hi * _LO + lo
+_LO = 4096
+
+
 @jax.jit
 def pack_tree_device(t):
-    """Everything except the categorical bitmask as ONE f32 vector
-    (i32 fields are < 2^24 so the cast is lossless): a tree crosses
-    device->host in two transfers instead of one per field."""
+    """Everything except the categorical bitmask as ONE f32 vector: a
+    tree crosses device->host in two transfers instead of one per
+    field. An int32 field goes as two pieces (``>> 12``, ``& 4095``),
+    each exact in float32 whatever the value."""
     import jax.numpy as jnp
-    parts = [getattr(t, f) for f in t._fields if f != "split_cat_mask"]
-    vec = jnp.concatenate(
-        [jnp.ravel(p).astype(jnp.float32) for p in parts])
+    parts = []
+    for f in t._fields:
+        if f == "split_cat_mask":
+            continue
+        p = jnp.ravel(getattr(t, f))
+        if p.dtype == jnp.int32:
+            parts += [p >> 12, p & (_LO - 1)]
+        else:
+            parts.append(p)
+    vec = jnp.concatenate([p.astype(jnp.float32) for p in parts])
     return vec, t.split_cat_mask
 
 
@@ -276,9 +289,14 @@ def unpack_tree_host(vec, cmask, proto):
             continue
         arr = getattr(proto, f)
         sz = int(np.prod(arr.shape)) if arr.shape else 1
-        piece = vec[off:off + sz].astype(arr.dtype)
+        if arr.dtype == np.int32:
+            hi, lo = vec[off:off + sz], vec[off + sz:off + 2 * sz]
+            piece = hi.astype(np.int32) * _LO + lo.astype(np.int32)
+            off += 2 * sz
+        else:
+            piece = vec[off:off + sz].astype(arr.dtype)
+            off += sz
         fields[f] = piece.reshape(arr.shape) if arr.shape else piece[0]
-        off += sz
     fields["split_cat_mask"] = np.asarray(cmask)
     return type(proto)(**fields)
 
@@ -297,7 +315,7 @@ def tree_from_arrays(dev_tree, mappers: Sequence[BinMapper],
     bin-space thresholds as real values via the BinMappers."""
     # ONE device->host fetch for the whole tree: everything except the
     # categorical bitmask is packed into a single f32 vector on device
-    # (i32 fields are < 2^24 so the cast is lossless); per-field
+    # (int32 fields as two exact halves); per-field
     # np.asarray would pay a device round-trip per array (a dozen
     # pipeline stalls per boosting iteration)
     dev_tree = _fetch_tree_host(dev_tree)

@@ -202,6 +202,15 @@ METRICS = {
         "kind": "counter", "labels": ("mode", "wire"),
         "doc": "collective payload bytes by parallelism mode and "
                "hist_comm wire format"},
+    "hist_reductions": {
+        "kind": "counter", "labels": (),
+        "doc": "histogram reductions the mesh path's grow program ran: "
+               "the sites it was traced with (parallel/comms.py) times "
+               "their executions (once a tree, once a split)"},
+    "hist_wire_bytes": {
+        "kind": "counter", "labels": ("wire",),
+        "doc": "bytes one rank handed those reductions (the local "
+               "operand's size), by wire format"},
     "fused_scan_iterations": {
         "kind": "counter", "labels": (),
         "doc": "iterations that ran inside a fused scan window"},

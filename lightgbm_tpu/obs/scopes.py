@@ -43,7 +43,10 @@ __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
 #: the row gathers and word writes that apply it, the (g, h) payload's
 #: slices and writes), histogram build and sibling
 #: subtraction, the split scan, and ``grow/fixed``: what a split costs
-#: whatever its rows (tree and leaf bookkeeping, masks, bounds).
+#: whatever its rows (tree and leaf bookkeeping, masks, bounds). Under a
+#: mesh, the two collective layers: ``grow/hist/allreduce`` (every
+#: histogram reduction: parallel/comms.py) and ``grow/sums/allreduce``
+#: (root and leaf sums, counts, SplitInfo combines: bytes, not KB).
 DEVICE_SCOPES: Tuple[str, ...] = (
     "boost/gradients",
     "boost/grow",
@@ -55,6 +58,8 @@ DEVICE_SCOPES: Tuple[str, ...] = (
     "grow/partition/payload",
     "grow/hist/build",
     "grow/hist/subtract",
+    "grow/hist/allreduce",
+    "grow/sums/allreduce",
     "grow/split_scan",
     "grow/fixed",
 )

@@ -39,7 +39,7 @@ from .split import (SplitParams, SplitResult, constrained_output,
                     gain_at_output, leaf_gain, leaf_output)
 
 from .partition_kernel import route_concentrate
-from ..obs.scopes import scope
+from ..obs.scopes import scope, scoped
 
 __all__ = ["GrowConfig", "TreeArrays", "grow_tree", "route_concentrate"]
 
@@ -47,6 +47,16 @@ __all__ = ["GrowConfig", "TreeArrays", "grow_tree", "route_concentrate"]
 NEG_INF = -jnp.inf
 
 
+def _sums_psum(x, axis_name):
+    """Every reduction that is not a histogram's (those are
+    parallel/comms.py's, scope ``grow/hist/allreduce``): root and leaf
+    sums, row counts, votes, one histogram row broadcast from its
+    owner. Small, so a trace shows their latency, not their bytes."""
+    with scope("grow/sums/allreduce"):
+        return lax.psum(x, axis_name)
+
+
+@scoped("grow/sums/allreduce")
 def _combine_split_infos(r: SplitResult, axis_name) -> SplitResult:
     """SyncUpGlobalBestSplit (parallel_tree_learner.h:209-232):
     allreduce the max-gain SplitInfo across devices searching disjoint
@@ -216,10 +226,10 @@ class TreeArrays(NamedTuple):
     split_gain: jnp.ndarray      # [L-1] f32
     internal_value: jnp.ndarray  # [L-1] f32
     internal_weight: jnp.ndarray  # [L-1] f32
-    internal_count: jnp.ndarray  # [L-1] f32
+    internal_count: jnp.ndarray  # [L-1] i32 (exact: see _count)
     leaf_value: jnp.ndarray      # [L] f32
     leaf_weight: jnp.ndarray     # [L] f32 (sum of hessians)
-    leaf_count: jnp.ndarray      # [L] f32
+    leaf_count: jnp.ndarray      # [L] i32 (in-bag rows, exact)
     leaf_parent: jnp.ndarray     # [L] i32
     leaf_depth: jnp.ndarray      # [L] i32
     num_leaves: jnp.ndarray      # scalar i32 (actual leaves grown)
@@ -289,6 +299,19 @@ class _GrowState(NamedTuple):
                                # (hist_comm int8/int16; comms.py)
 
 
+def _count(x):
+    """A row count as the tree keeps it: int32. The growers reckon with
+    counts in float32 (the split search's estimates, the minimum-rows
+    checks), which holds a count exactly only up to 2**24 = 16,777,216
+    rows; a mesh's ranks together hold more (26,562,500 on one v5e host
+    of the Criteo job, where a root's larger child read one row off).
+    So the counts the tree RECORDS come from the integer sums and stay
+    integers to the model file; float estimates round in."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        return x.astype(jnp.int32)
+    return jnp.round(x).astype(jnp.int32)
+
+
 def _init_tree(L: int, B: int, dtype) -> TreeArrays:
     return TreeArrays(
         split_is_cat=jnp.zeros((L - 1,), jnp.bool_),
@@ -301,10 +324,10 @@ def _init_tree(L: int, B: int, dtype) -> TreeArrays:
         split_gain=jnp.zeros((L - 1,), dtype),
         internal_value=jnp.zeros((L - 1,), dtype),
         internal_weight=jnp.zeros((L - 1,), dtype),
-        internal_count=jnp.zeros((L - 1,), dtype),
+        internal_count=jnp.zeros((L - 1,), jnp.int32),
         leaf_value=jnp.zeros((L,), dtype),
         leaf_weight=jnp.zeros((L,), dtype),
-        leaf_count=jnp.zeros((L,), dtype),
+        leaf_count=jnp.zeros((L,), jnp.int32),
         leaf_parent=jnp.full((L,), -1, jnp.int32),
         leaf_depth=jnp.zeros((L,), jnp.int32),
         num_leaves=jnp.asarray(1, jnp.int32),
@@ -336,8 +359,9 @@ def _apply_split_to_tree(tree: TreeArrays, best: _BestSplits, leaf, R, ns,
                                    ns, rc[pidx]))
     lc = lc.at[ns].set(~leaf)
     rc = rc.at[ns].set(~R)
-    lcnt = best.left_count[leaf] if left_cnt is None else left_cnt
-    rcnt = best.right_count[leaf] if right_cnt is None else right_cnt
+    lcnt = _count(best.left_count[leaf] if left_cnt is None else left_cnt)
+    rcnt = _count(best.right_count[leaf] if right_cnt is None
+                  else right_cnt)
     parent_g = best.left_sum_g[leaf] + best.right_sum_g[leaf]
     parent_h = best.left_sum_h[leaf] + best.right_sum_h[leaf]
     parent_c = lcnt + rcnt
@@ -480,12 +504,9 @@ def _make_sharded_search(cfg: GrowConfig, F: int, qm: str,
 
     def hist_psum_ef(x, ef):
         x = rs_pad(x)
-        ax = x.ndim - 3
-        if not use_ef:
-            return lax.psum_scatter(
-                x, cfg.axis_name, scatter_dimension=ax,
-                tiled=True), ef
-        return comms.hist_reduce_scatter(x, cfg.axis_name, qm, ef, ax)
+        # ``ef`` is () at the exact f32 wire: passed through untouched
+        return comms.hist_reduce_scatter(x, cfg.axis_name, qm, ef,
+                                         x.ndim - 3)
 
     def owned_slice(v, fill):
         """This device's Fl-slice of a per-feature vector."""
@@ -520,7 +541,7 @@ def _grow_masked_impl(cfg: GrowConfig,
                and cfg.split_search == "sharded")
 
     def psum(x):
-        return lax.psum(x, cfg.axis_name) if cfg.axis_name else x
+        return _sums_psum(x, cfg.axis_name) if cfg.axis_name else x
 
     qm, use_ef, _gath_ef = comms.make_hist_psum_ef(
         cfg.axis_name, cfg.hist_comm)
@@ -554,19 +575,22 @@ def _grow_masked_impl(cfg: GrowConfig,
     inbag = row_weight > 0
     total_g = psum(jnp.sum(grad * w))
     total_h = psum(jnp.sum(hess * w))
-    total_c = psum(jnp.sum(inbag.astype(dtype)))
+    total_ci = psum(jnp.sum(inbag, dtype=jnp.int32))    # exact
+    total_c = total_ci.astype(dtype)
     all_rows = jnp.ones((n,), jnp.bool_)
     comm_ef0 = jnp.zeros((Fsp if sharded else F, B, 2), dtype) \
         if use_ef else ()
-    root_hist, comm_ef0 = hist_psum_ef(
-        build_histogram(bins_T, grad, hess, row_weight, all_rows, B,
-                        cfg.hist_method, cfg.hist_precision), comm_ef0)
+    with comms.reduction_site("tree"):
+        root_hist, comm_ef0 = hist_psum_ef(
+            build_histogram(bins_T, grad, hess, row_weight, all_rows, B,
+                            cfg.hist_method, cfg.hist_precision),
+            comm_ef0)
 
     tree = _init_tree(L, B, dtype)
     tree = tree._replace(
         leaf_value=tree.leaf_value.at[0].set(leaf_output(total_g, total_h, p)),
         leaf_weight=tree.leaf_weight.at[0].set(total_h),
-        leaf_count=tree.leaf_count.at[0].set(total_c),
+        leaf_count=tree.leaf_count.at[0].set(total_ci),
     )
     best = _BestSplits.init(L, B, dtype)
     best = best.store(0, best_for(root_hist, total_g, total_h, total_c),
@@ -601,14 +625,15 @@ def _grow_masked_impl(cfg: GrowConfig,
         on_leaf = row_leaf == leaf
         # exact partition counts replace the search-time hessian-ratio
         # estimates (SplitInner update_cnt, serial_tree_learner.cpp:789)
-        nl_ex = psum(jnp.sum((on_leaf & go_left & inbag).astype(dtype)))
-        nr_ex = tree.leaf_count[leaf] - nl_ex
+        nl_i = psum(jnp.sum(on_leaf & go_left & inbag, dtype=jnp.int32))
+        nr_i = tree.leaf_count[leaf] - nl_i
+        nl_ex, nr_ex = nl_i.astype(dtype), nr_i.astype(dtype)
         row_leaf = jnp.where(on_leaf & ~go_left, R, row_leaf)
 
         # -- tree arrays update (Tree::Split, tree.h:63) --
         new_depth = tree.leaf_depth[leaf] + 1
         tree = _apply_split_to_tree(tree, best, leaf, R, ns, p,
-                                    nl_ex, nr_ex)
+                                    nl_i, nr_i)
 
         # -- histograms: scatter the smaller child, subtract for sibling --
         left_smaller = nl_ex <= nr_ex
@@ -735,7 +760,7 @@ def _grow_level_impl(cfg: GrowConfig,
                and cfg.split_search == "sharded")
 
     def psum(x):
-        return lax.psum(x, cfg.axis_name) if cfg.axis_name else x
+        return _sums_psum(x, cfg.axis_name) if cfg.axis_name else x
 
     qm, use_ef, _gath_ef = comms.make_hist_psum_ef(
         cfg.axis_name, cfg.hist_comm)
@@ -775,36 +800,29 @@ def _grow_level_impl(cfg: GrowConfig,
     gh = jnp.stack([grad * w, hess * w], axis=-1)          # [n, 2]
     total_g = psum(jnp.sum(gh[:, 0]))
     total_h = psum(jnp.sum(gh[:, 1]))
-    total_c = psum(jnp.sum(inbag.astype(dtype)))
+    total_ci = psum(jnp.sum(inbag, dtype=jnp.int32))    # exact
+    total_c = total_ci.astype(dtype)
     all_rows = jnp.ones((n,), jnp.bool_)
-    comm_ef0 = ()
     FE = Fsp if sharded else F        # EF feature width (scatter-padded)
-    if use_ef:
-        # EF shape follows the reduction the path issues (_LevelState)
-        if hmethod == "scatter":
+    root_local = build_histogram(bins_T, grad, hess, row_weight, all_rows,
+                                 B, hmethod, cfg.hist_precision)
+    with comms.reduction_site("tree"):
+        if use_ef and hmethod == "scatter":
+            # EF shape follows the reduction the path issues
+            # (_LevelState): one slot a leaf
             comm_ef0 = jnp.zeros((L, FE, B, 2), dtype)
-            root_hist, ef_slot0 = hist_psum_ef(
-                build_histogram(bins_T, grad, hess, row_weight,
-                                all_rows, B, hmethod,
-                                cfg.hist_precision),
-                comm_ef0[0])
+            root_hist, ef_slot0 = hist_psum_ef(root_local, comm_ef0[0])
             comm_ef0 = comm_ef0.at[0].set(ef_slot0)
         else:
             root_hist, comm_ef0 = hist_psum_ef(
-                build_histogram(bins_T, grad, hess, row_weight,
-                                all_rows, B, hmethod,
-                                cfg.hist_precision),
-                jnp.zeros((FE, B, 2), dtype))
-    else:
-        root_hist, _ = hist_psum_ef(
-            build_histogram(bins_T, grad, hess, row_weight, all_rows,
-                            B, hmethod, cfg.hist_precision), ())
+                root_local,
+                jnp.zeros((FE, B, 2), dtype) if use_ef else ())
     tree = _init_tree(L, B, dtype)
     tree = tree._replace(
         leaf_value=tree.leaf_value.at[0].set(
             leaf_output(total_g, total_h, p)),
         leaf_weight=tree.leaf_weight.at[0].set(total_h),
-        leaf_count=tree.leaf_count.at[0].set(total_c),
+        leaf_count=tree.leaf_count.at[0].set(total_ci),
     )
     best = _BestSplits.init(L, B, dtype)
     best = best.store(0, best_for(root_hist, total_g, total_h, total_c),
@@ -852,12 +870,12 @@ def _grow_level_impl(cfg: GrowConfig,
                     gl = jnp.where(best.is_cat[l], best.cat_mask[l][col],
                                    gl)
                 on_leaf = row_leaf == l
-                nl_ex = psum(jnp.sum(
-                    (on_leaf & gl & inbag).astype(dtype)))
-                nr_ex = tree.leaf_count[l] - nl_ex
+                nl_i = psum(jnp.sum(on_leaf & gl & inbag,
+                                    dtype=jnp.int32))
                 row_leaf = jnp.where(on_leaf & ~gl, R, row_leaf)
-                tree = _apply_split_to_tree(tree, best, l, R,
-                                            node_ids[l], p, nl_ex, nr_ex)
+                tree = _apply_split_to_tree(
+                    tree, best, l, R, node_ids[l], p, nl_i,
+                    tree.leaf_count[l] - nl_i)
                 return tree, best, row_leaf
 
             # COLLECTIVE-IN-COND INVARIANT (data-parallel): the taken
@@ -896,9 +914,10 @@ def _grow_level_impl(cfg: GrowConfig,
                 return carry, h
 
             _, h_f = lax.scan(seg_body, None, bins_T)    # [F, L*B, 2]
-            small_hists, comm_ef = hist_psum_ef(
-                h_f.reshape(F, L, B, 2).transpose(1, 0, 2, 3),
-                comm_ef)
+            with comms.reduction_site("level"):
+                small_hists, comm_ef = hist_psum_ef(
+                    h_f.reshape(F, L, B, 2).transpose(1, 0, 2, 3),
+                    comm_ef)
         else:
             # MXU / Pallas kernels have no segment axis: one masked
             # kernel pass per small child, cond-skipped for idle
@@ -957,14 +976,14 @@ def _grow_level_impl(cfg: GrowConfig,
             # bin sequence the gathered path sums (hists[:, 0] on a
             # chunk is a different feature per device: same total,
             # different addition order, hence different last-ulp bits)
-            row0 = lax.psum(
+            row0 = _sums_psum(
                 jnp.where(dev_idx == 0, hists[:, 0],
                           jnp.zeros_like(hists[:, 0])), cfg.axis_name)
             sums = row0.sum(axis=1)                      # [L, 2]
         else:
             sums = hists[:, 0].sum(axis=1)               # [L, 2]
         r = jax.vmap(best_for)(hists, sums[:, 0], sums[:, 1],
-                               tree.leaf_count)
+                               tree.leaf_count.astype(dtype))
         is_child = (slots < tree.num_leaves) \
             & (tree.leaf_depth == level + 1)
         allowed = is_child & depth_ok(level + 1)
@@ -1241,7 +1260,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         (rows are replicated there)."""
         if cfg.axis_name is None or fp:
             return x
-        return lax.psum(x, cfg.axis_name)
+        return _sums_psum(x, cfg.axis_name)
 
     # histogram wire format (parallel/comms.py): quantized exchange
     # only where a histogram reduction actually happens — data-parallel
@@ -1483,7 +1502,7 @@ def _grow_compact_impl(cfg: GrowConfig,
             k = min(cfg.voting_top_k, F)
             kth = jnp.sort(fgains)[F - k]
             ballot = jnp.isfinite(fgains) & (fgains >= kth)
-            votes = lax.psum(ballot.astype(jnp.int32), ax)
+            votes = _sums_psum(ballot.astype(jnp.int32), ax)
             k2 = min(2 * cfg.voting_top_k, F)
             # deterministic election, identical on every device: vote
             # count, ties to the lower feature id (GlobalVoting,
@@ -2277,8 +2296,8 @@ def _grow_compact_impl(cfg: GrowConfig,
         # exact global in-bag child counts replace the search-time
         # hessian-ratio estimates (SplitInner update_cnt,
         # serial_tree_learner.cpp:789-791)
-        nl_ex = psum(n_left_ib).astype(dtype)
-        nr_ex = psum(n_ib - n_left_ib).astype(dtype)
+        nl_ex = psum(n_left_ib)              # int32: the tree's record
+        nr_ex = psum(n_ib - n_left_ib)
         est_hist, comm_ef = hist_psum_ef(est_hist, comm_ef)
         return (bins2, pay2, ord2, lazy_used, n_left, nl_ex, nr_ex,
                 est_hist, est_nu, comm_ef)
@@ -2307,8 +2326,11 @@ def _grow_compact_impl(cfg: GrowConfig,
                 b_off = nb * BK
             else:
                 b_off = jnp.asarray(0, jnp.int32)
-            return hist_psum(lax.fori_loop(
-                0, window_chunks(cnt - b_off), make_body(K, b_off), acc0))
+            # runs on a histogram-pool miss only: listed, not counted
+            with comms.reduction_site("pool_miss"):
+                return hist_psum(lax.fori_loop(
+                    0, window_chunks(cnt - b_off), make_body(K, b_off),
+                    acc0))
 
     # the streamed copy of the bin matrix lives PACKED: u32 words of
     # pack_w bin columns each (u8 arrays carry a (4,1) sub-byte tiling
@@ -2326,17 +2348,19 @@ def _grow_compact_impl(cfg: GrowConfig,
     # feature-parallel devices histogram only their own feature block
     root_rows = _local_hist_rows(bins_pk, jnp.asarray(0, jnp.int32),
                                  n) if fp else bins_rm
-    total_c = psum(jnp.sum(inbag.astype(dtype)))
+    total_ci = psum(jnp.sum(inbag, dtype=jnp.int32))    # exact
+    total_c = total_ci.astype(dtype)
     comm_ef0 = jnp.zeros((Fsp if sharded else FB, B, C),
                          dtype) if use_ef else ()
     if quant:
-        root_hist = hist_psum(hist_from_rows_int(root_rows, gw2_q, B,
-                                                 hmethod))
+        with comms.reduction_site("tree"):
+            root_hist = hist_psum(hist_from_rows_int(root_rows, gw2_q, B,
+                                                     hmethod))
         if sharded:
             # the GLOBAL feature-0 row lives on device 0's chunk only;
             # broadcast it (exact int32 psum of one contributor) and
             # sum the same bin sequence the gathered path sums
-            row0 = lax.psum(
+            row0 = _sums_psum(
                 jnp.where(dev_idx == 0, root_hist[0],
                           jnp.zeros_like(root_hist[0])), cfg.axis_name)
             sums = (row0.astype(dtype) * scale2[None, :]).sum(axis=0)
@@ -2344,20 +2368,21 @@ def _grow_compact_impl(cfg: GrowConfig,
             sums = hist_f(root_hist)[0].sum(axis=0)  # row hits feature 0
         if vp:
             # voting keeps the cache local; the root tuple is global
-            sums = lax.psum(sums, cfg.axis_name)
+            sums = _sums_psum(sums, cfg.axis_name)
         total_g, total_h = sums[0], sums[1]
     else:
         total_g = psum(jnp.sum(gw2[:, 0]))
         total_h = psum(jnp.sum(gw2[:, 1]))
-        root_hist, comm_ef0 = hist_psum_ef(
-            hist_from_rows(root_rows, gw2, B, hmethod,
-                           cfg.hist_precision), comm_ef0)
+        with comms.reduction_site("tree"):
+            root_hist, comm_ef0 = hist_psum_ef(
+                hist_from_rows(root_rows, gw2, B, hmethod,
+                               cfg.hist_precision), comm_ef0)
 
     tree = _init_tree(L, B, dtype)
     tree = tree._replace(
         leaf_value=tree.leaf_value.at[0].set(leaf_output(total_g, total_h, p)),
         leaf_weight=tree.leaf_weight.at[0].set(total_h),
-        leaf_count=tree.leaf_count.at[0].set(total_c),
+        leaf_count=tree.leaf_count.at[0].set(total_ci),
     )
     best = _BestSplits.init(L, B, dtype)
     root_mask = None if interaction_groups is None \
@@ -2469,8 +2494,8 @@ def _grow_compact_impl(cfg: GrowConfig,
         pen_l = None
         if cegb:
             coupled_used, _, lazy_nu = cegb_st
-            pen_l = cegb_penalty(tree.leaf_count[l], coupled_used,
-                                 lazy_nu[l])
+            pen_l = cegb_penalty(tree.leaf_count[l].astype(dtype),
+                                 coupled_used, lazy_nu[l])
         bounds_l = None
         if has_mono:
             if advanced:
@@ -2529,7 +2554,7 @@ def _grow_compact_impl(cfg: GrowConfig,
                 # device sums the bit-identical bin sequence the
                 # gathered path sums (hf[0] on a chunk is a different
                 # feature per device — same total, different last-ulp)
-                row0 = lax.psum(
+                row0 = _sums_psum(
                     jnp.where(dev_idx == 0, hf[0], jnp.zeros_like(hf[0])),
                     cfg.axis_name)
                 sums = row0.sum(axis=0)
@@ -2537,7 +2562,8 @@ def _grow_compact_impl(cfg: GrowConfig,
                 sums = hf[0].sum(axis=0)
             mask_l, pen_l, bounds_l = _leaf_mask_pen_bounds(
                 tree, branch, cegb_st, mono_st, nmask_st, l)
-            r = best_for(hf, sums[0], sums[1], tree.leaf_count[l],
+            r = best_for(hf, sums[0], sums[1],
+                         tree.leaf_count[l].astype(dtype),
                          mask_l, pen_l, tree.leaf_value[l],
                          tree.leaf_depth[l], bounds_l)
             active = (l < tree.num_leaves) \
@@ -2560,14 +2586,15 @@ def _grow_compact_impl(cfg: GrowConfig,
         hf = jax.vmap(hist_f)(hists)              # [L, F, B, 2]
         if sharded:
             # global feature-0 rows via device 0 (see _research_leafwise)
-            row0 = lax.psum(
+            row0 = _sums_psum(
                 jnp.where(dev_idx == 0, hf[:, 0],
                           jnp.zeros_like(hf[:, 0])), cfg.axis_name)
             sums = row0.sum(axis=1)               # [L, 2]
         else:
             sums = hf[:, 0].sum(axis=1)           # [L, 2]
         in_axes = [0, 0, 0, 0]
-        args = [hf, sums[:, 0], sums[:, 1], tree.leaf_count]
+        leaf_cnt = tree.leaf_count.astype(dtype)
+        args = [hf, sums[:, 0], sums[:, 1], leaf_cnt]
         masks = None if interaction_groups is None \
             else jax.vmap(allowed_features)(branch)
         if use_bynode:
@@ -2577,7 +2604,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         if cegb:
             coupled_used, _, lazy_nu = cegb_st
             pens = jax.vmap(cegb_penalty,
-                            in_axes=(0, None, 0))(tree.leaf_count,
+                            in_axes=(0, None, 0))(leaf_cnt,
                                                   coupled_used, lazy_nu)
         else:
             pens = None
@@ -2647,7 +2674,7 @@ def _grow_compact_impl(cfg: GrowConfig,
 
         # -- partition the leaf's range (DataPartition::Split analog) +
         # child histogram, fused into one streaming pass --
-        (bins2, pay2, ord2, lazy_arr, n_left, nl_ex, nr_ex, est_hist,
+        (bins2, pay2, ord2, lazy_arr, n_left, nl_i, nr_i, est_hist,
          est_nu, comm_ef) = part_apply(bins2, pay2, ord2, lazy_arr,
                                        src, start, cnt, f_split, t_bin,
                                        dl, isc, cm, est_left_small,
@@ -2660,7 +2687,8 @@ def _grow_compact_impl(cfg: GrowConfig,
 
         new_depth = tree.leaf_depth[leaf] + 1
         tree = _apply_split_to_tree(tree, best, leaf, R, ns, p,
-                                    nl_ex, nr_ex)
+                                    nl_i, nr_i)
+        nl_ex, nr_ex = nl_i.astype(dtype), nr_i.astype(dtype)
 
         with scope("grow/hist/subtract"):
             other_hist = subtract_histogram(parent_hist, est_hist)
@@ -2907,7 +2935,7 @@ def _grow_compact_impl(cfg: GrowConfig,
             # the GLOBAL feature-0 row lives on device 0's chunk only
             # (see _research_leafwise) — broadcast, then sum the same
             # bin sequence the gathered path sums
-            row0 = lax.psum(
+            row0 = _sums_psum(
                 jnp.where(dev_idx == 0, hist[0], jnp.zeros_like(hist[0])),
                 cfg.axis_name)
             totals = jnp.sum(row0, axis=0)
@@ -2924,13 +2952,13 @@ def _grow_compact_impl(cfg: GrowConfig,
                 (fcol >= f_start) & (fcol < f_start + Fl)
             lf = jnp.clip(fcol - f_start, 0, Fl - 1)
             h_loc = lax.dynamic_index_in_dim(hist, lf, keepdims=False)
-            h = lax.psum(jnp.where(own, h_loc, 0.0), cfg.axis_name)
+            h = _sums_psum(jnp.where(own, h_loc, 0.0), cfg.axis_name)
         elif vp:
             # voting keeps per-device caches local; a forced (feature,
             # bin) needs the GLOBAL row — one [B, 2] psum
-            h = lax.psum(hist[fcol], cfg.axis_name)
-            tg = lax.psum(tg, cfg.axis_name)
-            th = lax.psum(th, cfg.axis_name)
+            h = _sums_psum(hist[fcol], cfg.axis_name)
+            tg = _sums_psum(tg, cfg.axis_name)
+            th = _sums_psum(th, cfg.axis_name)
         else:
             h = hist[fcol]                         # [B, 2]
         binsb = jnp.arange(B)
@@ -2994,7 +3022,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         else:
             hist_l = state.hists[leaf]
         r = forced_result(hist_f(hist_l),
-                          state.tree.leaf_count[leaf], f, t,
+                          state.tree.leaf_count[leaf].astype(dtype), f, t,
                           state.tree.leaf_value[leaf], bnds)
         valid = ok & (r.left_count > 0) & (r.right_count > 0)
         forced_state = state._replace(best=state.best.store(leaf, r,

@@ -70,11 +70,14 @@ thresholds where quantization buys nothing.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..obs.scopes import scope, scoped
 
 __all__ = [
     "BLOCK", "QMAX", "WIRE_ITEMSIZE", "hist_allreduce",
@@ -199,6 +202,38 @@ def _allreduce_shared_psum(blocks, axis_name, qmax, wire_dtype, dtype):
     return out, sent
 
 
+#: the histogram reductions of the program being traced, one record a
+#: reduction SITE: ``(per, wire, bytes)``. ``per`` says how often the
+#: site runs in one execution of the program — ``"tree"`` (the root's),
+#: ``"split"`` (once a split: the smaller child's) or ``"level"`` (the
+#: level grower's scatter batch) — and is set by the grower around its
+#: call (:func:`reduction_site`); ``bytes`` is the local operand's
+#: size, what one rank hands the collective. Filled at TRACE time only:
+#: the engine clears it before the first call of its grow program and
+#: keeps a copy after (models/gbdt.py ``_reduction_sites``), then
+#: multiplies by what executed into ``hist_reductions`` /
+#: ``hist_wire_bytes{wire}``.
+traced_reductions: list = []
+_site_per = ["split"]
+
+
+@contextlib.contextmanager
+def reduction_site(per: str):
+    """``with reduction_site("tree"):`` around a grower's reduction
+    that does not run once a split."""
+    _site_per.append(per)
+    try:
+        yield
+    finally:
+        _site_per.pop()
+
+
+def _note_reduction(x, wire: str) -> None:
+    traced_reductions.append(
+        (_site_per[-1], wire, x.size * x.dtype.itemsize))
+
+
+@scoped("grow/hist/allreduce")
 def hist_allreduce(x: jnp.ndarray, axis_name, mode: str = "f32",
                    error_feedback: Optional[jnp.ndarray] = None,
                    strategy: str = "auto"):
@@ -232,8 +267,10 @@ def hist_allreduce(x: jnp.ndarray, axis_name, mode: str = "f32",
 
     if axis_name is None:
         return ret(x, error_feedback)
-    if mode not in ("int8", "int16") \
-            or not jnp.issubdtype(x.dtype, jnp.floating):
+    quantized = mode in ("int8", "int16") \
+        and jnp.issubdtype(x.dtype, jnp.floating)
+    _note_reduction(x, mode if quantized else "f32")
+    if not quantized:
         return ret(lax.psum(x, axis_name), error_feedback)
     D = lax.axis_size(axis_name)
     if D == 1:
@@ -311,7 +348,9 @@ def make_hist_psum_ef(axis_name, hist_comm: str, quantize: bool = True):
         if axis_name is None:
             return x, ef
         if not use_ef:
-            return lax.psum(x, axis_name), ef
+            _note_reduction(x, "f32")
+            with scope("grow/hist/allreduce"):
+                return lax.psum(x, axis_name), ef
         return hist_allreduce(x, axis_name, qm, ef)
 
     return qm, use_ef, hist_psum_ef
@@ -321,6 +360,7 @@ def make_hist_psum_ef(axis_name, hist_comm: str, quantize: bool = True):
 # the reduce-scatter primitive (sharded split search)
 # ---------------------------------------------------------------------
 
+@scoped("grow/hist/allreduce")
 def hist_reduce_scatter(x: jnp.ndarray, axis_name, mode: str = "f32",
                         error_feedback: Optional[jnp.ndarray] = None,
                         scatter_axis: int = 0):
@@ -364,8 +404,10 @@ def hist_reduce_scatter(x: jnp.ndarray, axis_name, mode: str = "f32",
 
     if axis_name is None:
         return ret(x, error_feedback)
-    if mode not in ("int8", "int16") \
-            or not jnp.issubdtype(x.dtype, jnp.floating):
+    quantized = mode in ("int8", "int16") \
+        and jnp.issubdtype(x.dtype, jnp.floating)
+    _note_reduction(x, mode if quantized else "f32")
+    if not quantized:
         chunk = lax.psum_scatter(x, axis_name,
                                  scatter_dimension=scatter_axis,
                                  tiled=True)

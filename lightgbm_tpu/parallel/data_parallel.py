@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.scopes import scope
 from ..ops.grow import GrowConfig, grow_tree_impl
 
 __all__ = ["make_dp_grow_fn"]
@@ -84,9 +85,12 @@ def _build(cfg: GrowConfig, mesh: Mesh, has_monotone: bool, has_cat: bool,
             rest = rest[3:]
         nkey = rest.pop(0) if has_node_key else None
         bundle = tuple(rest[:8]) if has_bundle else None
-        return grow_tree_impl(cfg, bins_T, grad, hess, row_w, fmask,
-                              fnb, fnan, mono, cat, qkey, groups, forced,
-                              None, nkey, bundle)
+        # the fused step's name for the grower as a whole, so that what
+        # it does outside its split loop has a scope on this path too
+        with scope("boost/grow"):
+            return grow_tree_impl(cfg, bins_T, grad, hess, row_w, fmask,
+                                  fnb, fnan, mono, cat, qkey, groups,
+                                  forced, None, nkey, bundle)
 
     sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs, check_vma=False)

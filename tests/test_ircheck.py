@@ -238,14 +238,15 @@ def test_mutation_full_psum_trips_collective_budget(tmp_path):
     """Regressing sharded search to a full psum (+ slice) multiplies
     the post-reduction payload ~D x past the committed budget."""
     fids = _mutated_lint(
-        tmp_path, "ops/grow.py",
-        "            return lax.psum_scatter(\n"
-        "                x, cfg.axis_name, scatter_dimension=ax,\n"
-        "                tiled=True), ef\n",
-        "            full = lax.psum(x, cfg.axis_name)\n"
-        "            return lax.dynamic_slice_in_dim(\n"
-        "                full, dev_idx * (x.shape[ax] // D_sh),\n"
-        "                x.shape[ax] // D_sh, axis=ax), ef\n",
+        tmp_path, "parallel/comms.py",
+        "        chunk = lax.psum_scatter(x, axis_name,\n"
+        "                                 scatter_dimension=scatter_axis,\n"
+        "                                 tiled=True)\n",
+        "        full = lax.psum(x, axis_name)\n"
+        "        per = x.shape[scatter_axis] // lax.axis_size(axis_name)\n"
+        "        chunk = lax.dynamic_slice_in_dim(\n"
+        "            full, lax.axis_index(axis_name) * per, per,\n"
+        "            axis=scatter_axis)\n",
         "parallel/dp_grow@wide-sharded")
     assert ("TPL012:parallel/data_parallel.py:make_dp_grow_fn:"
             "ir-budget#1") in fids, fids

@@ -50,37 +50,33 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(
-        one_chip, no_compile_cache):
-    """The benchmark cell's grower at its width (67 columns, 255 leaves,
-    256 bins, mxu / high) and 200,000 rows. The float32 (g, h) payload
-    of the wide partition is one 1-D planar buffer, which has a single
-    possible layout: as a 2-D ``[2(n+2K), 2]`` carry the partition loop
-    and the child-histogram loop each chose their own, and the compiler
-    put a copy of the WHOLE buffer between them, once a split (58% of
-    the round on the v5e, ledger PR 26). No copy in the program may be
-    that large, and the buffer must appear under one layout."""
+F, ROWS = 67, 200_000          # the benchmark's width; rows a chip
+
+
+def grow_cfg(**over):
     import lightgbm_tpu.ops.grow as growmod
     from lightgbm_tpu.ops.split import SplitParams
-    F, n = 67, 200_000
-    cfg = growmod.GrowConfig(
+    return growmod.GrowConfig(
         num_leaves=255, num_bins=256,
         split=SplitParams(min_data_in_leaf=20.0), grower="compact",
-        hist_method="mxu", hist_precision="high", track_rows=False)
+        hist_method="mxu", hist_precision="high", track_rows=False,
+        **over)
 
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    growmod.last_plan.clear()
-    compiled = jax.jit(functools.partial(growmod.grow_tree_impl, cfg)) \
-        .trace(sds((F, n), jnp.uint8), sds((n,), jnp.float32),
-               sds((n,), jnp.float32), sds((n,), jnp.float32),
-               sds((F,), jnp.bool_), sds((F,), jnp.int32),
-               sds((F,), jnp.int32)) \
-        .lower(lowering_platforms=("tpu",)).compile()
-    assert growmod.last_plan == {"partition": "wide",
-                                 "payload": "f32-planar"}
-    hlo = compiled.as_text()
+def grower_args(n, rows, rep, cols):
+    """The grower's seven arguments as shapes: the bin matrix sharded
+    ``cols``, the three per-row vectors ``rows``, the rest ``rep``."""
+    def sds(shape, dt, sharding):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    return (sds((F, n), jnp.uint8, cols), sds((n,), jnp.float32, rows),
+            sds((n,), jnp.float32, rows), sds((n,), jnp.float32, rows),
+            sds((F,), jnp.bool_, rep), sds((F,), jnp.int32, rep),
+            sds((F,), jnp.int32, rep))
+
+
+def assert_planar_payload_and_no_whole_copy(hlo, cfg, n):
+    """No f32 copy as large as the payload, the planar buffer under one
+    layout, the 2-D form gone (``n``: rows a chip)."""
     rows2 = 2 * (n + 2 * cfg.chunk)                    # 2(n+2K)
     copies = re.findall(r"= (f32\[([\d,]+)\]\S*) copy\(", hlo)
     assert copies, "the optimized HLO names no f32 copy at all: the " \
@@ -90,5 +86,85 @@ def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(
     assert not whole, whole
     layouts = set(re.findall(r"f32\[%d\](\{[^}]*\})" % (2 * rows2), hlo))
     assert len(layouts) == 1, layouts
-    # and the 2-D form is gone from the program altogether
     assert not re.search(r"f32\[%d,2\]" % rows2, hlo)
+
+
+@pytest.fixture(scope="module")
+def meshless(one_chip):
+    """The compact grower compiled for one described chip: ``(compiled,
+    what the grower resolved, its config)``. Module-scoped: the
+    mesh-less test reads it and the mesh test measures against it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    import lightgbm_tpu.ops.grow as growmod
+    keep = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        cfg = grow_cfg()
+        growmod.last_plan.clear()
+        compiled = jax.jit(functools.partial(growmod.grow_tree_impl, cfg)) \
+            .trace(*grower_args(ROWS, one_chip, one_chip, one_chip)) \
+            .lower(lowering_platforms=("tpu",)).compile()
+        return compiled, dict(growmod.last_plan), cfg
+    finally:
+        jax.config.update("jax_enable_compilation_cache", keep)
+        cc.reset_cache()
+
+
+def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(meshless):
+    """The benchmark cell's grower at its width (67 columns, 255 leaves,
+    256 bins, mxu / high) and 200,000 rows. The float32 (g, h) payload
+    of the wide partition is one 1-D planar buffer, which has a single
+    possible layout: as a 2-D ``[2(n+2K), 2]`` carry the partition loop
+    and the child-histogram loop each chose their own, and the compiler
+    put a copy of the WHOLE buffer between them, once a split (58% of
+    the round on the v5e, ledger PR 26). No copy in the program may be
+    that large, and the buffer must appear under one layout."""
+    compiled, plan, cfg = meshless
+    assert plan == {"partition": "wide", "payload": "f32-planar"}
+    assert_planar_payload_and_no_whole_copy(compiled.as_text(), cfg, ROWS)
+
+
+def test_four_rank_grower_keeps_the_layout_and_reduces_twice_a_split(
+        topo, meshless, no_compile_cache):
+    """``parallel/dp_grow`` for the described four-chip host at
+    ``criteo256x4.train``'s width, 200,000 rows a chip: under
+    ``shard_map`` the grower resolves what the mesh-less one does (wide
+    partition, planar payload under one layout, no payload-sized copy),
+    its scratch a chip is the mesh-less grower's (the collectives add a
+    histogram's worth), and a split costs two all-reduces: the child
+    counts (two integers in one) and the smaller child's histogram; the
+    root costs two a tree (its float sums ride its histogram's, its
+    integer row count goes alone). At the cell's own 6,640,625 rows a
+    chip the same compile gave temp 10,299,808,768 B a chip against
+    10,299,786,752 mesh-less (sandbox compile, PR 29)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import lightgbm_tpu.ops.grow as growmod
+    from lightgbm_tpu.parallel.data_parallel import make_dp_grow_fn
+    one, _, cfg1 = meshless
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    ranks = mesh.devices.size
+    cfg = grow_cfg(parallel_mode="data", hist_comm="f32")
+    growmod.last_plan.clear()
+    compiled = make_dp_grow_fn(cfg, mesh).trace(*grower_args(
+        ranks * ROWS, NamedSharding(mesh, P("data")),
+        NamedSharding(mesh, P()), NamedSharding(mesh, P(None, "data")))) \
+        .lower(lowering_platforms=("tpu",)).compile()
+    assert growmod.last_plan == {"partition": "wide",
+                                 "payload": "f32-planar"}
+    hlo = compiled.as_text()
+    assert_planar_payload_and_no_whole_copy(hlo, cfg, ROWS)
+    reduces = [(m.group(1), m.group(2)) for m in (
+        re.search(r" = (.*?) all-reduce(?:-start)?\(.*op_name=\"([^\"]*)\"",
+                  line) for line in hlo.splitlines()) if m]
+    in_loop = [shape for shape, op in reduces if "while/body" in op]
+    assert len(reduces) == 4 and len(in_loop) == 2, reduces
+    hist = [shape for shape in in_loop
+            if shape.startswith("f32[%d,%d,2]" % (F, cfg.num_bins))]
+    assert len(hist) == 1, in_loop
+    assert all("grow/hist/allreduce" in op or "grow/sums/allreduce" in op
+               for _, op in reduces), reduces
+    temp, temp1 = (c.memory_analysis().temp_size_in_bytes
+                   for c in (compiled, one))
+    assert abs(temp - temp1) <= 0.05 * temp1, (temp, temp1)
