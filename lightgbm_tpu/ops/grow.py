@@ -1919,15 +1919,17 @@ def _grow_compact_impl(cfg: GrowConfig,
     SEG = n + 2 * PAD  # rows per ping-pong half (PAD rows both sides)
 
     # WIDE partition mode (round 5): at EFB width the per-chunk
-    # partition permutes rows with a (key, iota) sort + row GATHERS of
-    # the packed words instead of carrying all NW word columns through
-    # the variadic sort (which costs O(NW) traffic per bitonic stage —
-    # 0.77 ms/chunk at NW=167 vs 35 us at Higgs width). The gather and
-    # its DUS writeback want the ROW-MAJOR layout, while the histogram
-    # one-hot wants rows minor; storing bins2 FLAT (1-D) pins the
-    # row-major linearization globally, so XLA relayouts only
-    # chunk-sized hist inputs instead of transposing the whole
-    # multi-hundred-MB ping-pong buffer twice per chunk (measured
+    # partition permutes rows with a (key, iota) sort + ONE row GATHER a
+    # chunk of the packed words (payload and ord folded in behind them)
+    # instead of carrying all NW word columns through the variadic sort
+    # (which costs O(NW) traffic per bitonic stage — 0.77 ms/chunk at
+    # NW=167 vs 35 us at Higgs width). Both children are written from
+    # that one gathered block, the rights placed by the write's offset
+    # (make_body). The gather and its DUS writeback want the ROW-MAJOR
+    # layout, while the histogram one-hot wants rows minor; storing
+    # bins2 FLAT (1-D) pins the row-major linearization globally, so XLA
+    # relayouts only chunk-sized hist inputs instead of transposing the
+    # whole multi-hundred-MB ping-pong buffer twice per chunk (measured
     # in-situ: the whole-buffer copies were 1.7 s/tree at 131K x 665).
     # (the 2**31 guard: flat offsets are int32 products pos*NW — past
     # ~2^31 elements they would wrap and silently corrupt the
@@ -1962,20 +1964,22 @@ def _grow_compact_impl(cfg: GrowConfig,
         return lax.dynamic_slice(
             w32, (pos0, jnp.zeros((), pos0.dtype)), (CK, NW))
 
-    def _bins_write(arr, off, block, m):
-        """Masked RMW of a [CK, NW] block at row offset ``off``
-        (the wide mode addresses the flat buffer)."""
+    def _bins_write(arr, off, block, lo, hi):
+        """Masked RMW of rows [lo, hi) of a CK-row block of packed words
+        at row offset ``off``. The wide mode takes the block FLAT
+        (``u32[CK*NW]``, flattened once by the caller for both of its
+        writes) and selects in the flat domain: the flat buffer's slice
+        is never re-tiled to ``[CK, NW]``, whose 17-word minor dimension
+        is padded to 128 lanes, and back."""
+        i = jnp.arange(block.shape[0])
         if not wide_part:
             z = jnp.zeros((), off.dtype)
             cur = lax.dynamic_slice(arr, (off, z), block.shape)
-            out = jnp.where(m[:, None], block, cur)
+            out = jnp.where(((i >= lo) & (i < hi))[:, None], block, cur)
             return lax.dynamic_update_slice(arr, out, (off, z))
-        CK = block.shape[0]
-        cur = lax.dynamic_slice(
-            arr, (off * NW,), (CK * NW,)).reshape(CK, NW)
-        out = jnp.where(m[:, None], block, cur)
-        return lax.dynamic_update_slice(arr, out.reshape(-1),
-                                        (off * NW,))
+        cur = lax.dynamic_slice(arr, (off * NW,), block.shape)
+        out = jnp.where((i >= lo * NW) & (i < hi * NW), block, cur)
+        return lax.dynamic_update_slice(arr, out, (off * NW,))
 
     def write(arr, off, block, m):
         """Masked RMW block write at a dynamic row offset."""
@@ -2036,7 +2040,9 @@ def _grow_compact_impl(cfg: GrowConfig,
         Each K-row chunk is read from the source half, partitioned
         in-registers by a variadic sort on a (side, position) key — the
         TPU's one fast data-movement primitive (gathers/scatters
-        serialize per element) — then:
+        serialize per element; the wide mode sorts the key alone and
+        applies the permutation with one gather of whole rows a chunk,
+        rights placed by offset) — then:
         - LEFT runs append forward IN PLACE in the source half (safely
           behind the read frontier: l_off + K <= (c+1)K);
         - RIGHT runs pack backward from ``start + cnt`` in the OTHER
@@ -2112,8 +2118,9 @@ def _grow_compact_impl(cfg: GrowConfig,
                 cols = tuple(blk_w[:, i] for i in range(NW)) \
                     + _pack_pay(blk_p) + ((blk_o,) if track else ())
                 ml = iota_c < l_c
-                o_r = dst_base + cnt - r_off - CK
-                mr = iota_c >= (CK - r_c)
+                # the rights' first lane in the block written for them:
+                # the route and the sort put them at the block's END
+                r_lo = CK - r_c
                 if route:
                     # two butterfly concentrations: lefts compact to the
                     # block FRONT, rights directly to the block END (no
@@ -2135,8 +2142,8 @@ def _grow_compact_impl(cfg: GrowConfig,
                     # EFB width (Allstate: NW=167 word columns) the sort
                     # alone measured 0.77 ms/chunk vs 35 us at Higgs
                     # width. Instead sort ONLY (key, iota) to get the
-                    # permutation, then apply it with row GATHERS of the
-                    # packed [CK, ~NW] word block — one pass of traffic
+                    # permutation, then apply it with ONE row gather of
+                    # the packed [CK, ~NW] word block — one pass of traffic
                     # instead of O(log^2 CK) stage passes. Rows here are
                     # NW*4-byte contiguous runs, wide enough to gather
                     # at vector width (at Higgs width rows are ~28 B and
@@ -2146,11 +2153,8 @@ def _grow_compact_impl(cfg: GrowConfig,
                     with scope("grow/partition/key_sort"):
                         perm = lax.sort((key, iota_c.astype(jnp.int32)),
                                         num_keys=1)[1]
-                        s_r = lax.rem(l_c + r_c,
-                                      jnp.asarray(CK, jnp.int32))
-                        perm_r = rot(perm, s_r)
                     # fold the payload (and ord) into the word block so
-                    # ONE row gather a side moves everything: the int8
+                    # ONE row gather a chunk moves everything: the int8
                     # and bf16 (g, h) pairs are one u32 word, the f32
                     # pair bitcasts to two
                     with scope("grow/partition/payload"):
@@ -2167,26 +2171,28 @@ def _grow_compact_impl(cfg: GrowConfig,
                         blk_all = jnp.concatenate(
                             [blk_w, pw]
                             + ([blk_o[:, None]] if track else []), axis=1)
-                        la = jnp.take(blk_all, perm, axis=0)
-                        ra = jnp.take(blk_all, perm_r, axis=0)
-                    lb, rb = la[:, :NW], ra[:, :NW]
+                        # perm sorts a unique key over iota(CK): a
+                        # permutation of [0, CK), so the promise holds
+                        # and no bounds-fill select follows the gather
+                        la = blk_all.at[perm].get(
+                            mode="promise_in_bounds")
+                        # flattened ONCE, for both writes (_bins_write)
+                        lb = rb = la[:, :NW].reshape(-1)
                     with scope("grow/partition/payload"):
                         if quant:
-                            lp = _unpack_pay(
+                            lp = rp = _unpack_pay(
                                 (la[:, NW].astype(jnp.uint16),))
-                            rp = _unpack_pay(
-                                (ra[:, NW].astype(jnp.uint16),))
                         elif bf16_pay:
-                            lp = _unpack_pay((la[:, NW],))
-                            rp = _unpack_pay((ra[:, NW],))
+                            lp = rp = _unpack_pay((la[:, NW],))
                         else:
-                            lp = lax.bitcast_convert_type(
+                            lp = rp = lax.bitcast_convert_type(
                                 la[:, NW:NW + PW], blk_p.dtype)
-                            rp = lax.bitcast_convert_type(
-                                ra[:, NW:NW + PW], blk_p.dtype)
                     if track:
-                        lo = la[:, NW + PW]
-                        ro = ra[:, NW + PW]
+                        lo = ro = la[:, NW + PW]
+                    # the gathered block IS the right block: its rights
+                    # are lanes [l_c, l_c + r_c), placed by the write's
+                    # offset below instead of a second, rotated gather
+                    r_lo = l_c
                 else:
                     # stable in-chunk partition: variadic sort moving
                     # all row data by a (side, position) key
@@ -2203,13 +2209,24 @@ def _grow_compact_impl(cfg: GrowConfig,
                         lo = ops[1 + NW + NPAY]
                         ro = rot(lo, s_r)
                 # lefts [0, l_c) forward in place; rights packed
-                # backward from the window end in the other half
+                # backward from the window end E = dst_base + cnt in the
+                # other half: lane j of the right block lands at o_r + j,
+                # so lanes [r_lo, r_lo + r_c) fill [E - r_off - r_c,
+                # E - r_off). dynamic_slice CLAMPS an out-of-range offset
+                # silently, so both writes lean on the halves' PAD >= CK:
+                # o_r + CK <= E + CK with the rights at the wide block's
+                # middle (and o_r >= dst_base + l_off), the same trailing
+                # bound the LEFT write's src_base + l_off + CK has;
+                # o_r >= dst_base - CK with the rights at the block's end
+                o_r = dst_base + cnt - r_off - r_lo - r_c
+                mr = (iota_c >= r_lo) & (iota_c < r_lo + r_c)
                 with scope("grow/partition/gather"):
-                    bins2 = _bins_write(bins2, src_base + l_off, lb, ml)
+                    bins2 = _bins_write(bins2, src_base + l_off, lb,
+                                        0, l_c)
                 with scope("grow/partition/payload"):
                     pay2 = _pay_write(pay2, src_base + l_off, lp, ml)
                 with scope("grow/partition/gather"):
-                    bins2 = _bins_write(bins2, o_r, rb, mr)
+                    bins2 = _bins_write(bins2, o_r, rb, r_lo, r_lo + r_c)
                 with scope("grow/partition/payload"):
                     pay2 = _pay_write(pay2, o_r, rp, mr)
                 if track:

@@ -185,6 +185,20 @@ _WIDE_CASES = {
     # (sort A/B only: the masked grower does not quantize as this does)
     "int8": (dict(track_rows=True, quantized=True, stochastic=False),
              (64, 4096)),
+    # The shifted right-write (PR 30: ONE gather a chunk, the rights
+    # placed by the write's offset E - r_off - r_c - l_c). chunk=256
+    # over 5,003 rows: many chunks a window and a ragged last one, leaf
+    # windows of 1..K+-1 rows beside live neighbours on both sides, so a
+    # lane written outside [l_c, l_c + r_c) or a clamped offset shows as
+    # a changed tree
+    "small_chunk_ragged": (dict(track_rows=False, chunk=256),
+                           (67, 5003)),
+    # ... with ord2 a third folded column, shifted with the rest
+    "small_chunk_ragged_tracked": (dict(track_rows=True, chunk=256),
+                                   (67, 5003)),
+    # the root window under one chunk: every write of the tree is one
+    # partial block reaching into the halves' PAD
+    "under_one_chunk": (dict(track_rows=True, chunk=1024), (67, 1000)),
 }
 
 
@@ -221,7 +235,7 @@ def _wide_case(name, variant):
 
 @pytest.mark.parametrize("case", list(_WIDE_CASES))
 def test_grower_wide_gather_equals_sort(case):
-    """The wide partition (sort (key, iota) + ONE row gather a side of
+    """The wide partition (sort (key, iota) + ONE row gather a chunk of
     the packed words with the payload's words behind them) must be
     bit-identical to the payload-carrying sort it replaces past
     _SORT_SINGLE_MAX operands; forcing the threshold sky-high re-takes

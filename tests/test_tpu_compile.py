@@ -10,6 +10,7 @@ file), the tests skip where it cannot be, and every such test lives in
 THIS file so that one worker holds the library.
 """
 
+import contextlib
 import functools
 import math
 import re
@@ -36,7 +37,7 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
+@contextlib.contextmanager
 def no_compile_cache():
     """A compile for a described device is written to the persistent
     cache but cannot be read back without a chip (the next one warns
@@ -45,9 +46,11 @@ def no_compile_cache():
     keep = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", keep)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", keep)
+        cc.reset_cache()
 
 
 F, ROWS = 67, 200_000          # the benchmark's width; rows a chip
@@ -84,31 +87,49 @@ def assert_planar_payload_and_no_whole_copy(hlo, cfg, n):
     whole = [shape for shape, dims in copies
              if math.prod(map(int, dims.split(","))) >= rows2]
     assert not whole, whole
-    layouts = set(re.findall(r"f32\[%d\](\{[^}]*\})" % (2 * rows2), hlo))
+    # S(n) names the memory space a small buffer was placed in, not a
+    # layout (at 200,000 rows the 3.7 MB buffer can sit in S(1))
+    layouts = {re.sub(r"S\(\d+\)", "", lay) for lay in re.findall(
+        r"f32\[%d\](\{[^}]*\})" % (2 * rows2), hlo)}
     assert len(layouts) == 1, layouts
     assert not re.search(r"f32\[%d,2\]" % rows2, hlo)
 
 
-@pytest.fixture(scope="module")
-def meshless(one_chip):
-    """The compact grower compiled for one described chip: ``(compiled,
-    what the grower resolved, its config)``. Module-scoped: the
-    mesh-less test reads it and the mesh test measures against it."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
+def compile_grower(cfg, jitted, args):
+    """``(compiled, what the grower resolved while tracing, cfg)`` of a
+    jitted grower compiled for the described chip(s) ``args`` sit on."""
     import lightgbm_tpu.ops.grow as growmod
-    keep = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        cfg = grow_cfg()
+    with no_compile_cache():
         growmod.last_plan.clear()
-        compiled = jax.jit(functools.partial(growmod.grow_tree_impl, cfg)) \
-            .trace(*grower_args(ROWS, one_chip, one_chip, one_chip)) \
+        compiled = jitted.trace(*args) \
             .lower(lowering_platforms=("tpu",)).compile()
         return compiled, dict(growmod.last_plan), cfg
-    finally:
-        jax.config.update("jax_enable_compilation_cache", keep)
-        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def meshless(one_chip):
+    """The compact grower compiled for one described chip.
+    Module-scoped: the mesh-less tests read it and the mesh test
+    measures against it."""
+    import lightgbm_tpu.ops.grow as growmod
+    cfg = grow_cfg()
+    return compile_grower(
+        cfg, jax.jit(functools.partial(growmod.grow_tree_impl, cfg)),
+        grower_args(ROWS, one_chip, one_chip, one_chip))
+
+
+@pytest.fixture(scope="module")
+def four_rank(topo):
+    """``parallel/dp_grow`` compiled for the described four-chip host at
+    ``criteo256x4.train``'s width, 200,000 rows a chip."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.parallel.data_parallel import make_dp_grow_fn
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    cfg = grow_cfg(parallel_mode="data", hist_comm="f32")
+    return compile_grower(cfg, make_dp_grow_fn(cfg, mesh), grower_args(
+        mesh.devices.size * ROWS, NamedSharding(mesh, P("data")),
+        NamedSharding(mesh, P()), NamedSharding(mesh, P(None, "data"))))
 
 
 def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(meshless):
@@ -126,7 +147,7 @@ def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(meshless):
 
 
 def test_four_rank_grower_keeps_the_layout_and_reduces_twice_a_split(
-        topo, meshless, no_compile_cache):
+        meshless, four_rank):
     """``parallel/dp_grow`` for the described four-chip host at
     ``criteo256x4.train``'s width, 200,000 rows a chip: under
     ``shard_map`` the grower resolves what the mesh-less one does (wide
@@ -138,21 +159,9 @@ def test_four_rank_grower_keeps_the_layout_and_reduces_twice_a_split(
     integer row count goes alone). At the cell's own 6,640,625 rows a
     chip the same compile gave temp 10,299,808,768 B a chip against
     10,299,786,752 mesh-less (sandbox compile, PR 29)."""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    import lightgbm_tpu.ops.grow as growmod
-    from lightgbm_tpu.parallel.data_parallel import make_dp_grow_fn
-    one, _, cfg1 = meshless
-    mesh = Mesh(np.asarray(topo.devices), ("data",))
-    ranks = mesh.devices.size
-    cfg = grow_cfg(parallel_mode="data", hist_comm="f32")
-    growmod.last_plan.clear()
-    compiled = make_dp_grow_fn(cfg, mesh).trace(*grower_args(
-        ranks * ROWS, NamedSharding(mesh, P("data")),
-        NamedSharding(mesh, P()), NamedSharding(mesh, P(None, "data")))) \
-        .lower(lowering_platforms=("tpu",)).compile()
-    assert growmod.last_plan == {"partition": "wide",
-                                 "payload": "f32-planar"}
+    one = meshless[0]
+    compiled, plan, cfg = four_rank
+    assert plan == {"partition": "wide", "payload": "f32-planar"}
     hlo = compiled.as_text()
     assert_planar_payload_and_no_whole_copy(hlo, cfg, ROWS)
     reduces = [(m.group(1), m.group(2)) for m in (
@@ -168,3 +177,55 @@ def test_four_rank_grower_keeps_the_layout_and_reduces_twice_a_split(
     temp, temp1 = (c.memory_analysis().temp_size_in_bytes
                    for c in (compiled, one))
     assert abs(temp - temp1) <= 0.05 * temp1, (temp, temp1)
+
+
+def partition_ops(hlo):
+    """``(result shape, opcode, op_name)`` of every instruction the
+    chunk partition traced: those whose ``op_name`` carries
+    ``grow/partition/``, inside fused computations too."""
+    return [m.groups() for m in (
+        re.search(r" = \(?(\w+\[[\d,]*\]).*? ([\w-]+)\(.*"
+                  r"op_name=\"([^\"]*grow/partition/[^\"]*)\"", line)
+        for line in hlo.splitlines()) if m]
+
+
+@pytest.mark.parametrize("which", ["meshless", "four_rank"])
+def test_wide_partition_moves_a_chunks_rows_once(which, request):
+    """The wide partition's ``while`` body as the chip's compiler leaves
+    it, mesh-less and four-rank alike: ONE row gather a chunk of the
+    ``[K, NW + 2]`` block (17 packed words + the float32 pair), by the
+    sorted permutation itself (no rotated copy of it: the rights are
+    placed by the write's offset); no bounds-fill ``select`` behind the
+    gather (``perm`` is promised in bounds); at most two re-tiles between
+    the flat ``u32[K * NW]`` and the 2-D ``u32[K, NW]`` (the slice on the
+    way in, the gathered block on the way out; five before PR 30, when a
+    round spent 47% of its time in these ops). Every pattern is first
+    shown to match something, so that a renamed op fails here."""
+    compiled, plan, cfg = request.getfixturevalue(which)
+    assert plan == {"partition": "wide", "payload": "f32-planar"}
+    hlo = compiled.as_text()
+    K, NW = cfg.chunk, -(-F // 4)
+    row, words, flat = (f"u32[{K},{NW + 2}]", f"u32[{K},{NW}]",
+                        f"u32[{K * NW}]")
+    ops = partition_ops(hlo)
+    # the gather instruction itself sits in a fused computation whose
+    # metadata may drop the scope: count it by shape over the program,
+    # and its fusion by scope
+    gathers = re.findall(r" = (\w+\[[\d,]*\])\S* gather\(", hlo)
+    assert len(gathers) > 1, "the gather pattern finds no other " \
+        "gather of the program: it has rotted"
+    assert gathers.count(row) == 1, gathers
+    assert [shape for shape, _, name in ops
+            if name.endswith("/gather")
+            and "grow/partition/gather" in name].count(row) >= 1, ops
+    key_sort = [op for _, op, name in ops
+                if "grow/partition/key_sort" in name]
+    assert "sort" in key_sort, key_sort
+    assert not {"dynamic-slice", "pad", "concatenate"} & set(key_sort), \
+        key_sort                      # rot(perm, s_r) compiled to these
+    selects = [shape for shape, op, _ in ops if op == "select"]
+    assert flat in selects, selects   # the two masked word writes
+    assert row not in selects and f"pred[{K},{NW + 2}]" not in hlo, selects
+    retiles = [shape for shape, op, _ in ops
+               if op in ("reshape", "copy") and shape in (words, flat)]
+    assert 1 <= len(retiles) <= 2, retiles
