@@ -337,8 +337,6 @@ def kernels_leg(args):
     configure_compile_cache()
     from lightgbm_tpu.ops.histogram import hist_from_rows
     from lightgbm_tpu.ops.pallas_hist import hist_from_rows_pallas_jit
-    from lightgbm_tpu.ops.partition_kernel import (route_concentrate,
-                                                   route_pair)
 
     rs = np.random.RandomState(0)
     outcomes = {}
@@ -360,29 +358,11 @@ def kernels_leg(args):
                 f"max abs err {err}")
         outcomes[name] = {"ok": True, "max_abs_err": err}
 
-    def route_case(name):
-        k, nc = 16384, 6
-        A = jnp.asarray(rs.randint(0, 1 << 30, (nc, k)).astype(np.int32))
-        u = rs.rand(k)
-        ml, mr = jnp.asarray(u < 0.4), jnp.asarray(u > 0.7)
-        L, R = route_pair(A, ml, mr, interpret=False)
-        nl, nr = int(ml.sum()), int(mr.sum())
-        wantL = route_concentrate(tuple(A), ml, jnp.int32(0))
-        wantR = route_concentrate(tuple(A), mr, jnp.int32(k - nr))
-        okL = bool(jnp.array_equal(L[:, :nl], jnp.stack(wantL)[:, :nl]))
-        okR = bool(jnp.array_equal(R[:, k - nr:],
-                                   jnp.stack(wantR)[:, k - nr:]))
-        if not (okL and okR):
-            raise AssertionError(f"route_pair != route_concentrate "
-                                 f"(left {okL}, right {okR})")
-        outcomes[name] = {"ok": True}
-
     cases = [
         ("pallas_hist[16384x28,u8,B=255]",
          lambda n: hist_case(n, 16384, 28, 255, np.uint8)),
         ("pallas_hist[4096x8,u16,B=2040]",
          lambda n: hist_case(n, 4096, 8, 2040, np.uint16)),
-        ("route_pair[6x16384]", route_case),
     ]
     # every kernel's outcome is wanted from the one chip call, so a
     # refusal is recorded with the compiler's message and the next

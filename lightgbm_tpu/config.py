@@ -723,13 +723,10 @@ class Config:
     # depth; no chunk sweep has been run on a local chip — not
     # measured)
     chunk_rows: int = 16384
-    # bulk-batching chunk size: the partition streams floor(cnt/
-    # big_chunk_rows) big bodies per leaf window before the chunk_rows
-    # tail (GrowConfig.big_chunk). Measured neutral-to-negative on v5e
-    # (the body is throughput- not dispatch-bound); 0 (default) off.
-    big_chunk_rows: int = 0
 
-    # Unrecognized parameters are kept here (warned about, not fatal).
+    # Unrecognized parameters are kept here, not fatal (and not yet
+    # warned about: a retired knob such as PR 31's bulk-batching chunk
+    # lands here in silence).
     extra: Dict[str, Any] = field(default_factory=dict)
 
     _BOUNDS = {
@@ -845,12 +842,6 @@ class Config:
                                      & (self.chunk_rows - 1)) != 0:
             raise ValueError("chunk_rows must be a power of two >= 256, "
                              f"got {self.chunk_rows}")
-        if self.big_chunk_rows != 0 and (
-                self.big_chunk_rows < self.chunk_rows
-                or (self.big_chunk_rows & (self.big_chunk_rows - 1)) != 0):
-            raise ValueError(
-                "big_chunk_rows must be 0 or a power of two >= "
-                f"chunk_rows, got {self.big_chunk_rows}")
         if self.hist_precision not in ("default", "high", "highest"):
             raise ValueError(
                 f"Unknown hist_precision: {self.hist_precision}")

@@ -456,7 +456,6 @@ class GBDTBooster:
             max_depth=cfg.max_depth,
             grower=grower,
             chunk=cfg.chunk_rows,
-            big_chunk=cfg.big_chunk_rows,
             hist_method=hist_method,
             hist_precision=cfg.hist_precision,
             quantized=cfg.use_quantized_grad,

@@ -38,10 +38,9 @@ from .split import (SplitParams, SplitResult, constrained_output,
                     find_best_split, find_best_split_bundled,
                     gain_at_output, leaf_gain, leaf_output)
 
-from .partition_kernel import route_concentrate
 from ..obs.scopes import scope, scoped
 
-__all__ = ["GrowConfig", "TreeArrays", "grow_tree", "route_concentrate"]
+__all__ = ["GrowConfig", "TreeArrays", "grow_tree"]
 
 
 NEG_INF = -jnp.inf
@@ -104,14 +103,10 @@ class GrowConfig(NamedTuple):
     split: SplitParams = SplitParams()
     hist_method: str = "scatter"
     hist_precision: str = "default"  # mxu matmul passes: default|high|highest
-    chunk: int = 16384           # rows per streaming chunk (compact grower)
-    # Bulk-batching chunk size: each leaf window is partitioned as
-    # floor(cnt/big_chunk) BIG chunks followed by K-sized tail chunks.
-    # The bitonic sort's per-row work grows ~log^2 of the chunk size,
-    # which works against the amortized per-chunk overhead; whether it
-    # pays on a local chip is not measured (ROADMAP D4). Kept as a
-    # tuning knob; 0 (default) disables.
-    big_chunk: int = 0
+    # rows per streaming chunk (compact grower): the ONE size every
+    # window loop steps by (partition, child histogram, pool-miss
+    # recompute). Both benchmark cells run the default, 16,384.
+    chunk: int = 16384
     axis_name: Optional[str] = None
     grower: str = "compact"
     # quantized-gradient training (use_quantized_grad; the reference's
@@ -167,16 +162,6 @@ class GrowConfig(NamedTuple):
     # re-search paths (CEGB / intermediate monotone / forced splits),
     # which walk leaves serving each hist from slot or recompute.
     hist_pool_slots: int = 0
-    # in-chunk stable partition primitive (compact grower):
-    # "sort"  — one variadic lax.sort on a (side, position) key.
-    #           Default; its share of a chunk on a local chip is not
-    #           measured.
-    # "route" — two butterfly concentration passes (log2(K) stages of
-    #           stride exchanges, LSB-first) steered by destination
-    #           bits (ops/partition_kernel.py). Fewer stages on paper,
-    #           but Mosaic/XLA lower the stage chain poorly on TPU
-    #           today; kept as an option + correctness oracle.
-    partition: str = "sort"
     # carry per-row ids + in-bag bits (ord2) through the partition.
     # Only needed when something consumes them: exact in-bag child
     # counts under bagging/GOSS (weight-0 rows), CEGB's lazy per-row
@@ -1108,7 +1093,7 @@ def _row_leaf_from_order(order, leaf_of_pos):
 
 
 # What the compact grower resolved the last time it was traced: how a
-# chunk is partitioned (``partition``: wide | sort | route) and the
+# chunk is partitioned (``partition``: wide | sort) and the
 # form of the (g, h) payload (``payload``: int8 | bf16 | f32-planar |
 # f32). Written at trace time, so it describes a compile, not a call;
 # the engine stamps it onto its ``train/build_step`` span
@@ -1125,7 +1110,7 @@ last_plan: dict = {}
 # (CPU-backend compile stays seconds at every width, so it is the TPU
 # sort codegen, not XLA frontend passes.) Splitting into small-group
 # sorts that each re-sort the SAME key is result-identical — the key
-# (side*CK + lane) is unique per row, so every group sort computes the
+# (side*K + lane) is unique per row, so every group sort computes the
 # same permutation — at the cost of one extra key column of VMEM
 # traffic per group. Narrow datasets (the Higgs shape: 8-9 payload
 # operands) keep the proven single sort; wide ones pay ~12% more sort
@@ -1232,16 +1217,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     while K >= 2 * n:
         K //= 2
     K = max(K, 256)
-    route = cfg.partition == "route"
-    if route:
-        K = 1 << (K.bit_length() - 1)   # butterfly needs a power of two
-    # big-chunk bulk batching (see GrowConfig.big_chunk); the butterfly
-    # router is K-sized, so route mode keeps the tail loop only
-    BK = cfg.big_chunk
-    while BK >= 2 * n:
-        BK //= 2
-    use_big = (not route) and BK > K
-    PAD = BK if use_big else K   # write-tail padding absorbs one chunk
+    PAD = K                      # write-tail padding absorbs one chunk
 
     fp = cfg.axis_name is not None and cfg.parallel_mode == "feature"
     vp = cfg.axis_name is not None and cfg.parallel_mode == "voting"
@@ -1777,7 +1753,7 @@ def _grow_compact_impl(cfg: GrowConfig,
 
     def chunk_goleft(col, f, t, dl, isc, cm):
         """go-left decision for one chunk given the SPLIT column's bins
-        ``col`` [CK] (extracted from the packed words by _extract_col)
+        ``col`` [K] (extracted from the packed words by _extract_col)
         — all vector ops (a cm[col] table gather would serialize per
         element on TPU)."""
         if bundled:
@@ -1832,10 +1808,10 @@ def _grow_compact_impl(cfg: GrowConfig,
         return u.reshape(S, nw * pack_w)
 
     def _extract_col(blk_w, c):
-        """ONE bin column [CK] from the packed [CK, NW] words.
+        """ONE bin column [K] from the packed [K, NW] words.
 
         The partition body needs only the SPLIT column to route rows;
-        unpacking the whole [CK, F] block for it cost O(F) VPU work
+        unpacking the whole [K, F] block for it cost O(F) VPU work
         per chunk — invisible at Higgs width (F=28) but ~6% of a wide
         EFB iteration (1044 bundle columns). c is traced (the split's
         column index)."""
@@ -1865,7 +1841,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         return _unpack_words(blk)[:, :F]
 
     def rot(a, s):
-        """a shifted so that out[j] = a[j - (CK - s)] — dynamic roll via
+        """a shifted so that out[j] = a[j - (K - s)] — dynamic roll via
         self-concatenation (vectorized; no per-element gather)."""
         if a.ndim == 2:
             return lax.dynamic_slice(jnp.concatenate([a, a], axis=0),
@@ -1935,8 +1911,11 @@ def _grow_compact_impl(cfg: GrowConfig,
     # ~2^31 elements they would wrap and silently corrupt the
     # partition, so such shapes — which exceed v5e HBM anyway — keep
     # the group-sort path)
-    wide_part = (not route) \
-        and NW + NPAY + (1 if track else 0) > _SORT_SINGLE_MAX \
+    # Which of the two a job gets is read off its width here, never
+    # asked of the user. The wide arm's (key, iota) sort is
+    # ``grow.sort_ms_per_round`` 32.8 in both benchmark cells (ledger,
+    # PR 30); the narrow arm's variadic sort has no cell that times it.
+    wide_part = NW + NPAY + (1 if track else 0) > _SORT_SINGLE_MAX \
         and 2 * (n + 2 * PAD) * NW < 2 ** 31
     # The f32 (g, h) payload of the wide partition is resident PLANAR:
     # one 1-D f32[2 * 2*SEG], all g then all h. A 1-D buffer has one
@@ -1950,7 +1929,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     # code. The int8 and bf16 pairs are one word and stay 2-D.
     pay_planar = wide_part and NPAY == 2
     last_plan.update(
-        partition="route" if route else "wide" if wide_part else "sort",
+        partition="wide" if wide_part else "sort",
         payload="int8" if quant else "bf16" if bf16_pay
         else "f32-planar" if pay_planar else "f32")
 
@@ -1965,11 +1944,11 @@ def _grow_compact_impl(cfg: GrowConfig,
             w32, (pos0, jnp.zeros((), pos0.dtype)), (CK, NW))
 
     def _bins_write(arr, off, block, lo, hi):
-        """Masked RMW of rows [lo, hi) of a CK-row block of packed words
+        """Masked RMW of rows [lo, hi) of a K-row block of packed words
         at row offset ``off``. The wide mode takes the block FLAT
-        (``u32[CK*NW]``, flattened once by the caller for both of its
+        (``u32[K*NW]``, flattened once by the caller for both of its
         writes) and selects in the flat domain: the flat buffer's slice
-        is never re-tiled to ``[CK, NW]``, whose 17-word minor dimension
+        is never re-tiled to ``[K, NW]``, whose 17-word minor dimension
         is padded to 128 lanes, and back."""
         i = jnp.arange(block.shape[0])
         if not wide_part:
@@ -1993,17 +1972,17 @@ def _grow_compact_impl(cfg: GrowConfig,
         out = jnp.where(m, block, cur)
         return lax.dynamic_update_slice(arr, out, (off,))
 
-    def _pay_slice(pay2, pos0, CK):
-        """[CK, 2] (g, h) chunk of the payload at row offset pos0."""
+    def _pay_slice(pay2, pos0):
+        """[K, 2] (g, h) chunk of the payload at row offset pos0."""
         if pay_planar:
             return jnp.stack(
-                [lax.dynamic_slice(pay2, (pos0 + c * 2 * SEG,), (CK,))
+                [lax.dynamic_slice(pay2, (pos0 + c * 2 * SEG,), (K,))
                  for c in range(C)], axis=1)
         return lax.dynamic_slice(
-            pay2, (pos0, jnp.zeros((), pos0.dtype)), (CK, C))
+            pay2, (pos0, jnp.zeros((), pos0.dtype)), (K, C))
 
     def _pay_write(pay2, off, block, m):
-        """Masked RMW of a [CK, 2] (g, h) block at row offset ``off``
+        """Masked RMW of a [K, 2] (g, h) block at row offset ``off``
         (the planar form writes each component as ord2 is written)."""
         if pay_planar:
             for c in range(C):
@@ -2011,16 +1990,16 @@ def _grow_compact_impl(cfg: GrowConfig,
             return pay2
         return write(pay2, off, block, m)
 
-    def chunk_hist(bins2, pay2, pos0, limit, CK):
-        """Histogram of one CK-row chunk at dynamic row offset ``pos0``:
+    def chunk_hist(bins2, pay2, pos0, limit):
+        """Histogram of one K-row chunk at dynamic row offset ``pos0``:
         slice the packed bin words + payload, mask the window tail
         (rows past ``limit`` relative to the chunk start), accumulate
         on the MXU. Shared by the post-partition child pass and the
         pool-miss window recompute."""
         with scope("grow/hist/build"):
-            blk_b = _local_hist_rows(bins2, pos0, CK)
-            blk_p = _pay_slice(pay2, pos0, CK)
-            valid = jnp.arange(CK) < jnp.clip(limit, 0, CK)
+            blk_b = _local_hist_rows(bins2, pos0, K)
+            blk_p = _pay_slice(pay2, pos0)
+            valid = jnp.arange(K) < jnp.clip(limit, 0, K)
             hp = blk_p * valid[:, None].astype(blk_p.dtype)
             if quant:
                 return hist_from_rows_int(blk_b, hp, B, hmethod), valid
@@ -2073,188 +2052,158 @@ def _grow_compact_impl(cfg: GrowConfig,
         zero = jnp.asarray(0, jnp.int32)
         acc0 = jnp.zeros((FB, B, C), jnp.int32 if quant else dtype)
 
-        def make_body(CK, base_off):
-            """Partition-chunk body over CK rows starting at window
-            offset ``base_off + c*CK`` (base_off may be traced)."""
-            iota_c = jnp.arange(CK)
+        iota_c = jnp.arange(K)
 
-            def body(c, carry):
-                (bins2, pay2, ord2, lazy_used,
-                 l_off, r_off, nlib, nib) = carry
-                off = base_off + c * CK
-                pos0 = src_base + off
-                with scope("grow/partition/gather"):
-                    blk_w = _bins_slice(bins2, pos0, CK)
+        def body(c, carry):
+            """Partition of the window's K-row chunk ``c``."""
+            (bins2, pay2, ord2, lazy_used,
+             l_off, r_off, nlib, nib) = carry
+            off = c * K
+            pos0 = src_base + off
+            with scope("grow/partition/gather"):
+                blk_w = _bins_slice(bins2, pos0, K)
+            with scope("grow/partition/payload"):
+                blk_p = _pay_slice(pay2, pos0)
+            split_col = _extract_col(blk_w,
+                                     bundle_of[f] if bundled else f)
+            gl = chunk_goleft(split_col, f, t, dl, isc, cm)
+            valid = iota_c < jnp.clip(cnt - off, 0, K)
+            vl = valid & gl
+            l_c = jnp.sum(vl, dtype=jnp.int32)
+            r_c = jnp.sum(valid & ~gl, dtype=jnp.int32)
+            if track:
+                blk_o = lax.dynamic_slice(ord2, (pos0,), (K,))
+                blk_i = (blk_o & _IB_BIT) != 0
+                nlib += jnp.sum(vl & blk_i, dtype=jnp.int32)
+                nib += jnp.sum(valid & blk_i, dtype=jnp.int32)
+            else:
+                # every row is in-bag: the partition counts ARE the
+                # in-bag counts
+                nlib += l_c
+                nib += l_c + r_c
+            if cegb_lazy:
+                rows = (blk_o & ~_IB_BIT).astype(jnp.int32)
+                # the split acquires feature f for every in-bag row
+                # in the leaf (UpdateLeafBestSplits' InsertBitset
+                # loop over the bagged partition)
+                lazy_used = lazy_used.at[rows, f].max(valid & blk_i)
+            # either arm moves the PACKED u32 word columns;
+            # children are written back packed too — bins only ever
+            # unpack transiently for goleft/histogram (bins2 stays
+            # u32-tiled, avoiding the u8 (4,1) sub-byte layout tax
+            # on every slice/RMW write). Each arm names ``r_lo``, the
+            # rights' first lane in the block written for them.
+            ml = iota_c < l_c
+            if wide_part:
+                # WIDE partition (round 5): a variadic sort moves
+                # every operand through every bitonic stage, so at
+                # EFB width (Allstate: NW=167 word columns) the sort
+                # alone measured 0.77 ms/chunk vs 35 us at Higgs
+                # width. Instead sort ONLY (key, iota) to get the
+                # permutation, then apply it with ONE row gather of
+                # the packed [K, ~NW] word block — one pass of traffic
+                # instead of O(log^2 K) stage passes. Rows here are
+                # NW*4-byte contiguous runs, wide enough to gather
+                # at vector width (at Higgs width rows are ~28 B and
+                # the payload-carrying sort wins — hence the gate).
+                side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
+                key = side * K + iota_c
+                with scope("grow/partition/key_sort"):
+                    perm = lax.sort((key, iota_c.astype(jnp.int32)),
+                                    num_keys=1)[1]
+                # fold the payload (and ord) into the word block so
+                # ONE row gather a chunk moves everything: the int8
+                # and bf16 (g, h) pairs are one u32 word, the f32
+                # pair bitcasts to two
                 with scope("grow/partition/payload"):
-                    blk_p = _pay_slice(pay2, pos0, CK)
-                split_col = _extract_col(blk_w,
-                                         bundle_of[f] if bundled else f)
-                gl = chunk_goleft(split_col, f, t, dl, isc, cm)
-                valid = iota_c < jnp.clip(cnt - off, 0, CK)
-                vl = valid & gl
-                l_c = jnp.sum(vl, dtype=jnp.int32)
-                r_c = jnp.sum(valid & ~gl, dtype=jnp.int32)
+                    if quant:
+                        pw = _pack_pay(blk_p)[0].astype(
+                            jnp.uint32)[:, None]
+                    elif bf16_pay:
+                        pw = _pack_pay(blk_p)[0][:, None]
+                    else:
+                        pw = lax.bitcast_convert_type(blk_p,
+                                                      jnp.uint32)
+                PW = pw.shape[1]
+                with scope("grow/partition/gather"):
+                    blk_all = jnp.concatenate(
+                        [blk_w, pw]
+                        + ([blk_o[:, None]] if track else []), axis=1)
+                    # perm sorts a unique key over iota(K): a
+                    # permutation of [0, K), so the promise holds
+                    # and no bounds-fill select follows the gather
+                    la = blk_all.at[perm].get(
+                        mode="promise_in_bounds")
+                    # flattened ONCE, for both writes (_bins_write)
+                    lb = rb = la[:, :NW].reshape(-1)
+                with scope("grow/partition/payload"):
+                    if quant:
+                        lp = rp = _unpack_pay(
+                            (la[:, NW].astype(jnp.uint16),))
+                    elif bf16_pay:
+                        lp = rp = _unpack_pay((la[:, NW],))
+                    else:
+                        lp = rp = lax.bitcast_convert_type(
+                            la[:, NW:NW + PW], blk_p.dtype)
                 if track:
-                    blk_o = lax.dynamic_slice(ord2, (pos0,), (CK,))
-                    blk_i = (blk_o & _IB_BIT) != 0
-                    nlib += jnp.sum(vl & blk_i, dtype=jnp.int32)
-                    nib += jnp.sum(valid & blk_i, dtype=jnp.int32)
-                else:
-                    # every row is in-bag: the partition counts ARE the
-                    # in-bag counts
-                    nlib += l_c
-                    nib += l_c + r_c
-                if cegb_lazy:
-                    rows = (blk_o & ~_IB_BIT).astype(jnp.int32)
-                    # the split acquires feature f for every in-bag row
-                    # in the leaf (UpdateLeafBestSplits' InsertBitset
-                    # loop over the bagged partition)
-                    lazy_used = lazy_used.at[rows, f].max(valid & blk_i)
-                # the sort/route move the PACKED u32 word columns;
-                # children are written back packed too — bins only ever
-                # unpack transiently for goleft/histogram (bins2 stays
-                # u32-tiled, avoiding the u8 (4,1) sub-byte layout tax
-                # on every slice/RMW write)
+                    lo = ro = la[:, NW + PW]
+                # the gathered block IS the right block: its rights
+                # are lanes [l_c, l_c + r_c), placed by the write's
+                # offset below instead of a second, rotated gather
+                r_lo = l_c
+            else:
+                # stable in-chunk partition: variadic sort moving
+                # all row data by a (side, position) key
                 cols = tuple(blk_w[:, i] for i in range(NW)) \
                     + _pack_pay(blk_p) + ((blk_o,) if track else ())
-                ml = iota_c < l_c
-                # the rights' first lane in the block written for them:
-                # the route and the sort put them at the block's END
-                r_lo = CK - r_c
-                if route:
-                    # two butterfly concentrations: lefts compact to the
-                    # block FRONT, rights directly to the block END (no
-                    # rotate needed — the offset is part of the route).
-                    with scope("grow/partition/gather"):
-                        lops = route_concentrate(cols, vl, jnp.int32(0))
-                        rops = route_concentrate(cols, valid & ~gl,
-                                                 CK - r_c)
-                    lb = jnp.stack(lops[:NW], axis=1)
-                    lp = _unpack_pay(lops[NW:NW + NPAY])
-                    rb = jnp.stack(rops[:NW], axis=1)
-                    rp = _unpack_pay(rops[NW:NW + NPAY])
-                    if track:
-                        lo = lops[NW + NPAY]
-                        ro = rops[NW + NPAY]
-                elif wide_part:
-                    # WIDE partition (round 5): a variadic sort moves
-                    # every operand through every bitonic stage, so at
-                    # EFB width (Allstate: NW=167 word columns) the sort
-                    # alone measured 0.77 ms/chunk vs 35 us at Higgs
-                    # width. Instead sort ONLY (key, iota) to get the
-                    # permutation, then apply it with ONE row gather of
-                    # the packed [CK, ~NW] word block — one pass of traffic
-                    # instead of O(log^2 CK) stage passes. Rows here are
-                    # NW*4-byte contiguous runs, wide enough to gather
-                    # at vector width (at Higgs width rows are ~28 B and
-                    # the payload-carrying sort wins — hence the gate).
-                    side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
-                    key = side * CK + iota_c
-                    with scope("grow/partition/key_sort"):
-                        perm = lax.sort((key, iota_c.astype(jnp.int32)),
-                                        num_keys=1)[1]
-                    # fold the payload (and ord) into the word block so
-                    # ONE row gather a chunk moves everything: the int8
-                    # and bf16 (g, h) pairs are one u32 word, the f32
-                    # pair bitcasts to two
-                    with scope("grow/partition/payload"):
-                        if quant:
-                            pw = _pack_pay(blk_p)[0].astype(
-                                jnp.uint32)[:, None]
-                        elif bf16_pay:
-                            pw = _pack_pay(blk_p)[0][:, None]
-                        else:
-                            pw = lax.bitcast_convert_type(blk_p,
-                                                          jnp.uint32)
-                    PW = pw.shape[1]
-                    with scope("grow/partition/gather"):
-                        blk_all = jnp.concatenate(
-                            [blk_w, pw]
-                            + ([blk_o[:, None]] if track else []), axis=1)
-                        # perm sorts a unique key over iota(CK): a
-                        # permutation of [0, CK), so the promise holds
-                        # and no bounds-fill select follows the gather
-                        la = blk_all.at[perm].get(
-                            mode="promise_in_bounds")
-                        # flattened ONCE, for both writes (_bins_write)
-                        lb = rb = la[:, :NW].reshape(-1)
-                    with scope("grow/partition/payload"):
-                        if quant:
-                            lp = rp = _unpack_pay(
-                                (la[:, NW].astype(jnp.uint16),))
-                        elif bf16_pay:
-                            lp = rp = _unpack_pay((la[:, NW],))
-                        else:
-                            lp = rp = lax.bitcast_convert_type(
-                                la[:, NW:NW + PW], blk_p.dtype)
-                    if track:
-                        lo = ro = la[:, NW + PW]
-                    # the gathered block IS the right block: its rights
-                    # are lanes [l_c, l_c + r_c), placed by the write's
-                    # offset below instead of a second, rotated gather
-                    r_lo = l_c
-                else:
-                    # stable in-chunk partition: variadic sort moving
-                    # all row data by a (side, position) key
-                    side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
-                    key = side * CK + iota_c
-                    with scope("grow/partition/key_sort"):
-                        ops = _sort_by_key(key, cols)
-                    lb = jnp.stack(ops[1:1 + NW], axis=1)
-                    lp = _unpack_pay(ops[1 + NW:1 + NW + NPAY])
-                    # rights [l_c, l_c+r_c) rotated to the block END
-                    s_r = lax.rem(l_c + r_c, jnp.asarray(CK, jnp.int32))
-                    rb, rp = rot(lb, s_r), rot(lp, s_r)
-                    if track:
-                        lo = ops[1 + NW + NPAY]
-                        ro = rot(lo, s_r)
-                # lefts [0, l_c) forward in place; rights packed
-                # backward from the window end E = dst_base + cnt in the
-                # other half: lane j of the right block lands at o_r + j,
-                # so lanes [r_lo, r_lo + r_c) fill [E - r_off - r_c,
-                # E - r_off). dynamic_slice CLAMPS an out-of-range offset
-                # silently, so both writes lean on the halves' PAD >= CK:
-                # o_r + CK <= E + CK with the rights at the wide block's
-                # middle (and o_r >= dst_base + l_off), the same trailing
-                # bound the LEFT write's src_base + l_off + CK has;
-                # o_r >= dst_base - CK with the rights at the block's end
-                o_r = dst_base + cnt - r_off - r_lo - r_c
-                mr = (iota_c >= r_lo) & (iota_c < r_lo + r_c)
-                with scope("grow/partition/gather"):
-                    bins2 = _bins_write(bins2, src_base + l_off, lb,
-                                        0, l_c)
-                with scope("grow/partition/payload"):
-                    pay2 = _pay_write(pay2, src_base + l_off, lp, ml)
-                with scope("grow/partition/gather"):
-                    bins2 = _bins_write(bins2, o_r, rb, r_lo, r_lo + r_c)
-                with scope("grow/partition/payload"):
-                    pay2 = _pay_write(pay2, o_r, rp, mr)
+                side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
+                key = side * K + iota_c
+                with scope("grow/partition/key_sort"):
+                    ops = _sort_by_key(key, cols)
+                lb = jnp.stack(ops[1:1 + NW], axis=1)
+                lp = _unpack_pay(ops[1 + NW:1 + NW + NPAY])
+                # rights [l_c, l_c+r_c) rotated to the block END
+                r_lo = K - r_c
+                s_r = lax.rem(l_c + r_c, jnp.asarray(K, jnp.int32))
+                rb, rp = rot(lb, s_r), rot(lp, s_r)
                 if track:
-                    with scope("grow/partition/gather"):
-                        ord2 = write(ord2, src_base + l_off, lo, ml)
-                        ord2 = write(ord2, o_r, ro, mr)
-                return (bins2, pay2, ord2, lazy_used,
-                        l_off + l_c, r_off + r_c, nlib, nib)
+                    lo = ops[1 + NW + NPAY]
+                    ro = rot(lo, s_r)
+            # lefts [0, l_c) forward in place; rights packed
+            # backward from the window end E = dst_base + cnt in the
+            # other half: lane j of the right block lands at o_r + j,
+            # so lanes [r_lo, r_lo + r_c) fill [E - r_off - r_c,
+            # E - r_off). dynamic_slice CLAMPS an out-of-range offset
+            # silently, so both writes lean on the PAD == K rows either
+            # side of a half's n live rows (E <= the live rows' end):
+            # o_r + K <= E + K with the rights at the wide block's
+            # middle (and o_r >= dst_base + l_off), the same trailing
+            # bound the LEFT write's src_base + l_off + K has;
+            # o_r = E - r_off - K >= dst_base - K with the rights at
+            # the sorted block's end (r_off <= cnt)
+            o_r = dst_base + cnt - r_off - r_lo - r_c
+            mr = (iota_c >= r_lo) & (iota_c < r_lo + r_c)
+            with scope("grow/partition/gather"):
+                bins2 = _bins_write(bins2, src_base + l_off, lb,
+                                    0, l_c)
+            with scope("grow/partition/payload"):
+                pay2 = _pay_write(pay2, src_base + l_off, lp, ml)
+            with scope("grow/partition/gather"):
+                bins2 = _bins_write(bins2, o_r, rb, r_lo, r_lo + r_c)
+            with scope("grow/partition/payload"):
+                pay2 = _pay_write(pay2, o_r, rp, mr)
+            if track:
+                with scope("grow/partition/gather"):
+                    ord2 = write(ord2, src_base + l_off, lo, ml)
+                    ord2 = write(ord2, o_r, ro, mr)
+            return (bins2, pay2, ord2, lazy_used,
+                    l_off + l_c, r_off + r_c, nlib, nib)
 
-            return body
-
-        # the window's bulk streams in BK-row bodies (8x fewer
-        # serialized op chains than K-row bodies — the round-3 verdict's
-        # "kill the chunk serialization" item); the remainder streams in
-        # K-row bodies so small leaves never pay a BK-sized op
         carry = (bins2, pay2, ord2, lazy_used, zero, zero, zero, zero)
         # what of a chunk no inner scope names: the go-left decision,
         # the counts, the loop
         with scope("grow/partition/route"):
-            if use_big:
-                nb_big = lax.div(cnt, jnp.asarray(BK, jnp.int32))
-                carry = lax.fori_loop(0, nb_big, make_body(BK, zero),
-                                      carry)
-                tail_off = nb_big * BK
-            else:
-                tail_off = zero
-            carry = lax.fori_loop(0, window_chunks(cnt - tail_off),
-                                  make_body(K, tail_off), carry)
+            carry = lax.fori_loop(0, window_chunks(cnt), body, carry)
         (bins2, pay2, ord2, lazy_used, n_left, _,
          n_left_ib, n_ib) = carry
 
@@ -2272,43 +2221,31 @@ def _grow_compact_impl(cfg: GrowConfig,
         est_half = jnp.where(est_left_small, src, 1 - src)
         est_base = est_half * SEG + PAD + est_start
 
-        def make_hist_body(CK, base_off):
-            def hist_body(c, carry):
-                hist, nu = carry
-                off = base_off + c * CK
-                h, valid = chunk_hist(bins2, pay2, est_base + off,
-                                      est_cnt - off, CK)
-                hist = hist + h
-                if cegb_lazy:
-                    blk_o = lax.dynamic_slice(ord2, (est_base + off,),
-                                              (CK,))
-                    blk_i = (blk_o & _IB_BIT) != 0
-                    rows = (blk_o & ~_IB_BIT).astype(jnp.int32)
-                    used_rows = jnp.take(lazy_used, rows,
-                                         axis=0)          # [CK, F]
-                    # lazy_used already acquired feature f during the
-                    # partition pass, so column f over-counts as "used"
-                    # — harmless: the caller zeroes est_nu[f] regardless
-                    # (do_split's est_nu_z)
-                    nu = nu + jnp.sum(
-                        (valid & blk_i)[:, None] & ~used_rows,
-                        axis=0).astype(dtype)
-                return hist, nu
+        def hist_body(c, carry):
+            hist, nu = carry
+            off = c * K
+            h, valid = chunk_hist(bins2, pay2, est_base + off,
+                                  est_cnt - off)
+            hist = hist + h
+            if cegb_lazy:
+                blk_o = lax.dynamic_slice(ord2, (est_base + off,), (K,))
+                blk_i = (blk_o & _IB_BIT) != 0
+                rows = (blk_o & ~_IB_BIT).astype(jnp.int32)
+                used_rows = jnp.take(lazy_used, rows,
+                                     axis=0)          # [K, F]
+                # lazy_used already acquired feature f during the
+                # partition pass, so column f over-counts as "used"
+                # — harmless: the caller zeroes est_nu[f] regardless
+                # (do_split's est_nu_z)
+                nu = nu + jnp.sum(
+                    (valid & blk_i)[:, None] & ~used_rows,
+                    axis=0).astype(dtype)
+            return hist, nu
 
-            return hist_body
-
-        carry_h = (acc0, jnp.zeros((F_orig,), dtype))
         with scope("grow/hist/build"):
-            if use_big:
-                nh_big = lax.div(est_cnt, jnp.asarray(BK, jnp.int32))
-                carry_h = lax.fori_loop(0, nh_big,
-                                        make_hist_body(BK, zero), carry_h)
-                h_off = nh_big * BK
-            else:
-                h_off = zero
             est_hist, est_nu = lax.fori_loop(
-                0, window_chunks(est_cnt - h_off),
-                make_hist_body(K, h_off), carry_h)
+                0, window_chunks(est_cnt), hist_body,
+                (acc0, jnp.zeros((F_orig,), dtype)))
 
         # exact global in-bag child counts replace the search-time
         # hessian-ratio estimates (SplitInner update_cnt,
@@ -2328,26 +2265,16 @@ def _grow_compact_impl(cfg: GrowConfig,
         src_base = src * SEG + PAD + start
         acc0 = jnp.zeros((FB, B, C), jnp.int32 if quant else dtype)
 
-        def make_body(CK, base_off):
-            def body(c, acc):
-                off = base_off + c * CK
-                return acc + chunk_hist(bins2, pay2, src_base + off,
-                                        cnt - off, CK)[0]
-
-            return body
+        def body(c, acc):
+            off = c * K
+            return acc + chunk_hist(bins2, pay2, src_base + off,
+                                    cnt - off)[0]
 
         with scope("grow/hist/build"):
-            if use_big:
-                nb = lax.div(cnt, jnp.asarray(BK, jnp.int32))
-                acc0 = lax.fori_loop(0, nb, make_body(BK, 0), acc0)
-                b_off = nb * BK
-            else:
-                b_off = jnp.asarray(0, jnp.int32)
             # runs on a histogram-pool miss only: listed, not counted
             with comms.reduction_site("pool_miss"):
                 return hist_psum(lax.fori_loop(
-                    0, window_chunks(cnt - b_off), make_body(K, b_off),
-                    acc0))
+                    0, window_chunks(cnt), body, acc0))
 
     # the streamed copy of the bin matrix lives PACKED: u32 words of
     # pack_w bin columns each (u8 arrays carry a (4,1) sub-byte tiling

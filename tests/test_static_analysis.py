@@ -24,6 +24,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -49,10 +50,25 @@ def _cached_graph():
     return build_callgraph(PKG)
 
 
+# The review-time budget of the jax-free linter, in CPU seconds of the
+# linter itself. A wall clock also times whatever else the machine is
+# doing, and tier-1 runs six workers on eight cores: tools/lint.sh took
+# 6.3-6.9 s of wall clock alone, 13 s beside five workers and 23-31 s
+# beside seven busy processes, at 6.3-6.8 s of CPU alone and 7.4-8.1 s
+# beside the seven (sandbox, PR 31). Twice today's CPU still fails.
+LINT_CPU_BUDGET_S = 12.0
+_LINT_CPU_THEN = ("6.3-6.9s alone and 7.4-8.1s beside seven busy "
+                  "processes when the budget was set")
+_lint_cpu_s = {}
+
+
 @functools.lru_cache(maxsize=None)
 def _cached_lint(rules=None):
-    return run_lint(root=PKG, rules=list(rules) if rules else None,
-                    baseline_path=BASELINE)
+    t0 = time.process_time()
+    res = run_lint(root=PKG, rules=list(rules) if rules else None,
+                   baseline_path=BASELINE)
+    _lint_cpu_s[rules] = time.process_time() - t0
+    return res
 
 
 # ---------------------------------------------------------------------
@@ -70,9 +86,11 @@ def test_package_lints_clean_against_baseline():
         "stale baseline entries (the finding no longer occurs — "
         "delete them from tools/tpulint_baseline.txt):\n  "
         + "\n  ".join(e.fid for e in res.stale_baseline))
-    assert res.elapsed < 10.0, (
-        f"analyzer took {res.elapsed:.1f}s over the package; the "
-        "review-time budget is 10s")
+    cpu = _lint_cpu_s[None]
+    assert cpu < LINT_CPU_BUDGET_S, (
+        f"analyzer took {cpu:.1f}s of CPU over the package "
+        f"({res.elapsed:.1f}s wall); the review-time budget is "
+        f"{LINT_CPU_BUDGET_S:.0f}s of CPU ({_LINT_CPU_THEN})")
 
 
 def test_baseline_entries_all_justified():
@@ -945,17 +963,23 @@ def test_threadsafe_pragma_requires_a_reason():
 
 def test_lint_sh_strict_is_clean_and_fast():
     """tools/lint.sh (the CI one-shot) must pass --strict with
-    TPL007-TPL009 enabled, within the 10 s review-time budget."""
-    import time as _time
-    t0 = _time.perf_counter()
+    TPL007-TPL009 enabled, within the review-time budget: the CPU
+    seconds (user + system) of the processes it started, which this
+    process reaps."""
+    def children_cpu():
+        t = os.times()
+        return t.children_user + t.children_system
+    c0 = children_cpu()
     proc = subprocess.run(
         ["sh", os.path.join(REPO, "tools", "lint.sh")], cwd=REPO,
         capture_output=True, text=True, timeout=120)
-    elapsed = _time.perf_counter() - t0
+    cpu = children_cpu() - c0
     assert proc.returncode == 0, (
         f"tools/lint.sh --strict failed (rc={proc.returncode}):\n"
         f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}")
-    assert elapsed < 10.0, f"lint.sh took {elapsed:.1f}s (budget 10s)"
+    assert cpu < LINT_CPU_BUDGET_S, (
+        f"lint.sh took {cpu:.1f}s of CPU (budget "
+        f"{LINT_CPU_BUDGET_S:.0f}s; {_LINT_CPU_THEN})")
     from lightgbm_tpu.analysis import ALL_RULES
     assert {"TPL007", "TPL008", "TPL009"} <= {r.id for r in ALL_RULES}
 
