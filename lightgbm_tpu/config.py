@@ -698,7 +698,9 @@ class Config:
     # MXU histogram accumulation passes: default (single-pass bf16 input /
     # f32 accumulation — the reference GPU learner's single-precision
     # histogram choice, docs/GPU-Performance.rst:134-158) | high (3-pass)
-    # | highest (6-pass f32 emulation)
+    # | highest (6-pass f32 emulation). As compiled for the histogram's
+    # matmul those are two and three passes: the one-hot operand's low
+    # half is zero and XLA:TPU skips its passes.
     hist_precision: str = "default"
     # tree grower: compact (the flagship: leaf-wise, rows grouped by
     # leaf, per-split work ~ leaf size) | level (DEPTH-wise: the whole

@@ -39,9 +39,10 @@ __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
 #: the phases of one fused iteration (models/gbdt.py
 #: ``_fused_iter_step``). ``grow/*``: the grower's layers (ops/grow.py,
 #: ops/histogram.py, ops/split.py) — the partition's parts (the
-#: per-chunk go-left decision and counts, the (side, position) key sort,
-#: the row gathers and word writes that apply it, the (g, h) payload's
-#: slices and writes), histogram build and sibling
+#: per-chunk go-left decision and counts, the (side, position) key sort
+#: with the per-row columns it carries, the row gather and word writes
+#: that apply it, the (g, h) payload's slices and writes), histogram
+#: build and sibling
 #: subtraction, the split scan, and ``grow/fixed``: what a split costs
 #: whatever its rows (tree and leaf bookkeeping, masks, bounds). Under a
 #: mesh, the two collective layers: ``grow/hist/allreduce`` (every

@@ -1095,7 +1095,9 @@ def _row_leaf_from_order(order, leaf_of_pos):
 # What the compact grower resolved the last time it was traced: how a
 # chunk is partitioned (``partition``: wide | sort) and the
 # form of the (g, h) payload (``payload``: int8 | bf16 | f32-planar |
-# f32). Written at trace time, so it describes a compile, not a call;
+# f32), and how many columns the chunk's key sort carries
+# (``sort_operands``: 4 for the wide arm's key, iota, g, h). Written
+# at trace time, so it describes a compile, not a call;
 # the engine stamps it onto its ``train/build_step`` span
 # (models/gbdt.py, docs/OBSERVABILITY.md).
 last_plan: dict = {}
@@ -1118,6 +1120,31 @@ last_plan: dict = {}
 # one-time with the persistent compilation cache).
 _SORT_SINGLE_MAX = 12
 _SORT_GROUP = 8
+
+
+def _sort_gather(key, rows, cols):
+    """One chunk of the WIDE partition: the ``[K, NW]`` packed-word
+    ``rows`` and the 1-D per-row ``cols`` brought into the order of
+    the unique ``key``. Whatever is ONE value a row (the float32 g and
+    h columns, the one-word int8 / bf16 pair, ``ord``) rides the
+    (key, iota) sort as a further operand: moved, never compared
+    (``num_keys=1``). On the v5e at K = 16,384 the sort goes from 7.5
+    to 10.9 us a chunk with g and h aboard, where folding them into
+    the gathered row and slicing them out of its 128-lane padding again
+    cost 21 us (PR 32's chip runs; one word: 37 us the whole step for
+    53 folded). The rows follow by ONE gather, which costs per row
+    whatever its width. The iota is made HERE, inside the chunk loop's
+    body: the TPU's stable-sort expansion breaks ties on an iota
+    operand, and adds one of its own where it does not recognise ours.
+    Returns ``(rows, cols)``, sorted."""
+    iota = lax.iota(jnp.int32, key.shape[0])
+    with scope("grow/partition/key_sort"):
+        perm, *cols = lax.sort((key, iota) + tuple(cols), num_keys=1)[1:]
+    with scope("grow/partition/gather"):
+        # perm sorts a unique key over iota(K): a permutation of
+        # [0, K), so the promise holds and no bounds-fill select
+        # follows the gather
+        return rows.at[perm].get(mode="promise_in_bounds"), tuple(cols)
 
 
 def _sort_by_key(key, cols):
@@ -1859,8 +1886,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     # f32 gw2, never pay2. Everything else keeps f32: the CPU (its
     # matmuls don't truncate), the scatter method, and on the TPU
     # hist_precision=high|highest. The f32 pair is two sort columns on
-    # the narrow path and two u32 words of the gathered row on the wide
-    # one (pay_planar below).
+    # either path (held planar on the wide one: pay_planar below).
     bf16_pay = (not quant) and jax.default_backend() == "tpu" \
         and cfg.hist_method != "scatter" and cfg.hist_precision == "default"
     if quant:
@@ -1896,7 +1922,8 @@ def _grow_compact_impl(cfg: GrowConfig,
 
     # WIDE partition mode (round 5): at EFB width the per-chunk
     # partition permutes rows with a (key, iota) sort + ONE row GATHER a
-    # chunk of the packed words (payload and ord folded in behind them)
+    # chunk of the packed words (_sort_gather; the payload columns and
+    # ord, one value a row each, ride the sort)
     # instead of carrying all NW word columns through the variadic sort
     # (which costs O(NW) traffic per bitonic stage — 0.77 ms/chunk at
     # NW=167 vs 35 us at Higgs width). Both children are written from
@@ -1912,9 +1939,10 @@ def _grow_compact_impl(cfg: GrowConfig,
     # partition, so such shapes — which exceed v5e HBM anyway — keep
     # the group-sort path)
     # Which of the two a job gets is read off its width here, never
-    # asked of the user. The wide arm's (key, iota) sort is
-    # ``grow.sort_ms_per_round`` 32.8 in both benchmark cells (ledger,
-    # PR 30); the narrow arm's variadic sort has no cell that times it.
+    # asked of the user. The wide arm's (key, iota, g, h) sort is
+    # ``grow.sort_ms_per_round`` in both benchmark cells (47.5, PR
+    # 32's chip run; 32.8 as (key, iota, iota): ledger, PR 30); the
+    # narrow arm's variadic sort has no cell that times it.
     wide_part = NW + NPAY + (1 if track else 0) > _SORT_SINGLE_MAX \
         and 2 * (n + 2 * PAD) * NW < 2 ** 31
     # The f32 (g, h) payload of the wide partition is resident PLANAR:
@@ -1931,7 +1959,10 @@ def _grow_compact_impl(cfg: GrowConfig,
     last_plan.update(
         partition="wide" if wide_part else "sort",
         payload="int8" if quant else "bf16" if bf16_pay
-        else "f32-planar" if pay_planar else "f32")
+        else "f32-planar" if pay_planar else "f32",
+        # the key, the wide arm's iota or the sort arm's NW word
+        # columns, the payload's columns, ord
+        sort_operands=1 + (1 if wide_part else NW) + NPAY + int(track))
 
     def _bins_slice(w32, pos0, CK):
         """[CK, NW] chunk of the packed words at row offset pos0
@@ -1975,18 +2006,27 @@ def _grow_compact_impl(cfg: GrowConfig,
     def _pay_slice(pay2, pos0):
         """[K, 2] (g, h) chunk of the payload at row offset pos0."""
         if pay_planar:
-            return jnp.stack(
-                [lax.dynamic_slice(pay2, (pos0 + c * 2 * SEG,), (K,))
-                 for c in range(C)], axis=1)
+            return jnp.stack(_pay_cols(pay2, pos0), axis=1)
         return lax.dynamic_slice(
             pay2, (pos0, jnp.zeros((), pos0.dtype)), (K, C))
 
+    def _pay_cols(pay2, pos0):
+        """The same chunk as the NPAY 1-D columns a sort carries: the
+        planar form's two slices as they lie, else the pair in its one
+        word."""
+        if pay_planar:
+            return tuple(
+                lax.dynamic_slice(pay2, (pos0 + c * 2 * SEG,), (K,))
+                for c in range(C))
+        return _pack_pay(_pay_slice(pay2, pos0))
+
     def _pay_write(pay2, off, block, m):
-        """Masked RMW of a [K, 2] (g, h) block at row offset ``off``
-        (the planar form writes each component as ord2 is written)."""
+        """Masked RMW of a (g, h) chunk at row offset ``off``: a [K, 2]
+        block, or for the planar form its two sorted columns, each
+        written as ord2 is written."""
         if pay_planar:
             for c in range(C):
-                pay2 = write(pay2, off + c * 2 * SEG, block[:, c], m)
+                pay2 = write(pay2, off + c * 2 * SEG, block[c], m)
             return pay2
         return write(pay2, off, block, m)
 
@@ -2063,7 +2103,7 @@ def _grow_compact_impl(cfg: GrowConfig,
             with scope("grow/partition/gather"):
                 blk_w = _bins_slice(bins2, pos0, K)
             with scope("grow/partition/payload"):
-                blk_p = _pay_slice(pay2, pos0)
+                cols = _pay_cols(pay2, pos0)
             split_col = _extract_col(blk_w,
                                      bundle_of[f] if bundled else f)
             gl = chunk_goleft(split_col, f, t, dl, isc, cm)
@@ -2094,72 +2134,38 @@ def _grow_compact_impl(cfg: GrowConfig,
             # on every slice/RMW write). Each arm names ``r_lo``, the
             # rights' first lane in the block written for them.
             ml = iota_c < l_c
+            side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
+            key = side * K + iota_c
+            if track:
+                cols += (blk_o,)
             if wide_part:
                 # WIDE partition (round 5): a variadic sort moves
-                # every operand through every bitonic stage, so at
-                # EFB width (Allstate: NW=167 word columns) the sort
-                # alone measured 0.77 ms/chunk vs 35 us at Higgs
-                # width. Instead sort ONLY (key, iota) to get the
-                # permutation, then apply it with ONE row gather of
-                # the packed [K, ~NW] word block — one pass of traffic
-                # instead of O(log^2 K) stage passes. Rows here are
-                # NW*4-byte contiguous runs, wide enough to gather
-                # at vector width (at Higgs width rows are ~28 B and
-                # the payload-carrying sort wins — hence the gate).
-                side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
-                key = side * K + iota_c
-                with scope("grow/partition/key_sort"):
-                    perm = lax.sort((key, iota_c.astype(jnp.int32)),
-                                    num_keys=1)[1]
-                # fold the payload (and ord) into the word block so
-                # ONE row gather a chunk moves everything: the int8
-                # and bf16 (g, h) pairs are one u32 word, the f32
-                # pair bitcasts to two
-                with scope("grow/partition/payload"):
-                    if quant:
-                        pw = _pack_pay(blk_p)[0].astype(
-                            jnp.uint32)[:, None]
-                    elif bf16_pay:
-                        pw = _pack_pay(blk_p)[0][:, None]
-                    else:
-                        pw = lax.bitcast_convert_type(blk_p,
-                                                      jnp.uint32)
-                PW = pw.shape[1]
+                # every operand through every bitonic stage (Allstate,
+                # NW=167 word columns: 0.77 ms/chunk vs 35 us at Higgs
+                # width), so the sort carries only what is ONE value a
+                # row and the words follow by ONE row gather
+                # (_sort_gather). Rows here are NW*4-byte contiguous
+                # runs, wide enough to gather at vector width (at
+                # Higgs width rows are ~28 B and the all-carrying sort
+                # wins — hence the gate).
+                la, cols = _sort_gather(key, blk_w, cols)
                 with scope("grow/partition/gather"):
-                    blk_all = jnp.concatenate(
-                        [blk_w, pw]
-                        + ([blk_o[:, None]] if track else []), axis=1)
-                    # perm sorts a unique key over iota(K): a
-                    # permutation of [0, K), so the promise holds
-                    # and no bounds-fill select follows the gather
-                    la = blk_all.at[perm].get(
-                        mode="promise_in_bounds")
                     # flattened ONCE, for both writes (_bins_write)
-                    lb = rb = la[:, :NW].reshape(-1)
-                with scope("grow/partition/payload"):
-                    if quant:
-                        lp = rp = _unpack_pay(
-                            (la[:, NW].astype(jnp.uint16),))
-                    elif bf16_pay:
-                        lp = rp = _unpack_pay((la[:, NW],))
-                    else:
-                        lp = rp = lax.bitcast_convert_type(
-                            la[:, NW:NW + PW], blk_p.dtype)
+                    lb = rb = la.reshape(-1)
+                lp = rp = cols[:NPAY] if pay_planar \
+                    else _unpack_pay(cols[:NPAY])
                 if track:
-                    lo = ro = la[:, NW + PW]
-                # the gathered block IS the right block: its rights
+                    lo = ro = cols[NPAY]
+                # the sorted order IS the right block's: its rights
                 # are lanes [l_c, l_c + r_c), placed by the write's
                 # offset below instead of a second, rotated gather
                 r_lo = l_c
             else:
                 # stable in-chunk partition: variadic sort moving
                 # all row data by a (side, position) key
-                cols = tuple(blk_w[:, i] for i in range(NW)) \
-                    + _pack_pay(blk_p) + ((blk_o,) if track else ())
-                side = jnp.where(vl, 0, jnp.where(valid, 1, 2))
-                key = side * K + iota_c
                 with scope("grow/partition/key_sort"):
-                    ops = _sort_by_key(key, cols)
+                    ops = _sort_by_key(
+                        key, tuple(blk_w[:, i] for i in range(NW)) + cols)
                 lb = jnp.stack(ops[1:1 + NW], axis=1)
                 lp = _unpack_pay(ops[1 + NW:1 + NW + NPAY])
                 # rights [l_c, l_c+r_c) rotated to the block END

@@ -192,7 +192,8 @@ def hist_from_rows(rows: jnp.ndarray, payload: jnp.ndarray,
       method: "mxu" (nibble matmul), "pallas" (VMEM-resident one-hot
         kernel, ops/pallas_hist.py) or "scatter" (CPU-friendly).
       precision: matmul pass count — "default" (1-pass bf16/f32-accum),
-        "high" (3-pass), "highest" (6-pass); mxu path only.
+        "high" (3-pass), "highest" (6-pass): two and three as compiled,
+        the one-hot operand's zero low half being skipped; mxu path only.
     Returns:
       ``[F, B, C]`` histograms (padding features report zeros only if the
       caller masked their payload; callers crop to the true F).

@@ -3,8 +3,9 @@
 A leaf's window is partitioned K rows at a time by one of two arms the
 grower picks from the job's width: ``sort`` (a variadic sort carries
 every packed word, the payload and the row ids) up to _SORT_SINGLE_MAX
-sort operands, ``wide`` (a (key, iota) sort + ONE row gather a chunk)
-past it. These tests pin:
+sort operands, ``wide`` (a (key, iota) sort that also carries what is
+one value a row: the payload's columns and the row ids; + ONE row gather
+a chunk of the packed words) past it. These tests pin:
 - each arm against the ``masked`` grower, which partitions nothing
   (tests/test_grower_equivalence.py's bar: structure and row
   assignment exact, sums to float32 rounding), at ragged and
@@ -14,7 +15,9 @@ past it. These tests pin:
 - that the chunk size never changes a tree: exact under quantized
   gradients (int32 histograms), structural in float32, where only the
   summation order within a window may differ;
-- tracked against untracked rows, and the 4-bit packing.
+- tracked against untracked rows, and the 4-bit packing;
+- what the wide arm's sort carries (``last_plan["sort_operands"]``, and
+  the traced sort itself), and that it moves those columns bit for bit.
 """
 
 import functools
@@ -87,7 +90,8 @@ def test_grower_sort_equals_masked(n, chunk, track):
     reaching into the halves' PAD)."""
     t_s, rl_s, plan = _grow_fresh(_cfg(chunk=chunk, track_rows=track),
                                   9, n, seed=0)
-    assert plan == {"partition": "sort", "payload": "f32"}
+    assert plan == {"partition": "sort", "payload": "f32",
+                    "sort_operands": 6 + track}   # key, 3 words, g, h
     t_m, rl_m, plan_m = _grow_fresh(_cfg(grower="masked"), 9, n, seed=0)
     assert plan_m == {}
     _assert_equals_masked(t_m, rl_m, t_s, rl_s)
@@ -107,7 +111,10 @@ def test_chunk_size_never_changes_a_quantized_tree_in_either_arm(
                               quantized=True, stochastic=False),
                          F, 5003, seed=3) for chunk in (256, 1024)]
     (t_a, rl_a, plan_a), (t_b, rl_b, plan_b) = grown
-    assert plan_a == plan_b == {"partition": arm, "payload": "int8"}
+    # the key, the sort arm's 2 words or the wide arm's iota, the pair
+    assert plan_a == plan_b == {
+        "partition": arm, "payload": "int8",
+        "sort_operands": (4 if arm == "sort" else 3) + track}
     assert np.array_equal(rl_a, rl_b)
     for name, a, b in zip(t_a._fields, t_a, t_b):
         assert np.array_equal(a, b), name
@@ -204,15 +211,14 @@ def test_grower_nibble_packed_low_bin():
                                masked.predict(X[:400]), rtol=1e-5)
 
 
-# The wide partition's cases (grow.py make_body, the ``wide_part`` arm).
-# F=64 u8 columns -> NW=16 packed words: with the two payload operands
-# that is past _SORT_SINGLE_MAX, so the gather path engages at the
-# default threshold. Each case: GrowConfig fields, then (F, n).
+# The wide partition's cases (grow.py part_apply.body, the ``wide_part``
+# arm). F=64 u8 columns -> NW=16 packed words: with the two payload
+# operands that is past _SORT_SINGLE_MAX, so the gather path engages at
+# the default threshold. Each case: GrowConfig fields, then (F, n).
 _WIDE_CASES = {
-    # float32 payload, no row tracking: the words and the two payload
-    # words are all the gathered row holds
+    # float32 payload, no row tracking: the sort carries key, iota, g, h
     "plain": (dict(track_rows=False), (64, 5000)),
-    # + ord2 (bagging / GOSS / EFB): ord sits behind the payload words
+    # + ord2 (bagging / GOSS / EFB): a fifth sort operand
     "tracked": (dict(track_rows=True), (64, 4096)),
     # the benchmark cell's histogram: the MXU kernel reads the [CK, 2]
     # block the two planar slices stack
@@ -227,10 +233,13 @@ _WIDE_CASES = {
     # fewer histogram slots than leaves: the pool-miss window_hist
     # re-reads a leaf's window of the payload
     "pooled": (dict(track_rows=False, hist_pool_slots=4), (64, 5000)),
-    # the one-word int8 pair shares the arm's concatenate and gather
+    # the one-word int8 pair rides the sort as ONE u16 operand
     # (sort A/B only: the masked grower does not quantize as this does)
     "int8": (dict(track_rows=True, quantized=True, stochastic=False),
              (64, 4096)),
+    # ... and alone behind key and iota: three operands, the fewest
+    "int8_untracked": (dict(track_rows=False, quantized=True,
+                            stochastic=False), (64, 4096)),
     # The shifted right-write (PR 30: ONE gather a chunk, the rights
     # placed by the write's offset E - r_off - r_c - l_c). chunk=256
     # over 5,003 rows: many chunks a window and a ragged last one, leaf
@@ -239,13 +248,28 @@ _WIDE_CASES = {
     # a changed tree
     "small_chunk_ragged": (dict(track_rows=False, chunk=256),
                            (67, 5003)),
-    # ... with ord2 a third folded column, shifted with the rest
+    # ... with ord2 a sorted column too, shifted with the rest
     "small_chunk_ragged_tracked": (dict(track_rows=True, chunk=256),
                                    (67, 5003)),
     # the root window under one chunk: every write of the tree is one
     # partial block reaching into the halves' PAD
     "under_one_chunk": (dict(track_rows=True, chunk=1024), (67, 1000)),
 }
+
+
+def _wide_plans(case):
+    """What ``last_plan`` must read for a case grown ``wide`` and by the
+    ``sort`` arm: the wide sort carries key, iota, the payload's columns
+    (two float32, or the int8 pair's one word) and ord when tracked;
+    the sort arm carries the NW word columns in iota's place."""
+    fields, (F, _) = _WIDE_CASES[case]
+    int8 = fields.get("quantized", False)
+    rest = (1 if int8 else 2) + fields["track_rows"]
+    return ({"partition": "wide",
+             "payload": "int8" if int8 else "f32-planar",
+             "sort_operands": 2 + rest},
+            {"partition": "sort", "payload": "int8" if int8 else "f32",
+             "sort_operands": 1 + -(-F // 4) + rest})
 
 
 @functools.lru_cache(maxsize=None)
@@ -263,26 +287,23 @@ def _wide_case(name, variant):
 
 @pytest.mark.parametrize("case", list(_WIDE_CASES))
 def test_grower_wide_gather_equals_sort(case):
-    """The wide partition (sort (key, iota) + ONE row gather a chunk of
-    the packed words with the payload's words behind them) must be
-    bit-identical to the payload-carrying sort it replaces past
+    """The wide partition (a (key, iota) sort that carries the payload's
+    columns and ord + ONE row gather a chunk of the packed words) must
+    be bit-identical to the all-carrying sort it replaces past
     _SORT_SINGLE_MAX operands; forcing the threshold sky-high re-takes
     the sort path on the identical inputs. The float32 payload is held
     planar (1-D, all g then all h) on the wide side and as [rows, 2] on
     the sort side: data movement only, so not one bit may differ."""
     t_g, rl_g, plan_g = _wide_case(case, "wide")
     t_s, rl_s, plan_s = _wide_case(case, "sort")
-    int8 = case == "int8"
-    assert plan_g == {"partition": "wide",
-                      "payload": "int8" if int8 else "f32-planar"}
-    assert plan_s == {"partition": "sort",
-                      "payload": "int8" if int8 else "f32"}
+    assert (plan_g, plan_s) == _wide_plans(case)
     assert np.array_equal(rl_g, rl_s)
     for name, a, b in zip(t_g._fields, t_g, t_s):
         assert np.array_equal(a, b), name
 
 
-@pytest.mark.parametrize("case", [c for c in _WIDE_CASES if c != "int8"])
+@pytest.mark.parametrize("case", [c for c in _WIDE_CASES
+                                  if not c.startswith("int8")])
 def test_grower_wide_gather_equals_masked(case):
     """... and equal to the masked grower's tree (which partitions
     nothing) by tests/test_grower_equivalence.py's bar: structure and
@@ -292,6 +313,87 @@ def test_grower_wide_gather_equals_masked(case):
     assert plan_m == {}
     assert int(t_g.num_leaves) == 31
     _assert_equals_masked(t_m, rl_m, t_g, rl_g)
+
+
+@pytest.mark.parametrize("case", ["plain", "tracked", "int8",
+                                  "int8_untracked"])
+def test_wide_sort_carries_the_per_row_columns(case):
+    """The mechanism engages on every chunk of the wide arm or on none:
+    the traced grower holds ONE sort over a chunk's K rows, keyed on its
+    first operand alone, of as many operands as ``last_plan`` says; the
+    one gather of K rows moves the ``[K, NW]`` words and nothing behind
+    them, and no concatenate builds a wider row."""
+    import lightgbm_tpu.ops.grow as growmod
+    from lightgbm_tpu.analysis.ircheck import _walk_jaxprs
+    fields, (F, n) = _WIDE_CASES[case]
+    cfg, NW = _cfg(**fields), -(-F // 4)
+    sds = jax.ShapeDtypeStruct
+    growmod.last_plan.clear()
+    jaxpr = jax.make_jaxpr(functools.partial(growmod.grow_tree_impl, cfg))(
+        sds((F, n), jnp.uint8), *[sds((n,), jnp.float32)] * 3,
+        sds((F,), bool), *[sds((F,), jnp.int32)] * 2)
+    plan = dict(growmod.last_plan)
+    assert plan == _wide_plans(case)[0]
+    eqns = list(_walk_jaxprs(jaxpr.jaxpr))
+    sorts = [e for e in eqns if e.primitive.name == "sort"
+             and e.invars[0].aval.shape == (cfg.chunk,)]
+    assert [(len(e.invars), e.params["num_keys"]) for e in sorts] \
+        == [(plan["sort_operands"], 1)]
+    f32 = not fields.get("quantized", False)
+    assert [str(v.aval.dtype) for v in sorts[0].invars[2:]] \
+        == (["float32"] * 2 if f32 else ["uint16"]) \
+        + ["uint32"] * fields["track_rows"]
+    # the 2-D blocks of K rows that a gather or a concatenate makes
+    blocks = {prim: [e.outvars[0].aval.shape for e in eqns
+                     if e.primitive.name == prim
+                     and e.outvars[0].aval.ndim == 2
+                     and e.outvars[0].aval.shape[0] == cfg.chunk]
+              for prim in ("gather", "concatenate")}
+    assert blocks["gather"] == [(cfg.chunk, NW)]
+    assert not [s for s in blocks["concatenate"] if s[1] > NW]
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("form", ["f32_pair", "f32_pair_ord", "one_word",
+                                  "one_word_ord"])
+def test_wide_sort_moves_the_payload_and_never_compares_it(form):
+    """The payload's columns are sort OPERANDS, not keys: NaN of either
+    sign and any payload bits (a float comparison orders none of them,
+    and an arithmetic pass would quieten the signalling ones), +-inf and
+    -0.0 come out of one chunk's ``_sort_gather`` bit for bit where the
+    stable order of the key puts them, beside the words of their rows:
+    lefts, then rights, then the rows past the window, as rows that
+    stay out of the bag carry whatever the objective left them."""
+    import lightgbm_tpu.ops.grow as growmod
+    K, NW = 512, 17
+    rs = np.random.RandomState(11)
+    special = np.array([0x7FC00000, 0xFFC00000, 0x7F800001, 0xFFFFFFFF,
+                        0x7F800000, 0xFF800000, 0x80000000, 0x00000001],
+                       np.uint32)
+    words = rs.randint(0, 2 ** 32, size=(K, NW), dtype=np.uint64) \
+        .astype(np.uint32)
+    g, h = (np.where(rs.rand(K) < 0.5, rs.choice(special, K),
+                     rs.randn(K).astype(np.float32).view(np.uint32))
+            .astype(np.uint32) for _ in range(2))
+    cols = {"f32_pair": (g.view(np.float32), h.view(np.float32)),
+            "one_word": ((g >> 16).astype(np.uint16),)}[
+                form.removesuffix("_ord")]
+    if form.endswith("_ord"):
+        cols += (rs.permutation(K).astype(np.uint32) | (g & 0x80000000),)
+    side = rs.randint(0, 3, size=K)
+    key = (side * K + np.arange(K)).astype(np.int32)
+    rows_s, cols_s = jax.jit(growmod._sort_gather)(
+        jnp.asarray(key), jnp.asarray(words), tuple(map(jnp.asarray, cols)))
+    order = np.argsort(key, kind="stable")
+    assert np.array_equal(np.asarray(rows_s), words[order])
+    assert len(cols_s) == len(cols)
+    for got, col in zip(cols_s, cols):
+        assert got.dtype == col.dtype
+        assert np.array_equal(_bits(got), _bits(col)[order])
 
 
 def test_untracked_rows_bit_identical_to_tracked():

@@ -54,6 +54,9 @@ def no_compile_cache():
 
 
 F, ROWS = 67, 200_000          # the benchmark's width; rows a chip
+# what the grower resolves there: the chunk's sort carries key, iota, g, h
+WIDE_F32_PLAN = {"partition": "wide", "payload": "f32-planar",
+                 "sort_operands": 4}
 
 
 def grow_cfg(**over):
@@ -142,7 +145,7 @@ def test_wide_f32_payload_has_one_layout_and_no_whole_buffer_copy(meshless):
     the round on the v5e, ledger PR 26). No copy in the program may be
     that large, and the buffer must appear under one layout."""
     compiled, plan, cfg = meshless
-    assert plan == {"partition": "wide", "payload": "f32-planar"}
+    assert plan == WIDE_F32_PLAN
     assert_planar_payload_and_no_whole_copy(compiled.as_text(), cfg, ROWS)
 
 
@@ -161,7 +164,7 @@ def test_four_rank_grower_keeps_the_layout_and_reduces_twice_a_split(
     10,299,786,752 mesh-less (sandbox compile, PR 29)."""
     one = meshless[0]
     compiled, plan, cfg = four_rank
-    assert plan == {"partition": "wide", "payload": "f32-planar"}
+    assert plan == WIDE_F32_PLAN
     hlo = compiled.as_text()
     assert_planar_payload_and_no_whole_copy(hlo, cfg, ROWS)
     reduces = [(m.group(1), m.group(2)) for m in (
@@ -192,21 +195,24 @@ def partition_ops(hlo):
 @pytest.mark.parametrize("which", ["meshless", "four_rank"])
 def test_wide_partition_moves_a_chunks_rows_once(which, request):
     """The wide partition's ``while`` body as the chip's compiler leaves
-    it, mesh-less and four-rank alike: ONE row gather a chunk of the
-    ``[K, NW + 2]`` block (17 packed words + the float32 pair), by the
-    sorted permutation itself (no rotated copy of it: the rights are
-    placed by the write's offset); no bounds-fill ``select`` behind the
-    gather (``perm`` is promised in bounds); at most two re-tiles between
-    the flat ``u32[K * NW]`` and the 2-D ``u32[K, NW]`` (the slice on the
-    way in, the gathered block on the way out; five before PR 30, when a
-    round spent 47% of its time in these ops). Every pattern is first
-    shown to match something, so that a renamed op fails here."""
+    it, mesh-less and four-rank alike: ONE row gather a chunk and it is
+    of the ``[K, NW]`` packed words alone, by the sorted permutation
+    itself (no rotated copy of it: the rights are placed by the write's
+    offset); the float32 pair rides the chunk's ONE sort, which carries
+    four operands ``(key, iota, g, h)`` (PR 32: no ``[K, NW + 2]`` row
+    anywhere, no concatenate to it, no ``f32[K]`` column sliced out of a
+    2-D block, whose minor dimension is padded to 128 lanes); no
+    bounds-fill ``select`` behind the gather (``perm`` is promised in
+    bounds); at most two re-tiles between the flat ``u32[K * NW]`` and
+    the 2-D ``u32[K, NW]`` (the slice on the way in, the gathered block
+    on the way out; five before PR 30, when a round spent 47% of its
+    time in these ops). Every pattern is first shown to match something,
+    so that a renamed op fails here."""
     compiled, plan, cfg = request.getfixturevalue(which)
-    assert plan == {"partition": "wide", "payload": "f32-planar"}
+    assert plan == WIDE_F32_PLAN
     hlo = compiled.as_text()
     K, NW = cfg.chunk, -(-F // 4)
-    row, words, flat = (f"u32[{K},{NW + 2}]", f"u32[{K},{NW}]",
-                        f"u32[{K * NW}]")
+    words, flat = f"u32[{K},{NW}]", f"u32[{K * NW}]"
     ops = partition_ops(hlo)
     # the gather instruction itself sits in a fused computation whose
     # metadata may drop the scope: count it by shape over the program,
@@ -214,18 +220,40 @@ def test_wide_partition_moves_a_chunks_rows_once(which, request):
     gathers = re.findall(r" = (\w+\[[\d,]*\])\S* gather\(", hlo)
     assert len(gathers) > 1, "the gather pattern finds no other " \
         "gather of the program: it has rotted"
-    assert gathers.count(row) == 1, gathers
+    assert [g for g in gathers if g.startswith(f"u32[{K},")] == [words], \
+        gathers
     assert [shape for shape, _, name in ops
             if name.endswith("/gather")
-            and "grow/partition/gather" in name].count(row) >= 1, ops
+            and "grow/partition/gather" in name].count(words) >= 1, ops
+    # nothing as wide as the words + the pair anywhere, in any type
+    assert f"[{K},{NW}]" in hlo and f"[{K},{NW + 2}]" not in hlo
+    # the chunk's sort: one, keyed on its first operand, the pair behind
+    # key and iota
+    # (an operand's type carries its layout, parentheses and all)
+    sorts = [re.findall(r"(\w+)\[(\d+)\]", m) for m in re.findall(
+        r" = \((.*?)\) sort\(", hlo)]
+    assert sorts, "the sort pattern finds no sort: it has rotted"
+    assert [[dt for dt, _ in s] for s in sorts if s[0][1] == str(K)] \
+        == [["s32", "s32", "f32", "f32"]], sorts
     key_sort = [op for _, op, name in ops
                 if "grow/partition/key_sort" in name]
     assert "sort" in key_sort, key_sort
     assert not {"dynamic-slice", "pad", "concatenate"} & set(key_sort), \
         key_sort                      # rot(perm, s_r) compiled to these
+    # the payload's columns: 1-D in, 1-D out, never through a 2-D block
+    payload = [(shape, op) for shape, op, name in ops
+               if "grow/partition/payload" in name]
+    assert (f"f32[{K}]", "dynamic-slice") in payload, payload
+    assert not [shape for shape, _ in payload
+                if re.fullmatch(r"\w+\[%d,\d+\]" % K, shape)], payload
+    assert not [op for _, op in payload
+                if op in ("slice", "concatenate", "bitcast-convert")], payload
     selects = [shape for shape, op, _ in ops if op == "select"]
     assert flat in selects, selects   # the two masked word writes
-    assert row not in selects and f"pred[{K},{NW + 2}]" not in hlo, selects
-    retiles = [shape for shape, op, _ in ops
-               if op in ("reshape", "copy") and shape in (words, flat)]
+    assert words not in selects and f"pred[{K},{NW}]" not in hlo, selects
+    # (the gather's own fused computation ends in a reshape to its
+    # result's shape, under the gather's name: not an op of the body)
+    retiles = [shape for shape, op, name in ops
+               if op in ("reshape", "copy") and shape in (words, flat)
+               and not name.endswith("/gather")]
     assert 1 <= len(retiles) <= 2, retiles

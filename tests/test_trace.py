@@ -712,12 +712,14 @@ def test_job_level_spans_with_telemetry_off_and_no_round_span():
 
 @pytest.mark.parametrize("params,cols,want", [
     # 6 columns pack to 2 words: the payload-carrying variadic sort
-    ({}, 6, {"partition": "sort", "payload": "f32"}),
+    ({}, 6, {"partition": "sort", "payload": "f32", "sort_operands": 5}),
     # 48 columns pack to 12 words; with the float32 pair that is past
-    # _SORT_SINGLE_MAX: key sort + row gathers, the pair held planar
-    ({}, 48, {"partition": "wide", "payload": "f32-planar"}),
+    # _SORT_SINGLE_MAX: a (key, iota, g, h) sort + one row gather of the
+    # words, the pair held planar
+    ({}, 48, {"partition": "wide", "payload": "f32-planar",
+              "sort_operands": 4}),
     ({"use_quantized_grad": True}, 48,
-     {"partition": "wide", "payload": "int8"}),
+     {"partition": "wide", "payload": "int8", "sort_operands": 3}),
     # the masked grower partitions nothing and says nothing
     ({"grower": "masked"}, 6, {}),
 ])
