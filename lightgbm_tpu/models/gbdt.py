@@ -265,14 +265,15 @@ def _tree_values_binned(split_feature, threshold_bin, default_left,
                         feat_nan_bin, bins_T, is_cat=None, cat_masks=None):
     """Jitted per-row tree output over binned data (compiled once per
     (num_leaves, n) shape — trees are padded to the configured size)."""
-    leaves = predict_leaf_binned(split_feature, threshold_bin, default_left,
-                                 left_child, right_child, feat_nan_bin,
-                                 bins_T, is_cat, cat_masks)
-    # gather_small, not leaf_value[leaves]: XLA:TPU runs the
-    # [n]-sized small-table gather one element at a time (its cost on
-    # this chip: not measured) and valid-set scoring pays it every
-    # iteration
-    return gather_small(leaf_value, leaves)
+    with scope("valid/score_update"):
+        leaves = predict_leaf_binned(split_feature, threshold_bin,
+                                     default_left, left_child, right_child,
+                                     feat_nan_bin, bins_T, is_cat, cat_masks)
+        # gather_small, not leaf_value[leaves]: XLA:TPU runs the
+        # [n]-sized small-table gather one element at a time (its cost on
+        # this chip: not measured) and valid-set scoring pays it every
+        # iteration
+        return gather_small(leaf_value, leaves)
 
 
 @jax.jit
@@ -1227,6 +1228,19 @@ class GBDTBooster:
         out = (tb, isc, cmask)
         tree._binned_cache = (self.train_set, out)
         return out
+
+    def _add_tree_to_valid_scores(self, tree: Tree, k: int) -> None:
+        """The round's new tree added to every validation set's score:
+        the host tree's node arrays go back to the device and the tree
+        is routed over each resident binned table."""
+        from ..obs.registry import registry
+        from ..utils.timer import timed
+        with timed("valid/score_update"):
+            for v in self.valid_sets:
+                v.score = v.score.at[k].add(
+                    self._predict_tree_binned_host(tree, v.dataset))
+                registry.counter("valid_rows_scored").inc(
+                    int(v.score.shape[1]))
 
     def _predict_tree_binned_host(self, tree: Tree,
                                   dataset) -> jnp.ndarray:
@@ -2214,6 +2228,10 @@ class GBDTBooster:
                             None if node_key is None
                             else jax.random.fold_in(node_key, k),
                             self._bundle_dev), "grow")
+                if not self._grow_plan:
+                    # what the grower resolved, from the trace its first
+                    # call made (the fused and mesh paths keep it too)
+                    self._grow_plan = dict(grow_plan)
                 if self.cegb_enabled:
                     dev_tree, row_leaf, self._cegb_coupled, lz = out
                     if self.cegb_lazy:
@@ -2309,8 +2327,12 @@ class GBDTBooster:
                     lin = self._fit_linear(
                         dev_tree, row_leaf, grad[k], hess[k], row_w,
                         is_first=(len(self.models) < self.K))
-                tree = tree_from_arrays(dev_tree, self.train_set.mappers,
-                                        self.train_set.used_feature_indices())
+                # the finished tree's arrays, device to host: what a
+                # validation set (or a linear leaf) makes every round pay
+                with timed("tree/fetch"):
+                    tree = tree_from_arrays(
+                        dev_tree, self.train_set.mappers,
+                        self.train_set.used_feature_indices())
                 tree.apply_shrinkage(shrinkage)
                 if lin is not None:
                     self._attach_linear(tree, lin, shrinkage)
@@ -2354,9 +2376,8 @@ class GBDTBooster:
                         and self.init_score[k] != 0.0:
                     # internal score already starts at init; nothing to add
                     pass
-                for v in self.valid_sets:
-                    v.score = v.score.at[k].add(
-                        self._predict_tree_binned_host(tree, v.dataset))
+                if self.valid_sets:
+                    self._add_tree_to_valid_scores(tree, k)
 
         if defer:
             if iter_flag is not None:
@@ -2506,19 +2527,27 @@ class GBDTBooster:
         else:
             v = self.valid_sets[data_idx - 1]
             score, ds = v.score, v.dataset
-        label = jnp.asarray(ds.get_label(), jnp.float32)
-        w = ds.get_weight()
-        weight = None if w is None else jnp.asarray(w, jnp.float32)
-        convert = (self.objective.convert_output
-                   if self.objective is not None else (lambda s: s))
+        from ..obs.registry import registry
+        from ..utils.timer import timed
         out = {}
-        for m in metrics:
-            extra = {}
-            if hasattr(m, "eval_with_query"):
-                val = m.eval_with_query(score, label, weight, ds, convert)
-            else:
-                val = m.eval(score, label, weight, convert)
-            out[m.name] = float(val)
+        # the metrics run op by op (no registered entry): the device
+        # scope names those ops in a trace, the span is the host's wait
+        # for every value (float() blocks on the device)
+        with timed("metric/eval"), scope("metric/eval"):
+            with timed("metric/upload"):
+                label = jnp.asarray(ds.get_label(), jnp.float32)
+                w = ds.get_weight()
+                weight = None if w is None else jnp.asarray(w, jnp.float32)
+            convert = (self.objective.convert_output
+                       if self.objective is not None else (lambda s: s))
+            for m in metrics:
+                if hasattr(m, "eval_with_query"):
+                    val = m.eval_with_query(score, label, weight, ds,
+                                            convert)
+                else:
+                    val = m.eval(score, label, weight, convert)
+                out[m.name] = float(val)
+            registry.counter("metric_evals").inc(len(metrics))
         return out
 
     def current_score(self, data_idx: int) -> np.ndarray:

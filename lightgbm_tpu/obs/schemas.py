@@ -211,6 +211,31 @@ METRICS = {
         "kind": "counter", "labels": ("wire",),
         "doc": "bytes one rank handed those reductions (the local "
                "operand's size), by wire format"},
+    # ranking objective + validation path (ranking.py, models/gbdt.py)
+    "rank_queries": {
+        "kind": "counter", "labels": (),
+        "doc": "queries the lambdarank gradient pass went over (one "
+               "pass a round)"},
+    "rank_pairs": {
+        "kind": "counter", "labels": (),
+        "doc": "document pairs the SOURCE's lambdarank loop has to "
+               "weigh in those passes, from query lengths, grades and "
+               "lambdarank_truncation_level alone (ranking.py "
+               "source_loop_pairs): whatever computes the pass is held "
+               "to this work"},
+    "rank_pair_slots": {
+        "kind": "counter", "labels": (),
+        "doc": "pair slots the passes COMPUTED: query blocks x block "
+               "size x (longest query)^2 in the padded form; "
+               "rank_pairs / rank_pair_slots is the fill"},
+    "valid_rows_scored": {
+        "kind": "counter", "labels": (),
+        "doc": "validation rows a new tree was routed over (rows of "
+               "every validation set, once a tree)"},
+    "metric_evals": {
+        "kind": "counter", "labels": (),
+        "doc": "metric values computed by GBDT.eval_metrics (one per "
+               "metric per evaluated data set)"},
     "fused_scan_iterations": {
         "kind": "counter", "labels": (),
         "doc": "iterations that ran inside a fused scan window"},

@@ -48,8 +48,15 @@ __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
 #: mesh, the two collective layers: ``grow/hist/allreduce`` (every
 #: histogram reduction: parallel/comms.py) and ``grow/sums/allreduce``
 #: (root and leaf sums, counts, SplitInfo combines: bytes, not KB).
+#: What a ranking job with a validation set adds, each a program of the
+#: eager path: ``boost/gradients/lambdarank`` (ranking.py
+#: ``_lambdarank_grads``: the pairwise pass over padded query blocks),
+#: ``valid/score_update`` (models/gbdt.py ``_tree_values_binned``: one
+#: tree routed over a resident binned table) and ``metric/eval``
+#: (``GBDT.eval_metrics``: the metrics' own, op-by-op programs).
 DEVICE_SCOPES: Tuple[str, ...] = (
     "boost/gradients",
+    "boost/gradients/lambdarank",
     "boost/grow",
     "boost/score_update",
     "boost/tree_pack",
@@ -63,12 +70,16 @@ DEVICE_SCOPES: Tuple[str, ...] = (
     "grow/sums/allreduce",
     "grow/split_scan",
     "grow/fixed",
+    "valid/score_update",
+    "metric/eval",
 )
 
 #: the registered entry points whose programs carry these scopes (a
 #: program-owned capture writes a table for each that has run)
 SCOPED_ENTRIES: Tuple[str, ...] = ("gbdt/fused_iter", "gbdt/fused_scan",
-                                   "ops/grow_tree", "parallel/dp_grow")
+                                   "ops/grow_tree", "parallel/dp_grow",
+                                   "ranking/lambdarank_grads",
+                                   "gbdt/tree_values_binned")
 
 _SCOPE_SEGS = tuple(tuple(s.split("/")) for s in DEVICE_SCOPES)
 _ROOTS = frozenset(segs[0] for segs in _SCOPE_SEGS)

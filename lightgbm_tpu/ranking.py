@@ -21,6 +21,8 @@ from .config import Config
 from .metrics import Metric
 from .objectives import Objective
 from .obs import register_jit
+from .obs.scopes import scoped
+from .utils.timer import timed
 
 __all__ = ["create_ranking_objective", "create_ranking_metric",
            "LambdarankNDCG", "RankXENDCG", "NDCGMetric", "MapMetric"]
@@ -47,6 +49,26 @@ def _pad_queries(query_boundaries: np.ndarray):
         idx[q, : b - a] = np.arange(a, b)
         mask[q, : b - a] = True
     return idx, mask, sizes
+
+
+def source_loop_pairs(sizes: np.ndarray, labels_by_query, trunc: int) -> int:
+    """Pairs the source's lambdarank loop has to weigh in one pass, from
+    what does not change between rounds: per query of ``n`` documents
+    the loop visits ``(i, j)`` for the ``T = min(trunc, n - 1)`` best
+    ranked ``i`` and every ``j`` ranked below, ``T (n - 1) - T (T - 1) /
+    2`` pairs, and skips those of equal grade, of which a query holds
+    ``(n^2 - sum_c n_c^2) / 2`` at most: the smaller of the two."""
+    total = 0
+    for n, lab in zip(sizes, labels_by_query):
+        n = int(n)
+        t = min(int(trunc), n - 1)
+        if t <= 0:
+            continue
+        visited = t * (n - 1) - t * (t - 1) // 2
+        per_grade = np.bincount(np.asarray(lab, np.int64))
+        unequal = (n * n - int(np.sum(per_grade.astype(np.int64) ** 2))) // 2
+        total += min(visited, unequal)
+    return total
 
 
 def _ranks_desc(scores: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
@@ -125,6 +147,13 @@ class LambdarankNDCG(Objective):
         target_elems = 1 << 25
         self._blk = max(1, min(idx.shape[0],
                                target_elems // max(1, qmax * qmax)))
+        # what one pass counts (obs/schemas.py): the source loop's pairs
+        # against the slots this padded form computes
+        self._pass_queries = len(sizes)
+        self._pass_pairs = source_loop_pairs(
+            sizes, np.split(label, qb[1:-1]), self.trunc)
+        self._pass_slots = (-(-len(sizes) // self._blk) * self._blk
+                            * qmax * qmax)
         self._ready = True
 
     def _update_position_biases(self, g, h):
@@ -156,6 +185,10 @@ class LambdarankNDCG(Objective):
             score, self.q_idx, self.q_mask, self.gain_of_row, weight,
             jnp.float32(self.sigmoid), trunc=self.trunc,
             norm=self.norm, blk=self._blk)
+        from .obs.registry import registry
+        registry.counter("rank_queries").inc(self._pass_queries)
+        registry.counter("rank_pairs").inc(self._pass_pairs)
+        registry.counter("rank_pair_slots").inc(self._pass_slots)
         # bias update sees the weighted lambdas, like the reference
         # (weights are folded in inside the query loop before
         # UpdatePositionBiasFactors runs, rank_objective.hpp:75-86)
@@ -165,6 +198,7 @@ class LambdarankNDCG(Objective):
 
 
 @functools.partial(jax.jit, static_argnames=("trunc", "norm", "blk"))
+@scoped("boost/gradients/lambdarank")
 def _lambdarank_grads(score, q_idx, q_mask, gain_of_row, weight,
                       sigma, trunc, norm, blk):
     """LambdaMART lambdas/hessians over padded query blocks, fused
@@ -190,6 +224,14 @@ def _lambdarank_grads(score, q_idx, q_mask, gain_of_row, weight,
         in_top = ranks < trunc
         pair_m = pair_m & (in_top[:, :, None] | in_top[:, None, :])
         delta = jnp.abs(g_diff) * jnp.abs(d_diff) * inv_b[:, None, None]
+        if norm:
+            # "regular the delta_pair_NDCG by score distance"
+            # (rank_objective.hpp), where the query's best and worst
+            # scores differ: not in the first round's all-equal scores
+            spread = (jnp.max(s, axis=1)
+                      != jnp.min(jnp.where(mask_b, sd, jnp.inf), axis=1))
+            delta = jnp.where(spread[:, None, None],
+                              delta / (0.01 + jnp.abs(s_diff)), delta)
         sig_arg = sigma * s_diff
         p = jax.nn.sigmoid(-sig_arg)         # 1/(1+e^{sigma diff})
         lam = -sigma * p * delta
@@ -200,7 +242,9 @@ def _lambdarank_grads(score, q_idx, q_mask, gain_of_row, weight,
         g_q = jnp.sum(lam, axis=2) - jnp.sum(lam, axis=1)
         h_q = jnp.sum(hess, axis=2) + jnp.sum(hess, axis=1)
         if norm:
-            sum_lam = jnp.sum(jnp.abs(lam), axis=(1, 2)) + 1e-20
+            # the source adds a pair's lambda once for each of its two
+            # documents (sum_lambdas -= 2 * p_lambda)
+            sum_lam = 2.0 * jnp.sum(jnp.abs(lam), axis=(1, 2))
             norm_f = jnp.where(
                 sum_lam > 0, jnp.log2(1.0 + sum_lam) / sum_lam, 1.0)
             g_q = g_q * norm_f[:, None]
@@ -315,9 +359,11 @@ class NDCGMetric(Metric):
         qb = dataset.query_boundaries()
         if qb is None:
             raise ValueError("NDCG requires query information")
-        idx, mask, _ = _pad_queries(qb)
-        idx = jnp.asarray(idx)
-        mask = jnp.asarray(mask)
+        with timed("metric/ndcg/pad_queries"):
+            idx, mask, _ = _pad_queries(qb)
+        with timed("metric/upload"):
+            idx = jnp.asarray(idx)
+            mask = jnp.asarray(mask)
         score = raw_score[0] if raw_score.ndim == 2 else raw_score
         lab = label[idx]
         max_label = int(np.asarray(label).max())
