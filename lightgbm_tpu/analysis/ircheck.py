@@ -269,6 +269,15 @@ def build_specs(jax) -> List[IRSpec]:
                 sds((128,), jnp.float32), 1.0, 30, True, 8)
         return fn, args, (5, 6, 7, 8), None
 
+    def b_ndcg(ctx):
+        from ..ranking import _ndcg_at
+        fn = getattr(_ndcg_at, "unwrapped", _ndcg_at)
+        args = (sds((1, 128), jnp.float32), sds((128,), jnp.int32),
+                sds((128,), jnp.float32), sds((8, 10), jnp.int32),
+                sds((8, 10), jnp.float32), sds((8, 4), jnp.float32),
+                (1, 3, 5, 10))
+        return fn, args, (6,), None
+
     def _tree_args(L):
         return (sds((L - 1,), jnp.int32), sds((L - 1,), jnp.int32),
                 sds((L - 1,), jnp.bool_), sds((L - 1,), jnp.int32),
@@ -322,6 +331,8 @@ def build_specs(jax) -> List[IRSpec]:
         IRSpec("ranking/lambdarank_grads@default", "ranking.py",
                "_lambdarank_grads", "n=128 nq=8 Q=16 trunc=30",
                b_lambdarank),
+        IRSpec("ranking/ndcg@default", "ranking.py", "_ndcg_at",
+               "n=128 nq=8 eval_at=1,3,5,10", b_ndcg),
         IRSpec("gbdt/tree_values_binned@default", "models/gbdt.py",
                "_tree_values_binned", "L=15 F=8 n=256", b_tree_values),
         IRSpec("gbdt/tree_leaves_binned@default", "models/gbdt.py",

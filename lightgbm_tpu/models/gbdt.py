@@ -2530,9 +2530,11 @@ class GBDTBooster:
         from ..obs.registry import registry
         from ..utils.timer import timed
         out = {}
-        # the metrics run op by op (no registered entry): the device
-        # scope names those ops in a trace, the span is the host's wait
-        # for every value (float() blocks on the device)
+        # NDCG is one registered program for every eval_at
+        # (ranking/ndcg); the other metrics run op by op (no registered
+        # entry): the device scope names those ops in a trace, the span
+        # is the host's wait for every value (float() blocks on the
+        # device)
         with timed("metric/eval"), scope("metric/eval"):
             with timed("metric/upload"):
                 label = jnp.asarray(ds.get_label(), jnp.float32)

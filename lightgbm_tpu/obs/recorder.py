@@ -627,6 +627,7 @@ ENTRY_PHASES = {
     "ops/grow_tree": "tree_learner/grow",
     "parallel/dp_grow": "tree_learner/grow",
     "ranking/lambdarank_grads": "boosting/gradients",
+    "ranking/ndcg": "metric/eval",
 }
 
 

@@ -236,6 +236,13 @@ METRICS = {
         "kind": "counter", "labels": (),
         "doc": "metric values computed by GBDT.eval_metrics (one per "
                "metric per evaluated data set)"},
+    "metric_state_builds": {
+        "kind": "counter", "labels": (),
+        "doc": "times a data set's ranking-metric state was built "
+               "(ranking.py _NDCGEvaluator: query ids, gains, the top "
+               "slots and the best DCG, kept on the device): one per "
+               "evaluated data set per job; one a round is the state "
+               "not being kept"},
     "fused_scan_iterations": {
         "kind": "counter", "labels": (),
         "doc": "iterations that ran inside a fused scan window"},

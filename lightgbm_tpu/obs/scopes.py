@@ -53,7 +53,8 @@ __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
 #: ``_lambdarank_grads``: the pairwise pass over padded query blocks),
 #: ``valid/score_update`` (models/gbdt.py ``_tree_values_binned``: one
 #: tree routed over a resident binned table) and ``metric/eval``
-#: (``GBDT.eval_metrics``: the metrics' own, op-by-op programs).
+#: (``GBDT.eval_metrics``: ranking.py ``_ndcg_at``, one program for every
+#: ``eval_at``, and the other metrics' own, op-by-op programs).
 DEVICE_SCOPES: Tuple[str, ...] = (
     "boost/gradients",
     "boost/gradients/lambdarank",
@@ -79,7 +80,8 @@ DEVICE_SCOPES: Tuple[str, ...] = (
 SCOPED_ENTRIES: Tuple[str, ...] = ("gbdt/fused_iter", "gbdt/fused_scan",
                                    "ops/grow_tree", "parallel/dp_grow",
                                    "ranking/lambdarank_grads",
-                                   "gbdt/tree_values_binned")
+                                   "gbdt/tree_values_binned",
+                                   "ranking/ndcg")
 
 _SCOPE_SEGS = tuple(tuple(s.split("/")) for s in DEVICE_SCOPES)
 _ROOTS = frozenset(segs[0] for segs in _SCOPE_SEGS)

@@ -4,13 +4,18 @@ Re-design of /root/reference/src/objective/rank_objective.hpp
 (LambdarankNDCG :56-296, RankXENDCG) and src/metric/rank_metric.hpp +
 dcg_calculator.cpp for TPU: queries are padded to a common max length and
 processed in vmapped blocks, so the per-query O(Q^2) pairwise lambda
-computation is a batched dense tensor op instead of nested loops.
+computation is a batched dense tensor op instead of nested loops. NDCG
+pads nothing: ``group=`` keeps a query's rows contiguous, so one stable
+sort of the flat rows by (query, -score) ranks every query in place, and
+what no round changes is built once per data set (``_NDCGEvaluator``).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+import weakref
 from typing import List, Optional
 
 import jax
@@ -345,42 +350,127 @@ class RankXENDCG(Objective):
 # ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------
+def _descending_key(score):
+    """int32 keys whose ascending order is ``score``'s descending one,
+    as ``jnp.argsort(-score)`` has it: signed zeros tie, NaN goes last.
+    The sort then compares two integers: the program compiles for the
+    chip in half the time a float key's total-order comparator takes."""
+    s = jnp.where(score == 0, 0.0, -score)
+    s = jnp.where(jnp.isnan(s), jnp.nan, s)
+    bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+@functools.partial(jax.jit, static_argnames=("ks",))
+@scoped("metric/eval")
+def _ndcg_at(raw_score, qid_of_row, gain_of_row, top_slot, top_disc,
+             inv_max_dcg, ks):
+    """NDCG at every ``k`` of ``ks``, meaned over the queries, and each
+    query's DCG ``[queries, len(ks)]``, in the flat row order the data
+    set has. A query's rows are contiguous and its id is the first sort
+    key, so the stable sort ranks every query's gains in place (ties in
+    document order) and the document ranked ``p`` in query ``q`` sits at
+    the static flat slot ``top_slot[q, p]``; ``top_disc`` is its
+    discount ``1 / log2(2 + p)``, 0 past the query's end."""
+    score = raw_score[0] if raw_score.ndim == 2 else raw_score
+    _, _, ranked = jax.lax.sort(
+        (qid_of_row, _descending_key(score), gain_of_row), num_keys=2,
+        is_stable=True)
+    run = jnp.cumsum(ranked[top_slot] * top_disc, axis=1)
+    dcg = jnp.stack([run[:, k - 1] for k in ks], axis=1)
+    # a query whose best DCG is 0 counts 1 (rank_metric.hpp)
+    ndcg = jnp.where(inv_max_dcg > 0, dcg * inv_max_dcg, 1.0)
+    return jnp.mean(ndcg, axis=0), dcg
+
+
+_ndcg_at = register_jit("ranking/ndcg", _ndcg_at, max_signatures=8)
+
+
+@dataclasses.dataclass
+class _NDCGState:
+    """What no round changes of one data set's NDCG: the host arrays it
+    was built from (a ``set_label`` / ``set_group`` replaces those, and
+    the state with them) and ``_ndcg_at``'s operands after the score, on
+    the device; and the last score evaluated with its values, so that
+    every ``eval_at`` of a round shares one execution."""
+
+    label: np.ndarray
+    qb: np.ndarray
+    operands: tuple
+    score: Optional[weakref.ref] = None
+    values: Optional[np.ndarray] = None
+
+
+class _NDCGEvaluator:
+    """NDCG at every ``eval_at`` for the ``NDCGMetric`` objects that
+    share it: per data set a state built once and freed with the
+    ``Dataset``, per distinct score one execution of ``ranking/ndcg``
+    and one transfer of all the values."""
+
+    def __init__(self, cfg: Config, ks):
+        self.cfg = cfg
+        self.ks = tuple(int(k) for k in ks)
+        self._states = weakref.WeakKeyDictionary()
+
+    def _build(self, label, qb, raw_score) -> _NDCGState:
+        from .obs.registry import registry
+        registry.counter("metric_state_builds").inc()
+        sizes = np.diff(qb)
+        lab = np.asarray(label).astype(np.int64)
+        gain_of_row = _label_gains(self.cfg, int(lab.max()))[lab]
+        p = np.arange(max(self.ks))
+        valid = p[None, :] < sizes[:, None]
+        top_slot = np.where(valid, qb[:-1, None] + p[None, :], 0)
+        top_disc = np.where(valid, 1.0 / np.log2(2.0 + p)[None, :], 0.0)
+        flat = (
+            jnp.asarray(np.repeat(np.arange(len(sizes)), sizes), jnp.int32),
+            jnp.asarray(gain_of_row, jnp.float32),
+            jnp.asarray(top_slot, jnp.int32),
+            jnp.asarray(top_disc, jnp.float32))
+        # the best DCG is the DCG of the order the gains themselves
+        # give: the same program (shaped as the round's score, so the
+        # same executable), run once
+        _, best = _ndcg_at(
+            jnp.broadcast_to(flat[1], raw_score.shape), *flat,
+            jnp.ones((len(sizes), len(self.ks)), jnp.float32), ks=self.ks)
+        inv_max_dcg = jnp.where(best > 0, 1.0 / best, 0.0)
+        return _NDCGState(label, qb, (*flat, inv_max_dcg))
+
+    def values(self, raw_score, dataset) -> np.ndarray:
+        qb = dataset.query_boundaries()
+        if qb is None:
+            raise ValueError("NDCG requires query information")
+        raw_score = jnp.asarray(raw_score)
+        label = dataset.get_label()
+        st = self._states.get(dataset)
+        if st is None or st.label is not label or st.qb is not qb:
+            with timed("metric/ndcg/state"):
+                st = self._states[dataset] = self._build(label, qb,
+                                                         raw_score)
+        # a jax array never changes, so the same score object of the
+        # same data set has the values it had
+        if st.score is None or st.score() is not raw_score:
+            vals, _ = _ndcg_at(raw_score, *st.operands, ks=self.ks)
+            st.values = np.asarray(vals)
+            st.score = weakref.ref(raw_score)
+        return st.values
+
+
 class NDCGMetric(Metric):
     """NDCG@k (rank_metric.hpp NDCGMetric + dcg_calculator.cpp)."""
 
     higher_better = True
 
-    def __init__(self, cfg: Config, k: int):
+    def __init__(self, cfg: Config, k: int,
+                 evaluator: Optional[_NDCGEvaluator] = None):
         super().__init__(cfg)
         self.k = k
         self.name = f"ndcg@{k}"
+        self._evaluator = evaluator or _NDCGEvaluator(cfg, (k,))
 
     def eval_with_query(self, raw_score, label, weight, dataset, convert_fn):
-        qb = dataset.query_boundaries()
-        if qb is None:
-            raise ValueError("NDCG requires query information")
-        with timed("metric/ndcg/pad_queries"):
-            idx, mask, _ = _pad_queries(qb)
-        with timed("metric/upload"):
-            idx = jnp.asarray(idx)
-            mask = jnp.asarray(mask)
-        score = raw_score[0] if raw_score.ndim == 2 else raw_score
-        lab = label[idx]
-        max_label = int(np.asarray(label).max())
-        gains_tbl = jnp.asarray(_label_gains(self.cfg, max_label),
-                                jnp.float32)
-        gains = jnp.where(mask, gains_tbl[lab.astype(jnp.int32)], 0.0)
-        s = jnp.where(mask, score[idx], -jnp.inf)
-        order = jnp.argsort(-s, axis=1)
-        g_sorted = jnp.take_along_axis(gains, order, axis=1)
-        m_sorted = jnp.take_along_axis(mask, order, axis=1)
-        pos = jnp.arange(s.shape[1])
-        disc = 1.0 / jnp.log2(2.0 + pos)
-        use = (pos[None, :] < self.k) & m_sorted
-        dcg = jnp.sum(jnp.where(use, g_sorted * disc[None, :], 0.0), axis=1)
-        inv_max = _inverse_max_dcg(gains, mask, self.k)
-        ndcg = jnp.where(inv_max > 0, dcg * inv_max, 1.0)
-        return jnp.mean(ndcg)
+        ev = self._evaluator
+        return ev.values(raw_score, dataset)[ev.ks.index(self.k)]
 
 
 class MapMetric(Metric):
@@ -427,5 +517,6 @@ def create_ranking_metric(kind: str, cfg: Config) -> List[Metric]:
     """One metric object per eval_at position (eval_at, config.h)."""
     ks = cfg.eval_at or [1, 2, 3, 4, 5]
     if kind == "ndcg":
-        return [NDCGMetric(cfg, k) for k in ks]
+        shared = _NDCGEvaluator(cfg, ks)
+        return [NDCGMetric(cfg, k, shared) for k in ks]
     return [MapMetric(cfg, k) for k in ks]

@@ -105,8 +105,9 @@ def test_ir_lint_clean_on_tree(monkeypatch):
     assert not res.stale_budget, [e.fid for e in res.stale_budget]
     assert not res.unjustified_budget, \
         [e.fid for e in res.unjustified_budget]
-    assert len(res.entries_run) == 11, res.entries_run
+    assert len(res.entries_run) == 12, res.entries_run
     assert "parallel/dp_grow@wide-sharded" in res.entries_run
+    assert "ranking/ndcg@default" in res.entries_run
     assert res.elapsed < 60.0, f"IR pass took {res.elapsed:.1f}s"
 
 

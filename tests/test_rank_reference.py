@@ -8,17 +8,26 @@ validation score and recorded NDCG agree with the reference
 (``harness/check_eval.py``, the benchmark's own comparison). Small,
 seeded and ragged: queries of 1, 2, 7 and 130 documents and one at ten
 times their mean, one query of all-equal grades, tied scores.
+
+ISSUE 34: NDCG is one registered program a round (``ranking/ndcg``) over
+a per-data-set state: executions and state builds are counted, two data
+sets keep two states, the edge queries and tied scores are held to the
+loop, and the program's jaxpr holds no ``queries x longest`` array.
 """
 
+import gc
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
+from lightgbm_tpu import ranking
+from lightgbm_tpu.obs.registry import registry
 from lightgbm_tpu.ranking import LambdarankNDCG, NDCGMetric
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
@@ -145,6 +154,182 @@ def test_ndcg_agrees_with_the_reference(dataset, scores, k):
                                   reference_rank.query_layout(SIZES), (k,))
     want = loop_ndcg(score, label, SIZES, k)
     assert abs(ref - want) < 2e-6 and abs(got - want) < 2e-6
+
+
+EVAL_AT = (1, 3, 5, 10)
+
+
+def _ndcg_metrics():
+    return ranking.create_ranking_metric("ndcg", Config.from_params(
+        {"objective": "lambdarank", "eval_at": list(EVAL_AT)}))
+
+
+def _eval_all(metrics, score, ds):
+    """What ``GBDT.eval_metrics`` does with the metrics of one data set:
+    one score OBJECT handed to each metric in turn."""
+    score = jnp.asarray(score)[None, :]
+    label = jnp.asarray(ds.get_label(), jnp.float32)
+    return [float(m.eval_with_query(score, label, None, ds, lambda s: s))
+            for m in metrics]
+
+
+def _agrees(got, score, label, sizes):
+    return all(abs(g - loop_ndcg(np.asarray(score), np.asarray(label),
+                                 sizes, k)) < 2e-6
+               for g, k in zip(got, EVAL_AT))
+
+
+def _state_builds():
+    return registry.counter("metric_state_builds").snapshot()
+
+
+@pytest.fixture
+def executions(monkeypatch):
+    """Executions of the registered ``ranking/ndcg`` program, counted at
+    the entry every caller goes through."""
+    calls, entry = [], ranking._ndcg_at
+
+    def counted(*args, **kwargs):
+        calls.append(kwargs["ks"])
+        return entry(*args, **kwargs)
+
+    monkeypatch.setattr(ranking, "_ndcg_at", counted)
+    return calls
+
+
+def test_every_eval_at_comes_from_one_execution(dataset, executions):
+    metrics, label = _ndcg_metrics(), _labels()
+    assert [m.name for m in metrics] == [f"ndcg@{k}" for k in EVAL_AT]
+    got = _eval_all(metrics, _scores("random"), dataset)
+    # the first evaluation of a data set: the best DCG, then the round's
+    assert executions == [EVAL_AT] * 2
+    assert _agrees(got, _scores("random"), label, SIZES)
+    got = _eval_all(metrics, _scores("tied"), dataset)
+    assert executions == [EVAL_AT] * 3, "one execution a round, not four"
+    assert _agrees(got, _scores("tied"), label, SIZES)
+
+
+def test_three_scores_build_the_state_once(dataset, executions):
+    metrics, before = _ndcg_metrics(), _state_builds()
+    for kind in ("zero", "random", "tied"):
+        assert _agrees(_eval_all(metrics, _scores(kind), dataset),
+                       _scores(kind), _labels(), SIZES), kind
+    assert _state_builds() - before == 1
+    assert len(executions) == 4
+
+
+def test_two_data_sets_keep_two_states_and_a_freed_one_frees_its_own():
+    rs = np.random.RandomState(9)
+    sizes = {"train": SIZES, "valid": np.array([4, 40, 1, 15])}
+    labels = {k: rs.randint(0, 5, int(sz.sum())).astype(np.float32)
+              for k, sz in sizes.items()}
+    sets = {k: lgb.Dataset(rs.randn(len(labels[k]), 2), label=labels[k],
+                           group=sizes[k]).construct() for k in sizes}
+    metrics, before = _ndcg_metrics(), _state_builds()
+    for _ in range(3):
+        for k in ("train", "valid"):
+            score = rs.randn(len(labels[k])).astype(np.float32)
+            assert _agrees(_eval_all(metrics, score, sets[k]), score,
+                           labels[k], sizes[k]), k
+    states = metrics[0]._evaluator._states
+    assert _state_builds() - before == 2 and len(states) == 2
+    assert all(m._evaluator is metrics[0]._evaluator for m in metrics)
+    del sets["valid"]
+    gc.collect()
+    assert list(states) == [sets["train"]]
+
+
+def test_new_labels_rebuild_the_state():
+    rs = np.random.RandomState(13)
+    sizes = np.array([6, 11, 3])
+    ds = lgb.Dataset(rs.randn(20, 2), label=rs.randint(0, 5, 20),
+                     group=sizes).construct()
+    metrics, before = _ndcg_metrics(), _state_builds()
+    score = rs.randn(20).astype(np.float32)
+    assert _agrees(_eval_all(metrics, score, ds), score, ds.get_label(),
+                   sizes)
+    ds.set_label(rs.randint(0, 5, 20))
+    assert _agrees(_eval_all(metrics, score, ds), score, ds.get_label(),
+                   sizes)
+    assert _state_builds() - before == 2
+
+
+EDGES = {
+    # sizes, labels (None: drawn), scores (None: drawn)
+    "one_query": ([12], None, None),
+    "queries_of_one_document": ([1, 1, 1], [0, 3, 1], None),
+    "queries_shorter_than_k": ([2, 4, 3], None, None),
+    "a_query_of_all_zero_grades": ([5, 8], [0] * 5 + [1, 0, 2, 0, 0, 4, 0, 3],
+                                   None),
+    # ties keep the source's order, by document index: the worst order
+    # of the grades stays the worst
+    "all_zero_scores_keep_document_order": ([6, 4], [0, 1, 2, 3, 4, 4,
+                                                     0, 0, 1, 2],
+                                            [0.0] * 10),
+    "signed_zeros_tie": ([4], [0, 1, 2, 3], [0.0, -0.0, 0.0, -0.0]),
+    "tied_scores_keep_document_order": ([5, 5], [0, 4, 1, 3, 2, 2, 0, 4, 0,
+                                                 1],
+                                        [1, 1, 0, 0, 1, 2, 2, 2, 2, 3]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EDGES))
+def test_ndcg_at_the_edges_agrees_with_the_loop(case):
+    sizes, label, score = EDGES[case]
+    rs = np.random.RandomState(len(case))
+    sizes, n = np.array(sizes), int(np.sum(sizes))
+    label = np.asarray(rs.randint(0, 5, n) if label is None else label,
+                       np.float32)
+    score = np.asarray(rs.randn(n) if score is None else score, np.float32)
+    ds = lgb.Dataset(rs.randn(n, 2), label=label, group=sizes).construct()
+    got = _eval_all(_ndcg_metrics(), score, ds)
+    assert _agrees(got, score, label, sizes), (case, got)
+    if case == "a_query_of_all_zero_grades":
+        # the source's rule: a query whose best DCG is 0 counts 1
+        second = [loop_ndcg(score[5:], label[5:], sizes[1:], k)
+                  for k in EVAL_AT]
+        assert np.allclose(got, [(1 + v) / 2 for v in second], atol=2e-6)
+    if case == "all_zero_scores_keep_document_order":
+        # both queries open on a document of grade 0
+        assert abs(got[0]) < 2e-6 and got[3] < 0.8
+
+
+def test_the_sort_key_orders_as_argsort_of_the_negated_score():
+    rs = np.random.RandomState(17)
+    s = np.round(rs.randn(4000) * 10.0 ** rs.randint(-20, 20, 4000), 1)
+    s[:9] = [0.0, -0.0, np.inf, -np.inf, np.nan, 3.0, 3.0, -3.0, -0.0]
+    s = jnp.asarray(rs.permutation(s), jnp.float32)
+    key = np.asarray(ranking._descending_key(s))
+    assert key.dtype == np.int32
+    assert np.array_equal(np.argsort(key, kind="stable"),
+                          np.asarray(jnp.argsort(-s)))
+
+
+def _jaxpr_vars(jaxpr):
+    """Every operand and intermediate of a jaxpr, inner jaxprs too."""
+    yield from jaxpr.invars
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _jaxpr_vars(inner)
+
+
+def test_the_ndcg_program_holds_no_queries_by_longest_array(dataset):
+    """The guard of ``train.peak_hbm_gib``'s bound: nothing resident or
+    transient has ``queries x longest`` elements (here 6 x 350)."""
+    metrics = _ndcg_metrics()
+    _eval_all(metrics, _scores("random"), dataset)
+    st, = metrics[0]._evaluator._states.values()
+    padded = len(SIZES) * int(SIZES.max())
+    operands = (jnp.zeros((1, N), jnp.float32), *st.operands)
+    assert all(a.size < padded for a in operands)
+    fn = getattr(ranking._ndcg_at, "unwrapped", ranking._ndcg_at)
+    jaxpr = jax.make_jaxpr(lambda *a: fn(*a, ks=EVAL_AT))(*operands)
+    sizes = [int(np.prod(v.aval.shape)) for v in _jaxpr_vars(jaxpr.jaxpr)]
+    assert len(sizes) > 10 and N in sizes, "the walk saw the program"
+    assert max(sizes) < padded, sorted(sizes)[-3:]
 
 
 def test_auc_is_the_rank_sum_statistic_with_ties():
