@@ -297,6 +297,20 @@ def _extract_pandas(data, categorical_feature):
     return X, feature_name, cat_idx, pandas_categorical
 
 
+def _count_missing_cells(n: int, mappers, nan_cells: np.ndarray) -> None:
+    """Counters ``bin_cells`` / ``bin_cells_missing`` of one table binned
+    in memory: how much of it sits in a NaN bin. ``nan_cells`` is what
+    ``bin_matrix`` counted a column while it binned (no pass is made for
+    this); a NaN in a column without a NaN bin reads as zero and is not
+    counted."""
+    from .obs.registry import registry
+    has_bin = np.asarray([m.bin_type == BinType.NUMERICAL
+                          and m.missing_type == MissingType.NAN
+                          for m in mappers], bool)
+    registry.counter("bin_cells").inc(n * len(mappers))
+    registry.counter("bin_cells_missing").inc(int(nan_cells[has_bin].sum()))
+
+
 def _resolve_cat_indices(categorical_feature, feature_name) -> List[int]:
     out = []
     for c in categorical_feature or []:
@@ -722,7 +736,10 @@ class Dataset:
         with timed("dataset/construct/bin_rows", job=True,
                    attrs={"rows": int(n),
                           "features": len(self.mappers)}):
-            self._bins = bin_matrix(X, self._used_features, self.mappers)
+            nan_cells = np.zeros(len(self.mappers), np.int64)
+            self._bins = bin_matrix(X, self._used_features, self.mappers,
+                                    nan_cells=nan_cells)
+            _count_missing_cells(n, self.mappers, nan_cells)
         self._F = len(self.mappers)
         # linear trees fit on raw numerical values (the reference keeps
         # raw data when linear_tree is set — Dataset raw_data_, dataset.h).

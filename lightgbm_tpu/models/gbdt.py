@@ -33,15 +33,29 @@ from ..obs.trace import FUSED_SCAN_PHASE
 from ..objectives import Objective
 from ..resilience.faults import FaultPlan, is_resource_exhausted
 from ..ops.gather import gather_small
-from ..ops.grow import (GrowConfig, TreeArrays, grow_tree, grow_tree_impl,
-                        last_plan as grow_plan)
+from ..ops.grow import (GrowConfig, TreeArrays, compact_plan, grow_tree,
+                        grow_tree_impl, last_plan as grow_plan)
 from ..ops.predict import predict_leaf_binned
 from ..ops.renew import renew_leaf_values
 from ..ops.split import SplitParams
-from .tree import (Tree, pack_tree_device, tree_from_arrays,
-                   unpack_tree_host)
+from .tree import (CAT_MASK, DEFAULT_LEFT_MASK, Tree, pack_tree_device,
+                   tree_from_arrays, unpack_tree_host)
 
 __all__ = ["GBDTBooster", "resolve_hist_method", "resolve_scan_iters"]
+
+
+def _count_tree(tree: Tree) -> None:
+    """Counters ``tree_leaf_count`` / ``tree_splits`` / ``tree_splits_on_missing``
+    / ``tree_splits_default_left`` of one tree that has reached the host
+    (``tree_from_arrays``' arrays: nothing is fetched for this)."""
+    from ..obs.registry import registry
+    dt = np.asarray(tree.decision_type[:tree.num_nodes], np.uint8)
+    on_missing = ((dt >> 2) & 3 != 0) & (dt & CAT_MASK == 0)
+    registry.counter("tree_leaf_count").inc(int(tree.num_leaves))
+    registry.counter("tree_splits").inc(len(dt))
+    registry.counter("tree_splits_on_missing").inc(int(on_missing.sum()))
+    registry.counter("tree_splits_default_left").inc(
+        int((on_missing & (dt & DEFAULT_LEFT_MASK != 0)).sum()))
 
 
 def _donate(*argnums: int):
@@ -1043,6 +1057,7 @@ class GBDTBooster:
         for vec, cmask, proto, shrink, bias in pending:
             host = unpack_tree_host(vec, cmask, proto)
             tree = tree_from_arrays(host, mappers, used)
+            _count_tree(tree)
             if int(host.num_leaves) <= 1:
                 # AsConstantTree (gbdt.cpp): a no-growth tree keeps only
                 # the folded bias, unshrunk
@@ -2228,10 +2243,16 @@ class GBDTBooster:
                             None if node_key is None
                             else jax.random.fold_in(node_key, k),
                             self._bundle_dev), "grow")
-                if not self._grow_plan:
-                    # what the grower resolved, from the trace its first
-                    # call made (the fused and mesh paths keep it too)
-                    self._grow_plan = dict(grow_plan)
+                if not self._grow_plan and self.grow_cfg.grower == "compact":
+                    # what the grower resolves for this job (the fused
+                    # and mesh paths keep it from their own trace). Not
+                    # ``last_plan``: an earlier job of this process may
+                    # have compiled the same program, and then nothing
+                    # was traced for this one
+                    self._grow_plan = compact_plan(
+                        self.grow_cfg, int(self.bins_T.shape[1]),
+                        int(self.bins_T.shape[0]), self.bins_T.dtype,
+                        self._bundle_dev is not None)
                 if self.cegb_enabled:
                     dev_tree, row_leaf, self._cegb_coupled, lz = out
                     if self.cegb_lazy:
@@ -2333,6 +2354,7 @@ class GBDTBooster:
                     tree = tree_from_arrays(
                         dev_tree, self.train_set.mappers,
                         self.train_set.used_feature_indices())
+                    _count_tree(tree)
                 tree.apply_shrinkage(shrinkage)
                 if lin is not None:
                     self._attach_linear(tree, lin, shrinkage)

@@ -159,6 +159,8 @@ int64_t ltpu_parse_dense(const char* buf, int64_t len, int skip_header,
 //            else the precomputed bin of 0.0 — identical to the numpy
 //            path's where(nan -> 0.0) + searchsorted)
 //   out      row-major [n, C], uint8 (out_is_u16=0) or uint16 (=1)
+//   nan_cells  [C] int64 or null: the NaN cells seen in each column are
+//            ADDED to it (counted where the value is tested anyway)
 //
 // searchsorted(side="left") == std::lower_bound; the result is clamped
 // to the last bound like the numpy path.
@@ -166,7 +168,7 @@ void ltpu_bin_columns(const void* X, int is_f64, int64_t n, int64_t F,
                       const int32_t* cols, int64_t C,
                       const double* bounds, const int64_t* bnd_off,
                       const int32_t* nan_to,
-                      void* out, int out_is_u16) {
+                      void* out, int out_is_u16, int64_t* nan_cells) {
   const float* xf = static_cast<const float*>(X);
   const double* xd = static_cast<const double*>(X);
   uint8_t* o8 = static_cast<uint8_t*>(out);
@@ -182,6 +184,7 @@ void ltpu_bin_columns(const void* X, int is_f64, int64_t n, int64_t F,
 #endif
     for (int64_t r0 = 0; r0 < n; r0 += RB) {
       const int64_t r1 = (r0 + RB < n) ? r0 + RB : n;
+      int64_t seen_nan[CB] = {0};  // this tile's NaN cells a column
       for (int64_t r = r0; r < r1; ++r) {
         for (int64_t c = c0; c < c1; ++c) {
           const int64_t src = r * F + cols[c];
@@ -192,6 +195,7 @@ void ltpu_bin_columns(const void* X, int is_f64, int64_t n, int64_t F,
           int64_t b;
           if (std::isnan(v)) {
             b = nan_to[c];
+            ++seen_nan[c - c0];
           } else {
             b = std::lower_bound(lo, lo + nb, v) - lo;
             if (b >= nb) b = nb - 1;
@@ -202,6 +206,14 @@ void ltpu_bin_columns(const void* X, int is_f64, int64_t n, int64_t F,
             o8[r * C + c] = static_cast<uint8_t>(b);
         }
       }
+      if (nan_cells)
+        for (int64_t c = c0; c < c1; ++c)
+          if (seen_nan[c - c0]) {
+#if defined(_OPENMP)
+#pragma omp atomic
+#endif
+            nan_cells[c] += seen_nan[c - c0];
+          }
     }
   }
 }

@@ -243,6 +243,42 @@ METRICS = {
                "slots and the best DCG, kept on the device): one per "
                "evaluated data set per job; one a round is the state "
                "not being kept"},
+    "bin_cells": {
+        "kind": "counter", "labels": (),
+        "doc": "cells of the binned tables Dataset.construct made in "
+               "memory (rows x used columns, every table: a validation "
+               "set counts too); counted on the host inside "
+               "dataset/construct/bin_rows"},
+    "bin_cells_missing": {
+        "kind": "counter", "labels": (),
+        "doc": "of those, the cells that sit in their column's NaN bin "
+               "(the value was NaN and the column's missing_type is NaN), "
+               "counted by the binning kernel where it tests the value "
+               "anyway (ops/binning.py bin_matrix nan_cells: no pass of "
+               "its own); bin_cells_missing / bin_cells is the table's "
+               "missing share as the program saw it"},
+    "tree_leaf_count": {
+        "kind": "counter", "labels": (),
+        "doc": "leaves of the trees that reached the host (a counter "
+               "beside the recorder's histogram tree_leaves, which only "
+               "a telemetry run feeds) "
+               "(models/gbdt.py _count_tree: tree/fetch on the eager "
+               "path, the deferred tree's materialisation on the fused "
+               "one, never earlier; read from the host arrays "
+               "tree_from_arrays already holds); tree_leaf_count - "
+               "tree_splits is the number of trees"},
+    "tree_splits": {
+        "kind": "counter", "labels": (),
+        "doc": "internal nodes of those trees"},
+    "tree_splits_on_missing": {
+        "kind": "counter", "labels": (),
+        "doc": "of those, the numerical splits on a column that has a "
+               "missing bin (the node's missing_type is NaN or Zero), "
+               "so that the node's default direction routes rows"},
+    "tree_splits_default_left": {
+        "kind": "counter", "labels": (),
+        "doc": "of tree_splits_on_missing, the nodes whose default "
+               "direction is left (the split search's second scan won)"},
     "fused_scan_iterations": {
         "kind": "counter", "labels": (),
         "doc": "iterations that ran inside a fused scan window"},

@@ -366,7 +366,8 @@ def bin_values(columns: Sequence[np.ndarray], mappers: Sequence[BinMapper],
 
 
 def bin_matrix(X: np.ndarray, col_indices, mappers: Sequence[BinMapper],
-               dtype=None) -> np.ndarray:
+               dtype=None, nan_cells: Optional[np.ndarray] = None
+               ) -> np.ndarray:
     """Bin selected columns of a row-major [n, F] values matrix into a
     dense [n, C] bin matrix.
 
@@ -377,7 +378,12 @@ def bin_matrix(X: np.ndarray, col_indices, mappers: Sequence[BinMapper],
     Allstate width (4228 columns) dominates construct. Categorical
     columns
     (dict lookups) and unsupported dtypes fall back to value_to_bin;
-    results are bit-identical either way."""
+    results are bit-identical either way.
+
+    ``nan_cells``: an int64 array, one entry a mapper, that receives the
+    NaN cells of each NUMERICAL column: counted by the kernel where it
+    tests the value anyway, so a caller that wants the table's missing
+    share pays no pass of its own."""
     col_indices = np.asarray(col_indices, np.int64)
     max_bins = max((m.num_bins for m in mappers), default=2)
     if dtype is None:
@@ -398,9 +404,13 @@ def bin_matrix(X: np.ndarray, col_indices, mappers: Sequence[BinMapper],
                                           side="left")),
                       len(mappers[i].upper_bounds) - 1)
              for i in num_sel], np.int32)
+        seen = None if nan_cells is None \
+            else np.zeros(len(num_sel), np.int64)
         sub = bin_columns_native(
             X, col_indices[num_sel].astype(np.int32), bounds_list,
-            nan_to, dtype)
+            nan_to, dtype, seen)
+        if sub is not None and seen is not None:
+            nan_cells[num_sel] += seen
     if sub is not None and len(num_sel) == len(mappers):
         return sub
     out = np.zeros((n, len(mappers)), dtype=dtype)
@@ -411,6 +421,10 @@ def bin_matrix(X: np.ndarray, col_indices, mappers: Sequence[BinMapper],
     else:
         rest = range(len(mappers))
     for i in rest:
-        out[:, i] = mappers[i].value_to_bin(
-            X[:, col_indices[i]]).astype(dtype)
+        col = X[:, col_indices[i]]
+        out[:, i] = mappers[i].value_to_bin(col).astype(dtype)
+        if nan_cells is not None \
+                and mappers[i].bin_type == BinType.NUMERICAL:
+            nan_cells[i] += int(np.count_nonzero(
+                np.isnan(np.asarray(col, np.float64))))
     return out

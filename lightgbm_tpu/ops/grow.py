@@ -56,6 +56,14 @@ def _sums_psum(x, axis_name):
 
 
 @scoped("grow/sums/allreduce")
+def _left_is_smaller(n_left, cnt, est_left_small, rows_sharded: bool):
+    """Which child of a window the compact grower builds a histogram for
+    (``part_apply``): the one the partition counted fewer rows in, or,
+    where this device holds a shard of the rows and the count would be a
+    collective, the split search's estimate."""
+    return est_left_small if rows_sharded else n_left <= cnt - n_left
+
+
 def _combine_split_infos(r: SplitResult, axis_name) -> SplitResult:
     """SyncUpGlobalBestSplit (parallel_tree_learner.h:209-232):
     allreduce the max-gain SplitInfo across devices searching disjoint
@@ -1122,6 +1130,60 @@ _SORT_SINGLE_MAX = 12
 _SORT_GROUP = 8
 
 
+def _chunk_rows(cfg: "GrowConfig", n: int) -> int:
+    """Rows a streamed chunk of the compact grower: ``cfg.chunk``, halved
+    while it is twice the table, never under 256."""
+    K = cfg.chunk
+    while K >= 2 * n:
+        K //= 2
+    return max(K, 256)
+
+
+def _pack_width(bin_dtype, num_bins: int) -> int:
+    """Bin columns per u32 word of the streamed copy: 8 when every
+    feature fits 4 bits (the reference's 4-bit DenseBin,
+    src/io/dense_bin.hpp is_4bit path), else 4 (u8) / 2 (u16)."""
+    if bin_dtype == jnp.uint8:
+        return 8 if num_bins <= 16 else 4
+    return 2
+
+
+def _payload_form(cfg: "GrowConfig") -> str:
+    """The streamed (g, h) pair: ``int8`` under quantized gradients,
+    ``bf16`` where the histogram matmul truncates to bfloat16 anyway
+    (``_grow_compact_impl`` says why), else ``f32``."""
+    if cfg.quantized:
+        return "int8"
+    if jax.default_backend() == "tpu" and cfg.hist_method != "scatter" \
+            and cfg.hist_precision == "default":
+        return "bf16"
+    return "f32"
+
+
+def compact_plan(cfg: "GrowConfig", n: int, F: int, bin_dtype,
+                 bundled: bool = False) -> dict:
+    """What the compact grower resolves for a ``[F, n]`` bin matrix of
+    ``bin_dtype`` (``n``: the rows this device holds): ``last_plan``'s
+    three keys, as a pure function of what a caller knows before any
+    trace. The grower takes its partition arm from here, so the two
+    cannot disagree; a caller whose grower came out of the process's jit
+    cache (nothing was traced for it) asks here and not ``last_plan``,
+    which is whatever job traced last."""
+    K = _chunk_rows(cfg, n)
+    NW = -(-F // _pack_width(bin_dtype, cfg.num_bins))
+    form = _payload_form(cfg)
+    NPAY = 2 if form == "f32" else 1
+    track = bool(cfg.track_rows or cfg.cegb or (cfg.bundled and bundled))
+    # the wide arm's flat offsets are int32 products pos * NW
+    wide = NW + NPAY + int(track) > _SORT_SINGLE_MAX \
+        and 2 * (n + 2 * K) * NW < 2 ** 31
+    return {"partition": "wide" if wide else "sort",
+            "payload": "f32-planar" if wide and form == "f32" else form,
+            # the key, the wide arm's iota or the sort arm's NW word
+            # columns, the payload's columns, ord
+            "sort_operands": 1 + (1 if wide else NW) + NPAY + int(track)}
+
+
 def _sort_gather(key, rows, cols):
     """One chunk of the WIDE partition: the ``[K, NW]`` packed-word
     ``rows`` and the 1-D per-row ``cols`` brought into the order of
@@ -1240,10 +1302,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     n = bins_T.shape[1]
     dtype = grad.dtype
     p = cfg.split
-    K = cfg.chunk
-    while K >= 2 * n:
-        K //= 2
-    K = max(K, 256)
+    K = _chunk_rows(cfg, n)
     PAD = K                      # write-tail padding absorbs one chunk
 
     fp = cfg.axis_name is not None and cfg.parallel_mode == "feature"
@@ -1258,12 +1317,12 @@ def _grow_compact_impl(cfg: GrowConfig,
     sharded = (cfg.axis_name is not None and cfg.parallel_mode == "data"
                and cfg.split_search == "sharded")
 
+    rows_sharded = cfg.axis_name is not None and not fp
+
     def psum(x):
         """Row-sharded reduction; identity in feature-parallel mode
         (rows are replicated there)."""
-        if cfg.axis_name is None or fp:
-            return x
-        return _sums_psum(x, cfg.axis_name)
+        return _sums_psum(x, cfg.axis_name) if rows_sharded else x
 
     # histogram wire format (parallel/comms.py): quantized exchange
     # only where a histogram reduction actually happens — data-parallel
@@ -1733,11 +1792,8 @@ def _grow_compact_impl(cfg: GrowConfig,
 
     has_cat = feat_is_cat is not None
     bin_dt = bins_T.dtype
-    # bin columns per u32 word of the streamed copy: 8 when every
-    # feature fits 4 bits (the reference's 4-bit DenseBin,
-    # src/io/dense_bin.hpp is_4bit path), else 4 (u8) / 2 (u16)
-    nibble_bins = bin_dt == jnp.uint8 and B <= 16
-    pack_w = 8 if nibble_bins else (4 if bin_dt == jnp.uint8 else 2)
+    pack_w = _pack_width(bin_dt, B)    # bin columns per u32 word
+    nibble_bins = pack_w == 8
     Fp = -(-F // pack_w) * pack_w
     NW = Fp // pack_w                             # u32 words per row
 
@@ -1887,8 +1943,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     # matmuls don't truncate), the scatter method, and on the TPU
     # hist_precision=high|highest. The f32 pair is two sort columns on
     # either path (held planar on the wide one: pay_planar below).
-    bf16_pay = (not quant) and jax.default_backend() == "tpu" \
-        and cfg.hist_method != "scatter" and cfg.hist_precision == "default"
+    bf16_pay = _payload_form(cfg) == "bf16"
     if quant:
         # int8 (g, h) pairs ride the sort as ONE u16 column
         def _pack_pay(blk_p):
@@ -1943,8 +1998,8 @@ def _grow_compact_impl(cfg: GrowConfig,
     # ``grow.sort_ms_per_round`` in both benchmark cells (47.5, PR
     # 32's chip run; 32.8 as (key, iota, iota): ledger, PR 30); the
     # narrow arm's variadic sort has no cell that times it.
-    wide_part = NW + NPAY + (1 if track else 0) > _SORT_SINGLE_MAX \
-        and 2 * (n + 2 * PAD) * NW < 2 ** 31
+    plan = compact_plan(cfg, n, F, bin_dt, bundle_arrays is not None)
+    wide_part = plan["partition"] == "wide"
     # The f32 (g, h) payload of the wide partition is resident PLANAR:
     # one 1-D f32[2 * 2*SEG], all g then all h. A 1-D buffer has one
     # possible layout, so the partition loop and the histogram loop
@@ -1956,13 +2011,7 @@ def _grow_compact_impl(cfg: GrowConfig,
     # de-interleave of a 2-wide row compiled 8x slower at twice the
     # code. The int8 and bf16 pairs are one word and stay 2-D.
     pay_planar = wide_part and NPAY == 2
-    last_plan.update(
-        partition="wide" if wide_part else "sort",
-        payload="int8" if quant else "bf16" if bf16_pay
-        else "f32-planar" if pay_planar else "f32",
-        # the key, the wide arm's iota or the sort arm's NW word
-        # columns, the payload's columns, ord
-        sort_operands=1 + (1 if wide_part else NW) + NPAY + int(track))
+    last_plan.update(plan)
 
     def _bins_slice(w32, pos0, CK):
         """[CK, NW] chunk of the packed words at row offset pos0
@@ -2081,11 +2130,19 @@ def _grow_compact_impl(cfg: GrowConfig,
         ConstructHistogramForLeaf on the smaller leaf
         (cuda_histogram_constructor.cu).
 
-        ``est_left_small`` picks the histogrammed side from the stored
-        SplitInfo's count estimates — decided before streaming (the
-        reference re-checks with exact counts, but exact counts only
-        exist after the pass; estimates are deterministic and
-        replicated across shards).
+        The histogrammed side is the child of FEWER rows. On one chip
+        (and under feature parallelism, where rows are replicated) that
+        is read off the partition pass that has just run: ``n_left``
+        against ``cnt - n_left``, exact, as the reference re-checks its
+        smaller leaf with the partition's counts. Where rows are sharded
+        the choice has to be the same on every shard before the
+        histogram loop starts, and the exact global count is a
+        collective, so there ``est_left_small`` decides: the stored
+        SplitInfo's hessian-ratio estimates, deterministic and
+        replicated, and wrong where hessians are uneven (a rare class:
+        the child of few rows that fail often carries more hessian than
+        its sibling; ROADMAP S13). Returns the side it built as
+        ``left_small``.
         """
         src_base = src * SEG + PAD + start
         dst_base = (1 - src) * SEG + PAD + start
@@ -2213,18 +2270,20 @@ def _grow_compact_impl(cfg: GrowConfig,
         (bins2, pay2, ord2, lazy_used, n_left, _,
          n_left_ib, n_ib) = carry
 
-        # -- second streaming pass: histogram of the estimated-smaller
-        # child over its NOW-CONTIGUOUS rows only. Histogram work drops
-        # from Sum(parent) to Sum(min-child) rows per tree (~0.42x
+        # -- second streaming pass: histogram of the smaller child over
+        # its NOW-CONTIGUOUS rows only. Histogram work drops from
+        # Sum(parent) to Sum(min-child) rows per tree (~0.42x
         # empirically), which the one extra read of the small side's
-        # rows does not come close to cancelling. The side is chosen by
-        # the search-time count ESTIMATES (deterministic, replicated
-        # across shards), like the reference's smaller-leaf choice
-        # (serial_tree_learner.cpp:473-520); the sibling follows by
-        # subtraction. --
-        est_start = jnp.where(est_left_small, start, start + n_left)
-        est_cnt = jnp.where(est_left_small, n_left, cnt - n_left)
-        est_half = jnp.where(est_left_small, src, 1 - src)
+        # rows does not come close to cancelling. The side is the
+        # partition's own count where every device sees every row (the
+        # reference's smaller-leaf choice, serial_tree_learner.cpp:
+        # 473-520), the search-time ESTIMATE where rows are sharded
+        # (docstring); the sibling follows by subtraction. --
+        left_small = _left_is_smaller(n_left, cnt, est_left_small,
+                                      rows_sharded)
+        est_start = jnp.where(left_small, start, start + n_left)
+        est_cnt = jnp.where(left_small, n_left, cnt - n_left)
+        est_half = jnp.where(left_small, src, 1 - src)
         est_base = est_half * SEG + PAD + est_start
 
         def hist_body(c, carry):
@@ -2260,7 +2319,7 @@ def _grow_compact_impl(cfg: GrowConfig,
         nr_ex = psum(n_ib - n_left_ib)
         est_hist, comm_ef = hist_psum_ef(est_hist, comm_ef)
         return (bins2, pay2, ord2, lazy_used, n_left, nl_ex, nr_ex,
-                est_hist, est_nu, comm_ef)
+                left_small, est_hist, est_nu, comm_ef)
 
     def window_hist(bins2, pay2, src, start, cnt):
         """Recompute one leaf's full histogram from its contiguous row
@@ -2624,11 +2683,10 @@ def _grow_compact_impl(cfg: GrowConfig,
 
         # -- partition the leaf's range (DataPartition::Split analog) +
         # child histogram, fused into one streaming pass --
-        (bins2, pay2, ord2, lazy_arr, n_left, nl_i, nr_i, est_hist,
-         est_nu, comm_ef) = part_apply(bins2, pay2, ord2, lazy_arr,
-                                       src, start, cnt, f_split, t_bin,
-                                       dl, isc, cm, est_left_small,
-                                       comm_ef)
+        (bins2, pay2, ord2, lazy_arr, n_left, nl_i, nr_i, left_small,
+         est_hist, est_nu, comm_ef) = part_apply(
+            bins2, pay2, ord2, lazy_arr, src, start, cnt, f_split, t_bin,
+            dl, isc, cm, est_left_small, comm_ef)
         # left child stays in the parent's half; right child was packed
         # into the opposite half
         leaf_buf = leaf_buf.at[R].set(1 - src)
@@ -2642,8 +2700,8 @@ def _grow_compact_impl(cfg: GrowConfig,
 
         with scope("grow/hist/subtract"):
             other_hist = subtract_histogram(parent_hist, est_hist)
-            left_hist = jnp.where(est_left_small, est_hist, other_hist)
-            right_hist = jnp.where(est_left_small, other_hist, est_hist)
+            left_hist = jnp.where(left_small, est_hist, other_hist)
+            right_hist = jnp.where(left_small, other_hist, est_hist)
         if pooled:
             # store the children: the left child inherits the parent's
             # slot when cached; otherwise (and for the right child) the
@@ -2766,8 +2824,8 @@ def _grow_compact_impl(cfg: GrowConfig,
             est_nu_z = est_nu.at[f_split].set(0.0)
             parent_nu = lazy_nu[leaf].at[f_split].set(0.0)
             big_nu = jnp.maximum(parent_nu - est_nu_z, 0.0)
-            left_nu = jnp.where(est_left_small, est_nu_z, big_nu)
-            right_nu = jnp.where(est_left_small, big_nu, est_nu_z)
+            left_nu = jnp.where(left_small, est_nu_z, big_nu)
+            right_nu = jnp.where(left_small, big_nu, est_nu_z)
             lazy_nu = lazy_nu.at[leaf].set(left_nu).at[R].set(right_nu)
             cegb_st = (coupled_used, lazy_arr, lazy_nu)
             pen_l = cegb_penalty(nl_ex, coupled_used, left_nu)

@@ -101,7 +101,7 @@ def _load() -> Optional[ctypes.CDLL]:
         np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
-        ctypes.c_void_p, ctypes.c_int]
+        ctypes.c_void_p, ctypes.c_int, ctypes.c_void_p]
     _LIB = lib
     return lib
 
@@ -133,13 +133,17 @@ def parse_dense_text(path: str, skip_header: bool) -> Optional[np.ndarray]:
 
 def bin_columns_native(X: np.ndarray, col_indices: np.ndarray,
                        bounds_list, nan_to: np.ndarray,
-                       out_dtype) -> Optional[np.ndarray]:
+                       out_dtype,
+                       nan_cells: Optional[np.ndarray] = None
+                       ) -> Optional[np.ndarray]:
     """Bin numerical columns of a row-major matrix with the native
     kernel (ltpu_bin_columns); None when native is unavailable or the
     matrix dtype is unsupported (caller falls back to numpy).
 
     ``bounds_list``: per-selected-column float64 ascending upper
-    bounds; ``nan_to``: per-selected-column target bin for NaN cells.
+    bounds; ``nan_to``: per-selected-column target bin for NaN cells;
+    ``nan_cells``: a C-contiguous int64 array, one entry a selected
+    column, to which the kernel adds the NaN cells it saw there.
     """
     lib = _load()
     if lib is None or X.ndim != 2:
@@ -165,5 +169,7 @@ def bin_columns_native(X: np.ndarray, col_indices: np.ndarray,
         np.ascontiguousarray(bounds), bnd_off,
         np.ascontiguousarray(nan_to, np.int32),
         out.ctypes.data_as(ctypes.c_void_p),
-        int(out.dtype == np.uint16))
+        int(out.dtype == np.uint16),
+        None if nan_cells is None
+        else nan_cells.ctypes.data_as(ctypes.c_void_p))
     return out

@@ -136,6 +136,67 @@ def test_compact_grower_multi_chunk_windows(quantized):
                                atol=1e-4, rtol=1e-4)
 
 
+def test_compact_grower_builds_the_child_of_fewer_rows(monkeypatch):
+    """The compact grower's second pass builds the histogram of the child
+    the PARTITION counted fewer rows in (ROADMAP S13). The split search's
+    counts are hessian-ratio estimates, and with uneven hessians (a rare
+    class that fails often) they call the small child the larger one: on
+    one chip the estimate no longer decides, and the trees still agree
+    with the masked grower's row for row."""
+    from lightgbm_tpu.ops import grow
+    asked = []
+    inner = grow._left_is_smaller
+
+    def spy(n_left, cnt, est_left_small, rows_sharded):
+        asked.append(rows_sharded)
+        return inner(n_left, cnt, est_left_small, rows_sharded)
+
+    monkeypatch.setattr(grow, "_left_is_smaller", spy)
+    # the partition's count where every row is on this device, whatever
+    # the search estimated; the estimate where rows are sharded
+    assert bool(inner(jnp.int32(3), jnp.int32(10), jnp.bool_(False), False))
+    assert not bool(inner(jnp.int32(7), jnp.int32(10), jnp.bool_(True),
+                          False))
+    assert not bool(inner(jnp.int32(3), jnp.int32(10), jnp.bool_(False),
+                          True))
+    (bins, g, h, w, fm, fnb, fnan) = _mk(5003, 7, 32, seed=13)
+    # a tenth of the rows, those of one column's low bins, carry 40x the
+    # hessian of the others: the child that holds them is small in rows
+    # and large in hessian
+    rare = np.asarray(bins[0]) < 3
+    h = jnp.asarray(np.where(rare, 4.0, 0.1).astype(np.float32))
+    g = g + jnp.asarray(np.where(rare, 6.0, 0.0).astype(np.float32))
+    cfg_m = GrowConfig(num_leaves=14, num_bins=32,
+                       split=SplitParams(min_data_in_leaf=5.0),
+                       grower="masked", hist_method="scatter")
+    cfg_c = cfg_m._replace(grower="compact", chunk=1024)
+    tm, rlm = grow_tree(cfg_m, bins, g, h, w, fm, fnb, fnan)
+    tc, rlc = grow_tree(cfg_c, bins, g, h, w, fm, fnb, fnan)
+    assert asked and not any(asked)
+    # the estimate would have chosen otherwise at some node of this tree
+    n = int(tc.num_leaves) - 1
+    lc, rc = np.asarray(tc.left_child)[:n], np.asarray(tc.right_child)[:n]
+
+    def rows_and_hess(child):       # a child is ~leaf or an internal node
+        if child < 0:
+            return (float(np.asarray(tc.leaf_count)[~child]),
+                    float(np.asarray(tc.leaf_weight)[~child]))
+        (a, b), (c, d) = rows_and_hess(lc[child]), rows_and_hess(rc[child])
+        return a + c, b + d
+
+    sides = [(rows_and_hess(lc[i]), rows_and_hess(rc[i])) for i in range(n)]
+    assert any((l[0] <= r[0]) != (l[1] <= r[1]) for l, r in sides)
+    for name in ("split_feature", "threshold_bin", "leaf_count",
+                 "left_child", "right_child"):
+        np.testing.assert_array_equal(np.asarray(getattr(tm, name)),
+                                      np.asarray(getattr(tc, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(rlm), np.asarray(rlc))
+    np.testing.assert_allclose(np.asarray(tm.leaf_value),
+                               np.asarray(tc.leaf_value),
+                               atol=1e-4, rtol=1e-4)
+
+
 def test_hist_from_rows_int_exact():
     """int8 nibble histogram is exact integer arithmetic."""
     from lightgbm_tpu.ops.histogram import hist_from_rows_int

@@ -230,6 +230,42 @@ def test_telemetry_records_fused_path_tree_stats(tmp_path):
     assert bst.num_trees() == 4
 
 
+MISSING_VALUE_COUNTERS = (
+    "bin_cells", "bin_cells_missing", "tree_leaf_count", "tree_splits", "tree_splits_on_missing",
+    "tree_splits_default_left")
+
+
+@pytest.mark.parametrize("valid", [True, False])
+def test_missing_value_counters_are_declared_and_run_beside_telemetry(
+        tmp_path, valid):
+    """ISSUE 35: the six host counters are families of obs/schemas.py
+    (no labels), counted whether or not a recorder runs, on the eager
+    path (tree/fetch) and the fused one (the deferred trees'
+    materialisation); the recorder's histogram ``tree_leaves`` keeps its
+    kind beside the counter ``tree_leaf_count``."""
+    from lightgbm_tpu.obs import schemas
+    from lightgbm_tpu.obs.registry import registry
+    for name in MISSING_VALUE_COUNTERS:
+        assert schemas.METRICS[name]["kind"] == "counter"
+        assert schemas.METRICS[name]["labels"] == ()
+    assert schemas.METRICS["tree_leaves"]["kind"] == "histogram"
+    before = {k: registry.counter(k).snapshot()
+              for k in MISSING_VALUE_COUNTERS}
+    path = str(tmp_path / "run.jsonl")
+    bst = _small_train(tmp_path, callbacks=[cbm.telemetry(path)], rounds=3,
+                       valid=valid)
+    leaves = sum(t["num_leaves"] for t in bst.dump_model()["tree_info"])
+    got = {k: registry.counter(k).snapshot() - before[k]
+           for k in MISSING_VALUE_COUNTERS}
+    assert got["tree_leaf_count"] == leaves
+    assert got["tree_splits"] == leaves - 3
+    assert got["bin_cells"] == (800 if valid else 600) * 8
+    # a dense table: no NaN bin, no cell in one, no node with a direction
+    assert got["bin_cells_missing"] == 0
+    assert got["tree_splits_on_missing"] == 0
+    assert got["tree_splits_default_left"] == 0
+
+
 def test_disabled_recorder_writes_nothing(tmp_path):
     path = str(tmp_path / "never.jsonl")
     was_enabled = Timer.enabled()
