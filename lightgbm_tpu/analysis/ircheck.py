@@ -264,10 +264,17 @@ def build_specs(jax) -> List[IRSpec]:
     def b_lambdarank(ctx):
         from ..ranking import _lambdarank_grads
         fn = getattr(_lambdarank_grads, "unwrapped", _lambdarank_grads)
-        args = (sds((128,), jnp.float32), sds((8, 16), jnp.int32),
-                sds((8, 16), jnp.bool_), sds((128,), jnp.float32),
-                sds((128,), jnp.float32), 1.0, 30, True, 8)
-        return fn, args, (5, 6, 7, 8), None
+        # two length classes (ranking._RankLayout): 6 queries at width
+        # 8 in two blocks, 2 at width 16 in one
+        def length_class(nb, blk, w):
+            return (sds((nb, blk), jnp.int32), sds((nb, blk), jnp.int32),
+                    sds((nb, blk, w), jnp.float32),
+                    sds((nb, blk), jnp.float32))
+        args = (sds((128,), jnp.float32),
+                (length_class(2, 3, 8), length_class(1, 2, 16)),
+                sds((128,), jnp.int32), sds((128,), jnp.float32),
+                1.0, 30, True)
+        return fn, args, (4, 5, 6), None
 
     def b_ndcg(ctx):
         from ..ranking import _ndcg_at
@@ -329,7 +336,7 @@ def build_specs(jax) -> List[IRSpec]:
         IRSpec("prediction/forest_leaves@default", "prediction.py",
                "_forest_leaves", "T=8 L=16 rows=16", b_forest_leaves),
         IRSpec("ranking/lambdarank_grads@default", "ranking.py",
-               "_lambdarank_grads", "n=128 nq=8 Q=16 trunc=30",
+               "_lambdarank_grads", "n=128 nq=8 widths=8,16 trunc=30",
                b_lambdarank),
         IRSpec("ranking/ndcg@default", "ranking.py", "_ndcg_at",
                "n=128 nq=8 eval_at=1,3,5,10", b_ndcg),

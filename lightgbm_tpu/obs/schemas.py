@@ -225,9 +225,16 @@ METRICS = {
                "to this work"},
     "rank_pair_slots": {
         "kind": "counter", "labels": (),
-        "doc": "pair slots the passes COMPUTED: query blocks x block "
-               "size x (longest query)^2 in the padded form; "
+        "doc": "pair slots the passes COMPUTED: the sum over the data "
+               "set's length classes (ranking.py _length_classes) of "
+               "query blocks x block size x (class width)^2; "
                "rank_pairs / rank_pair_slots is the fill"},
+    "rank_row_slots": {
+        "kind": "counter", "labels": (),
+        "doc": "row slots the passes MOVED: the sum over the length "
+               "classes of query blocks x block size x class width (the "
+               "score read into them, the gradients read back out); "
+               "rows / rank_row_slots is how much of that was documents"},
     "valid_rows_scored": {
         "kind": "counter", "labels": (),
         "doc": "validation rows a new tree was routed over (rows of "

@@ -2,10 +2,17 @@
 
 Re-design of /root/reference/src/objective/rank_objective.hpp
 (LambdarankNDCG :56-296, RankXENDCG) and src/metric/rank_metric.hpp +
-dcg_calculator.cpp for TPU: queries are padded to a common max length and
-processed in vmapped blocks, so the per-query O(Q^2) pairwise lambda
-computation is a batched dense tensor op instead of nested loops. NDCG
-pads nothing: ``group=`` keeps a query's rows contiguous, so one stable
+dcg_calculator.cpp for TPU: the per-query O(Q^2) pairwise lambda
+computation is a batched dense tensor op over padded query blocks instead
+of nested loops. The lambdarank gradient pads a query to the width of its
+LENGTH CLASS, not to the data set's longest query: the classes (widths
+that double, the widest the longest query) come from the query lengths
+alone and are built once per data set with everything no round changes
+(``_length_classes``, ``_RankLayout``); a round reads the score into the
+classes' slots, runs every class's blocks inside one program and reads
+each row's gradient back from its one slot. ``RankXENDCG`` and
+``MapMetric`` still pad to the longest (``_pad_queries``). NDCG pads
+nothing: ``group=`` keeps a query's rows contiguous, so one stable
 sort of the flat rows by (query, -score) ranks every query in place, and
 what no round changes is built once per data set (``_NDCGEvaluator``).
 """
@@ -76,24 +83,24 @@ def source_loop_pairs(sizes: np.ndarray, labels_by_query, trunc: int) -> int:
     return total
 
 
-def _ranks_desc(scores: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """rank[i] = position of item i when sorted by score desc (0-based);
-    padded items get a huge rank."""
-    s = jnp.where(mask, scores, -jnp.inf)
-    order = jnp.argsort(-s, axis=-1)
-    ranks = jnp.zeros_like(order)
-    put = jnp.arange(order.shape[-1])[None, :].astype(order.dtype)
-    ranks = jnp.take_along_axis(
-        jnp.zeros_like(order), order, axis=-1)  # placeholder
-    ranks = jnp.zeros_like(order).at[
-        jnp.arange(order.shape[0])[:, None], order].set(
-        jnp.broadcast_to(put, order.shape))
-    return ranks
+def _ranks_desc(s: jnp.ndarray) -> jnp.ndarray:
+    """rank[i] = position of item i of ``s[blk, w]`` when each query is
+    sorted by score descending (0-based), ties in document order, as a
+    stable ``jnp.argsort(-s)`` places them: the documents ahead of ``i``
+    are counted over the pairwise compare, so no sort and no scatter.
+    Padded items (``-inf``) rank after every document."""
+    before = jnp.arange(s.shape[-1])
+    ahead = (s[:, None, :] > s[:, :, None]) | (
+        (s[:, None, :] == s[:, :, None])
+        & (before[None, None, :] < before[None, :, None]))
+    return jnp.sum(ahead, axis=2, dtype=jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
 def _inverse_max_dcg(gains: jnp.ndarray, mask: jnp.ndarray,
                      k: int) -> jnp.ndarray:
-    """1 / maxDCG@k per query (DCGCalculator analog)."""
+    """1 / maxDCG@k per query (DCGCalculator analog); no round changes
+    it, so it runs once per length class, from ``set_dataset``."""
     g = jnp.where(mask, gains, -jnp.inf)
     g_sorted = -jnp.sort(-g, axis=-1)
     pos = jnp.arange(g.shape[-1])
@@ -104,6 +111,100 @@ def _inverse_max_dcg(gains: jnp.ndarray, mask: jnp.ndarray,
     use = (pos[None, :] < k) & jnp.isfinite(g_sorted)
     dcg = jnp.sum(jnp.where(use, g_sorted * disc[None, :], 0.0), axis=-1)
     return jnp.where(dcg > 0, 1.0 / dcg, 0.0)
+
+
+# pair slots of one block's [blk, w, w] temporaries, and the narrowest
+# length class (one sublane tile)
+_BLOCK_PAIR_SLOTS = 1 << 25
+_NARROWEST_CLASS = 8
+
+
+def _block_queries(width: int) -> int:
+    """Queries of one block at padded width ``width``."""
+    return max(1, _BLOCK_PAIR_SLOTS // (width * width))
+
+
+def _length_classes(sizes: np.ndarray):
+    """The padded widths a data set's queries are computed at, from
+    their lengths alone: ``[(width, ids of its queries in data set
+    order)]``, narrowest first. Widths double from ``_NARROWEST_CLASS``
+    and the widest is the longest query itself; a query goes to the
+    narrowest class that holds it; a class that would hold less than one
+    block of its width joins the next one up, and an empty one does not
+    exist. Queries all of one length, or all under the narrowest width,
+    make ONE class of the longest's width, which is the padding every
+    data set had before the classes."""
+    sizes = np.asarray(sizes, np.int64)
+    longest = max(1, int(sizes.max())) if len(sizes) else 1
+    widths = []
+    w = _NARROWEST_CLASS
+    while w < longest:
+        widths.append(w)
+        w *= 2
+    widths.append(longest)
+    home = np.searchsorted(widths, sizes)
+    classes, first = [], 0
+    for k, w in enumerate(widths):
+        members = np.flatnonzero((home >= first) & (home <= k))
+        if k + 1 < len(widths) and len(members) < _block_queries(w):
+            continue
+        first = k + 1
+        if len(members):
+            classes.append((w, members))
+    return classes
+
+
+@dataclasses.dataclass
+class _RankLayout:
+    """What no round changes of one data set's lambdarank pass, built
+    once in ``set_dataset``. ``classes``: per length class, on the
+    device, ``(start [nb, blk], length [nb, blk], gains [nb, blk, w],
+    1 / maxDCG [nb, blk])``: its queries' first rows and lengths (row
+    index and mask are an ``iota`` compare away), their documents' gains
+    padded to the class's width, and the truncated best DCG's inverse;
+    the last block is filled with queries of no document. ``slot_of_row
+    [rows]``: every row sits in exactly one slot of exactly one class,
+    at this offset of the classes' slots laid end to end. ``pair_slots``
+    / ``row_slots``: the sums over the classes of ``nb * blk * w^2`` and
+    ``nb * blk * w``."""
+
+    classes: tuple
+    slot_of_row: jnp.ndarray
+    pair_slots: int
+    row_slots: int
+
+
+def _rank_layout(qb: np.ndarray, gain_of_row: np.ndarray,
+                 trunc: int) -> _RankLayout:
+    qb = np.asarray(qb, np.int64)
+    sizes = np.diff(qb)
+    first_slot = np.zeros(len(sizes), np.int64)
+    classes, row_slots, pair_slots = [], 0, 0
+    for w, members in _length_classes(sizes):
+        # blocks as even as the block size allows: never larger than
+        # ``_block_queries(w)``, and fewer queries of no document
+        nb = -(-len(members) // _block_queries(w))
+        blk = -(-len(members) // nb)
+        fill = (0, nb * blk - len(members))
+        start = np.pad(qb[:-1][members], fill)
+        length = np.pad(sizes[members], fill)
+        pos = np.arange(w)
+        mask = pos[None, :] < length[:, None]
+        rows = np.where(mask, start[:, None] + pos[None, :], 0)
+        gains = jnp.asarray(np.where(mask, gain_of_row[rows], 0.0),
+                            jnp.float32)
+        inv_max = _inverse_max_dcg(gains, jnp.asarray(mask), k=trunc)
+        classes.append((
+            jnp.asarray(start.reshape(nb, blk), jnp.int32),
+            jnp.asarray(length.reshape(nb, blk), jnp.int32),
+            gains.reshape(nb, blk, w), inv_max.reshape(nb, blk)))
+        first_slot[members] = row_slots + np.arange(len(members)) * w
+        row_slots += nb * blk * w
+        pair_slots += nb * blk * w * w
+    slot_of_row = (np.repeat(first_slot - qb[:-1], sizes)
+                   + np.arange(int(qb[-1])))
+    return _RankLayout(tuple(classes), jnp.asarray(slot_of_row, jnp.int32),
+                       pair_slots, row_slots)
 
 
 class LambdarankNDCG(Objective):
@@ -125,14 +226,10 @@ class LambdarankNDCG(Objective):
         if qb is None:
             raise ValueError(
                 "lambdarank requires query information (group)")
-        idx, mask, sizes = _pad_queries(qb)
-        self.q_idx = jnp.asarray(idx)
-        self.q_mask = jnp.asarray(mask)
+        self._qb = np.asarray(qb)
         label = np.asarray(dataset.get_label())
         max_label = int(label.max())
         gains_tbl = _label_gains(self.cfg, max_label)
-        self.gain_of_row = jnp.asarray(gains_tbl[label.astype(np.int64)],
-                                       jnp.float32)
         self._n = len(label)
         # position-debiased LTR (rank_objective.hpp:43-56,297-334):
         # factorize raw positions to ids; biases start at 0 and are
@@ -147,19 +244,27 @@ class LambdarankNDCG(Objective):
             self.pos_biases = jnp.zeros((self.num_pos,), jnp.float32)
         else:
             self.num_pos = 0
-        # queries processed in blocks to bound the [blk, Q, Q] tensor
-        qmax = idx.shape[1]
-        target_elems = 1 << 25
-        self._blk = max(1, min(idx.shape[0],
-                               target_elems // max(1, qmax * qmax)))
+        # the padded layout of the pass, shaped by the query lengths
+        self._layout = _rank_layout(
+            self._qb, gains_tbl[label.astype(np.int64)], self.trunc)
         # what one pass counts (obs/schemas.py): the source loop's pairs
-        # against the slots this padded form computes
+        # against the slots this padded form computes and moves
+        sizes = np.diff(self._qb)
         self._pass_queries = len(sizes)
         self._pass_pairs = source_loop_pairs(
-            sizes, np.split(label, qb[1:-1]), self.trunc)
-        self._pass_slots = (-(-len(sizes) // self._blk) * self._blk
-                            * qmax * qmax)
+            sizes, np.split(label, self._qb[1:-1]), self.trunc)
         self._ready = True
+
+    @property
+    def q_idx(self) -> np.ndarray:
+        """``[queries, longest]`` row index of every query, on the host
+        and built on demand: the pass itself holds no such array."""
+        return _pad_queries(self._qb)[0]
+
+    @property
+    def q_mask(self) -> np.ndarray:
+        """``[queries, longest]``: whether ``q_idx`` names a document."""
+        return _pad_queries(self._qb)[1]
 
     def _update_position_biases(self, g, h):
         """Newton-Raphson step on per-position bias factors
@@ -186,14 +291,15 @@ class LambdarankNDCG(Objective):
         # per-iteration host state keeps it on the eager path — so an
         # eager block-scan here would dispatch op-by-op every
         # iteration: tpulint TPL001)
+        layout = self._layout
         g, h = _lambdarank_grads(
-            score, self.q_idx, self.q_mask, self.gain_of_row, weight,
-            jnp.float32(self.sigmoid), trunc=self.trunc,
-            norm=self.norm, blk=self._blk)
+            score, layout.classes, layout.slot_of_row, weight,
+            jnp.float32(self.sigmoid), trunc=self.trunc, norm=self.norm)
         from .obs.registry import registry
         registry.counter("rank_queries").inc(self._pass_queries)
         registry.counter("rank_pairs").inc(self._pass_pairs)
-        registry.counter("rank_pair_slots").inc(self._pass_slots)
+        registry.counter("rank_pair_slots").inc(layout.pair_slots)
+        registry.counter("rank_row_slots").inc(layout.row_slots)
         # bias update sees the weighted lambdas, like the reference
         # (weights are folded in inside the query loop before
         # UpdatePositionBiasFactors runs, rank_objective.hpp:75-86)
@@ -202,24 +308,33 @@ class LambdarankNDCG(Objective):
         return g, h
 
 
-@functools.partial(jax.jit, static_argnames=("trunc", "norm", "blk"))
-@scoped("boost/gradients/lambdarank")
-def _lambdarank_grads(score, q_idx, q_mask, gain_of_row, weight,
-                      sigma, trunc, norm, blk):
-    """LambdaMART lambdas/hessians over padded query blocks, fused
-    into one XLA program (compiled once per dataset shape; ``trunc``/
-    ``norm``/``blk`` are config-static)."""
-    gains = gain_of_row[q_idx]               # [nq, Q]
-    inv_max = _inverse_max_dcg(gains, q_mask, trunc)  # [nq]
+def _query_slices(padded_score, start, width):
+    """``[queries, width]``: ``width`` consecutive elements from each of
+    ``start``, one contiguous slice a query (a query's rows are
+    consecutive), from a score padded at its end so no slice clamps."""
+    return jax.lax.gather(
+        padded_score, start[:, None],
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(),
+            start_index_map=(0,)),
+        slice_sizes=(width,),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
-    def per_block(idx_b, mask_b, gains_b, inv_b):
-        s = score[idx_b] * mask_b            # [blk, Q]
-        s = jnp.where(mask_b, s, -jnp.inf)
-        ranks = _ranks_desc(s, mask_b)       # [blk, Q]
+
+@functools.partial(jax.jit, static_argnames=("trunc", "norm"))
+@scoped("boost/gradients/lambdarank")
+def _lambdarank_grads(score, classes, slot_of_row, weight, sigma, trunc,
+                      norm):
+    """LambdaMART lambdas/hessians over padded query blocks, every
+    length class of ``_RankLayout`` inside this one XLA program (compiled
+    once per dataset shape; ``trunc``/``norm`` are config-static)."""
+
+    def per_block(sd, mask_b, gains_b, inv_b):
+        s = jnp.where(mask_b, sd, -jnp.inf)  # [blk, w]
+        ranks = _ranks_desc(s)               # [blk, w]
         disc = jnp.where(
             mask_b, 1.0 / jnp.log2(2.0 + ranks.astype(s.dtype)), 0.0)
-        # pairwise tensors [blk, Q, Q]
-        sd = jnp.where(mask_b, score[idx_b], 0.0)
+        # pairwise tensors [blk, w, w]
         s_diff = sd[:, :, None] - sd[:, None, :]
         g_diff = gains_b[:, :, None] - gains_b[:, None, :]
         d_diff = disc[:, :, None] - disc[:, None, :]
@@ -256,29 +371,22 @@ def _lambdarank_grads(score, q_idx, q_mask, gain_of_row, weight,
             h_q = h_q * norm_f[:, None]
         return g_q, h_q
 
-    nq, qmax = q_idx.shape
-    pad_q = (-nq) % blk
-    idx_p = jnp.pad(q_idx, ((0, pad_q), (0, 0)))
-    mask_p = jnp.pad(q_mask, ((0, pad_q), (0, 0)))
-    gains_p = jnp.pad(gains, ((0, pad_q), (0, 0)))
-    inv_p = jnp.pad(inv_max, (0, pad_q))
-    nb = idx_p.shape[0] // blk
+    # a slice of the widest class from any query's first row stays inside
+    padded_score = jnp.pad(score, (0, classes[-1][2].shape[-1]))
 
-    def body(carry, xs):
-        g_acc, h_acc = carry
-        idx_b, mask_b, gains_b, inv_b = xs
-        g_q, h_q = per_block(idx_b, mask_b, gains_b, inv_b)
-        flat = idx_b.reshape(-1)
-        g_acc = g_acc.at[flat].add(
-            jnp.where(mask_b, g_q, 0.0).reshape(-1))
-        h_acc = h_acc.at[flat].add(
-            jnp.where(mask_b, h_q, 0.0).reshape(-1))
-        return (g_acc, h_acc), None
+    def block(_, xs):
+        start_b, length_b, gains_b, inv_b = xs
+        width = gains_b.shape[-1]
+        mask_b = jnp.arange(width)[None, :] < length_b[:, None]
+        sd = jnp.where(
+            mask_b, _query_slices(padded_score, start_b, width), 0.0)
+        return None, per_block(sd, mask_b, gains_b, inv_b)
 
-    init = (jnp.zeros_like(score), jnp.zeros_like(score))
-    xs = (idx_p.reshape(nb, blk, qmax), mask_p.reshape(nb, blk, qmax),
-          gains_p.reshape(nb, blk, qmax), inv_p.reshape(nb, blk))
-    (g, h), _ = jax.lax.scan(body, init, xs)
+    slots = [jax.lax.scan(block, None, c)[1] for c in classes]
+    # every row sits in one slot of one class: a gather, not a scatter-add
+    g, h = (jnp.concatenate([x.reshape(-1) for x in of_classes])
+            .at[slot_of_row].get(mode="promise_in_bounds")
+            for of_classes in zip(*slots))
     if weight is not None:
         g = g * weight
         h = h * weight

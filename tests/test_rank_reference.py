@@ -13,8 +13,14 @@ ISSUE 34: NDCG is one registered program a round (``ranking/ndcg``) over
 a per-data-set state: executions and state builds are counted, two data
 sets keep two states, the edge queries and tied scores are held to the
 loop, and the program's jaxpr holds no ``queries x longest`` array.
+
+ISSUE 36: the lambdarank gradient pads a query to its length class
+(``ranking._length_classes``): length mixes that cross every class edge,
+weighted or not, against the reference and the loop; the traced program's
+shapes; the counters as sums over the classes.
 """
 
+import functools
 import gc
 import os
 import sys
@@ -119,27 +125,99 @@ def dataset():
     return ds.construct()
 
 
+# Length mixes for the gradient's length classes. A block of the real
+# size (2**25 pair slots) would merge every class of a test-sized table
+# into the widest, so all but ``ragged`` run with a block of 256 pair
+# slots: 4 queries of width 8, 1 of any wider class. Each lists the
+# class widths the rule has to give it.
+SMALL_BLOCK = 256
+MIXES = {
+    # the file's own table under the real block: one class of 350
+    "ragged": (SIZES, None, [350]),
+    # 1, w - 1, w, w + 1 around every width in use; the longest is 65
+    "edges": ([1, 7, 8, 9, 15, 16, 17, 31, 32, 33, 63, 64, 65, 5],
+              SMALL_BLOCK, [8, 16, 32, 64, 65]),
+    "one_far_longer": ([3, 5, 8, 2, 6, 4, 7, 200], SMALL_BLOCK, [8, 200]),
+    "one_length": ([12] * 6, SMALL_BLOCK, [12]),
+    "single_query": ([40], SMALL_BLOCK, [40]),
+    # two queries are less than a block of width 8: they join width 16
+    "merged_up": ([3, 5, 20, 30], SMALL_BLOCK, [16, 30]),
+    "all_under_the_narrowest": ([2, 5, 1, 7, 3], SMALL_BLOCK, [7]),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _mix(name):
+    """Sizes, grades, per-row weights and the constructed data set of a
+    mix, built once."""
+    sizes = np.asarray(MIXES[name][0])
+    n = int(sizes.sum())
+    if name == "ragged":
+        label = _labels()
+    else:
+        label = np.random.RandomState(len(name)).randint(0, 5, n) \
+            .astype(np.float32)
+    weight = np.random.RandomState(n).uniform(0.5, 2.0, n).astype(np.float32)
+    ds = lgb.Dataset(np.random.RandomState(3).randn(n, 3), label=label,
+                     group=sizes).construct()
+    return sizes, label, weight, ds
+
+
+def _mix_scores(name, kind):
+    if name == "ragged":
+        return _scores(kind)
+    n = int(np.sum(MIXES[name][0]))
+    if kind == "zero":
+        return np.zeros(n, np.float32)
+    s = np.random.RandomState(n + 1).randn(n)
+    return (np.round(s, 1) if kind == "tied" else s).astype(np.float32)
+
+
+@pytest.fixture
+def length_mix(request, monkeypatch):
+    """A mix's tables, with its block size in force for the test."""
+    name = request.param
+    if MIXES[name][1] is not None:
+        monkeypatch.setattr(ranking, "_BLOCK_PAIR_SLOTS", MIXES[name][1])
+    return (name, *_mix(name))
+
+
+def _lambdarank(norm=True, trunc=30):
+    return LambdarankNDCG(Config.from_params({
+        "objective": "lambdarank", "lambdarank_norm": norm,
+        "lambdarank_truncation_level": trunc}))
+
+
 @pytest.mark.parametrize("trunc", [1, 30, 500])
 @pytest.mark.parametrize("norm", [True, False])
 @pytest.mark.parametrize("scores", ["zero", "random", "tied"])
-def test_lambdarank_gradients_agree_with_the_reference(dataset, scores, norm,
-                                                       trunc):
-    label, score = _labels(), _scores(scores)
-    obj = LambdarankNDCG(Config.from_params({
-        "objective": "lambdarank", "lambdarank_norm": norm,
-        "lambdarank_truncation_level": trunc}))
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("length_mix", sorted(MIXES), indirect=True)
+def test_lambdarank_gradients_agree_with_the_reference(length_mix, weighted,
+                                                       scores, norm, trunc):
+    name, sizes, label, weight, dataset = length_mix
+    score = _mix_scores(name, scores)
+    obj = _lambdarank(norm, trunc)
     obj.set_dataset(dataset)
-    got = obj.grad_hess(jnp.asarray(score), jnp.asarray(label), None)
+    assert [c[2].shape[-1] for c in obj._layout.classes] == MIXES[name][2]
+    got = obj.grad_hess(jnp.asarray(score), jnp.asarray(label),
+                        jnp.asarray(weight) if weighted else None)
     ref = reference_rank.lambdarank_grad_hess(
         jnp.asarray(score), jnp.asarray(label),
-        reference_rank.query_layout(SIZES), 1.0, trunc, norm)
-    loop = loop_lambdarank(score, label, SIZES, 1.0, trunc, norm)
+        reference_rank.query_layout(sizes), 1.0, trunc, norm)
+    loop = loop_lambdarank(score, label, sizes, 1.0, trunc, norm)
+    # the source folds a row's weight into its lambda after the query's
+    # normalisation (rank_objective.hpp:75-86)
+    scale = weight if weighted else 1.0
     for g, r, want in zip(got, ref, loop):
         assert _close(r, want), "the reference departs from the source"
-        assert _close(g, want), "the program departs from the source"
+        assert _close(g, want * scale), "the program departs from the source"
+    if name == "ragged":
         # a query of one document and one of equal grades weigh no pair
-        assert not np.any(np.asarray(g)[0:1]) \
-            and not np.any(np.asarray(g)[EQUAL_QUERY])
+        for g in got:
+            assert not np.any(np.asarray(g)[0:1]) \
+                and not np.any(np.asarray(g)[EQUAL_QUERY])
 
 
 @pytest.mark.parametrize("k", [1, 3, 5, 10])
@@ -305,15 +383,22 @@ def test_the_sort_key_orders_as_argsort_of_the_negated_score():
                           np.asarray(jnp.argsort(-s)))
 
 
-def _jaxpr_vars(jaxpr):
-    """Every operand and intermediate of a jaxpr, inner jaxprs too."""
-    yield from jaxpr.invars
+def _jaxprs(jaxpr):
+    """A jaxpr and every jaxpr inside its equations."""
+    yield jaxpr
     for eqn in jaxpr.eqns:
-        yield from eqn.outvars
         for param in eqn.params.values():
             inner = getattr(param, "jaxpr", param)
             if hasattr(inner, "eqns"):
-                yield from _jaxpr_vars(inner)
+                yield from _jaxprs(inner)
+
+
+def _jaxpr_vars(jaxpr):
+    """Every operand and intermediate of a jaxpr, inner jaxprs too."""
+    for j in _jaxprs(jaxpr):
+        yield from j.invars
+        for eqn in j.eqns:
+            yield from eqn.outvars
 
 
 def test_the_ndcg_program_holds_no_queries_by_longest_array(dataset):
@@ -330,6 +415,82 @@ def test_the_ndcg_program_holds_no_queries_by_longest_array(dataset):
     sizes = [int(np.prod(v.aval.shape)) for v in _jaxpr_vars(jaxpr.jaxpr)]
     assert len(sizes) > 10 and N in sizes, "the walk saw the program"
     assert max(sizes) < padded, sorted(sizes)[-3:]
+
+
+@pytest.mark.parametrize("length_mix", ["edges", "one_far_longer"],
+                         indirect=True)
+def test_the_gradient_program_is_shaped_by_the_length_classes(length_mix):
+    """What ``rank.grad_ms_per_round`` rests on: on mixed lengths the
+    traced ``ranking/lambdarank_grads`` pads nothing to the longest query
+    outside the widest class, is handed no per-row gains to gather and
+    scatter-adds nothing."""
+    name, sizes, label, weight, dataset = length_mix
+    obj = _lambdarank()
+    obj.set_dataset(dataset)
+    lay, n, longest = obj._layout, len(label), int(sizes.max())
+    assert obj.q_idx.shape == obj.q_mask.shape == (len(sizes), longest)
+    operands = (jnp.zeros(n, jnp.float32), lay.classes, lay.slot_of_row)
+    fn = getattr(ranking._lambdarank_grads, "unwrapped",
+                 ranking._lambdarank_grads)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: fn(*a, None, jnp.float32(1.0), trunc=30, norm=True)
+    )(*operands).jaxpr
+    shapes = [tuple(v.aval.shape) for v in _jaxpr_vars(jaxpr)]
+    assert len(shapes) > 50 and (n,) in shapes, "the walk saw the program"
+    # the score is the one float [rows] operand: no gains to gather
+    assert [v.aval.dtype for v in jaxpr.invars
+            if v.aval.shape == (n,)] == [np.float32, np.int32]
+    blocks = {(c[2].shape[1], c[2].shape[2]) for c in lay.classes}
+    assert len(blocks) == len(MIXES[name][2]) > 1
+    # (the tie-break's [1, w, w] document order is a constant a class)
+    blocks |= {(1, w) for _, w in blocks}
+    for shape in shapes:
+        # no [queries, longest] array, and the pair tensors are a
+        # class's own [blk, w, w]: [*, longest, longest] is the widest's
+        assert shape[-2:] != (len(sizes), longest), shape
+        if len(shape) == 3 and shape[1] == shape[2] > 1:
+            assert (shape[0], shape[1]) in blocks, shape
+    pair = [s for s in shapes if len(s) == 3 and s[1] == s[2] == longest]
+    assert pair and {s[0] for s in pair} == {1}, "the widest holds one query"
+    prims = {e.primitive.name for j in _jaxprs(jaxpr) for e in j.eqns}
+    assert "gather" in prims and not any("scatter" in p for p in prims), \
+        sorted(p for p in prims if "scatter" in p)
+
+
+@pytest.mark.parametrize("length_mix", sorted(MIXES), indirect=True)
+def test_the_pass_counts_its_classes_slots(length_mix):
+    """``rank_pairs`` is the source loop's whatever the layout;
+    ``rank_pair_slots`` / ``rank_row_slots`` are the sums over the
+    classes; the classes are a function of the lengths alone."""
+    name, sizes, label, weight, dataset = length_mix
+    obj, again = _lambdarank(), _lambdarank()
+    obj.set_dataset(dataset)
+    again.set_dataset(dataset)
+    again.set_dataset(dataset)
+    lay = obj._layout
+    for a, b in zip(jax.tree_util.tree_leaves(lay.classes + (lay.slot_of_row,)),
+                    jax.tree_util.tree_leaves(again._layout.classes
+                                              + (again._layout.slot_of_row,))):
+        assert a.shape == b.shape and np.array_equal(a, b)
+    dims = [c[2].shape for c in lay.classes]
+    assert lay.pair_slots == sum(nb * blk * w * w for nb, blk, w in dims)
+    assert lay.row_slots == sum(nb * blk * w for nb, blk, w in dims)
+    # every row has one slot of its own, inside its class's block
+    slots = np.asarray(lay.slot_of_row)
+    assert len(set(slots.tolist())) == len(label) and slots.max() < lay.row_slots
+    # never more than one pad to the longest under the same block rule
+    longest = int(np.max(sizes))
+    old_blk = max(1, min(len(sizes), ranking._BLOCK_PAIR_SLOTS // longest ** 2))
+    assert lay.pair_slots <= -(-len(sizes) // old_blk) * old_blk * longest ** 2
+    if len(dims) == 1:
+        assert dims[0][2] == longest
+    names = ("rank_queries", "rank_pairs", "rank_pair_slots", "rank_row_slots")
+    before = [registry.counter(c).snapshot() for c in names]
+    obj.grad_hess(jnp.zeros(len(label), jnp.float32), jnp.asarray(label), None)
+    moved = [registry.counter(c).snapshot() - b for c, b in zip(names, before)]
+    assert moved == [len(sizes), ranking.source_loop_pairs(
+        sizes, np.split(label, np.cumsum(sizes)[:-1]), 30),
+        lay.pair_slots, lay.row_slots]
 
 
 def test_auc_is_the_rank_sum_statistic_with_ties():

@@ -257,3 +257,56 @@ def test_wide_partition_moves_a_chunks_rows_once(which, request):
                if op in ("reshape", "copy") and shape in (words, flat)
                and not name.endswith("/gather")]
     assert 1 <= len(retiles) <= 2, retiles
+
+
+def test_the_lambdarank_pass_is_shaped_by_its_length_classes(one_chip):
+    """The ranking gradient as the chip's compiler leaves it (PR 36), on
+    the length mix of the benchmark's ranking table (18,919 lognormal
+    queries, mean ~120, one of 1,251): every length class's blocks are in
+    the ONE program; a row's gradient is READ from its one slot (two
+    element gathers, ``f32[rows]``, nothing else gathered by element and
+    nothing scattered); the ranks are a count over the pairwise compare
+    (no sort); no ``[queries, longest]`` array and no pair tensor of the
+    longest's width for more queries than the widest class holds; and
+    the program's temporaries are a few blocks' worth, not the padded
+    table's. The row gather's pattern is shown to match first."""
+    import numpy as np
+    from lightgbm_tpu import ranking
+    rng = np.random.default_rng(30331)
+    sizes = np.clip(np.rint(rng.lognormal(0.0, 0.55, 18919) * 103.3),
+                    1, 1251).astype(np.int64)
+    sizes[7] = 1251
+    qb = np.concatenate([[0], np.cumsum(sizes)])
+    n = int(qb[-1])
+    lay = ranking._rank_layout(qb, np.ones(n), 30)
+    dims = [c[2].shape for c in lay.classes]
+    assert [w for _, _, w in dims][::len(dims) - 1] == [128, 1251], dims
+    assert n <= lay.row_slots < 2 * n and lay.pair_slots < 1.2e9
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    fn = getattr(ranking._lambdarank_grads, "unwrapped",
+                 ranking._lambdarank_grads)
+    with no_compile_cache():
+        compiled = fn.trace(
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+            jax.tree_util.tree_map(sds, lay.classes), sds(lay.slot_of_row),
+            None, jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+            trunc=30, norm=True).lower(
+                lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    gathers = re.findall(r" = (\w+\[[\d,]*\])\S* gather\(", hlo)
+    assert gathers.count(f"f32[{n}]") == 2, gathers
+    # a query's score is one contiguous slice, never an element gather
+    assert all(g == f"f32[{n}]" or not g.endswith(f"[{lay.row_slots}]")
+               for g in gathers), gathers
+    assert not re.search(r" (scatter|sort)\(", hlo)
+    assert f"[{len(sizes)},1251]" not in hlo
+    widest_blk = dims[-1][1]
+    lead = {int(m) for m in re.findall(r"\[(\d+),1251,1251\]", hlo)}
+    assert lead <= {1, widest_blk}, lead
+    for _, blk, w in dims:
+        assert f"[{blk},{w}]" in hlo, (blk, w)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * (1 << 25), mem
