@@ -58,6 +58,27 @@ def _count_tree(tree: Tree) -> None:
         int((on_missing & (dt & DEFAULT_LEFT_MASK != 0)).sum()))
 
 
+def _rows_streamed(tree: Tree) -> Dict[str, int]:
+    """What the ``tree/fetch`` span is stamped with: the rows the tree's
+    layers streamed, by the tree's own counts (its arrays are on the
+    host). ``hist_rows``: the root's rows and, every split, the smaller
+    child's (the child whose histogram is built; the sibling's is
+    subtracted); ``partition_rows``: every split's parent's rows."""
+    nn = tree.num_nodes
+    parent = np.asarray(tree.internal_count[:nn], np.int64)
+
+    def child_rows(child):      # a child >= 0 is a node, else leaf ~child
+        child = np.asarray(child[:nn])
+        return np.where(child >= 0, parent[np.maximum(child, 0)],
+                        tree.leaf_count[np.where(child < 0, ~child, 0)])
+
+    smaller = np.minimum(child_rows(tree.left_child),
+                         child_rows(tree.right_child))
+    return {"hist_rows": int(parent[0] if nn else tree.leaf_count[0])
+            + int(smaller.sum()),
+            "partition_rows": int(parent.sum())}
+
+
 def _donate(*argnums: int):
     """Donation argnums for the fused step/scan wrappers.
 
@@ -2350,11 +2371,13 @@ class GBDTBooster:
                         is_first=(len(self.models) < self.K))
                 # the finished tree's arrays, device to host: what a
                 # validation set (or a linear leaf) makes every round pay
-                with timed("tree/fetch"):
+                with timed("tree/fetch") as fetch:
                     tree = tree_from_arrays(
                         dev_tree, self.train_set.mappers,
                         self.train_set.used_feature_indices())
                     _count_tree(tree)
+                    if fetch is not None:   # a span only under a capture
+                        fetch.attrs = _rows_streamed(tree)
                 tree.apply_shrinkage(shrinkage)
                 if lin is not None:
                     self._attach_linear(tree, lin, shrinkage)

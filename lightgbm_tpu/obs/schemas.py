@@ -234,7 +234,12 @@ METRICS = {
         "doc": "row slots the passes MOVED: the sum over the length "
                "classes of query blocks x block size x class width (the "
                "score read into them, the gradients read back out); "
-               "rows / rank_row_slots is how much of that was documents"},
+               "rank_rows / rank_row_slots is how much of that was "
+               "documents"},
+    "rank_rows": {
+        "kind": "counter", "labels": (),
+        "doc": "documents (rows in a query) those passes went over, "
+               "bumped beside rank_queries"},
     "valid_rows_scored": {
         "kind": "counter", "labels": (),
         "doc": "validation rows a new tree was routed over (rows of "

@@ -33,7 +33,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
            "scoped", "scope_of_op_name", "scopes_from_hlo_text",
-           "op_scopes", "write_op_scopes"]
+           "op_scopes", "tables_of_run", "write_op_scopes"]
 
 #: every device scope the program opens, innermost wins. ``boost/*``:
 #: the phases of one fused iteration (models/gbdt.py
@@ -44,7 +44,14 @@ __all__ = ["DEVICE_SCOPES", "SCOPED_ENTRIES", "ScopeTable", "scope",
 #: that apply it, the (g, h) payload's slices and writes), histogram
 #: build and sibling
 #: subtraction, the split scan, and ``grow/fixed``: what a split costs
-#: whatever its rows (tree and leaf bookkeeping, masks, bounds). Under a
+#: whatever its rows (tree and leaf bookkeeping, masks, bounds). What a
+#: tree costs ONCE, over every row and whatever its splits:
+#: ``grow/setup`` (before the first split: the weighted (g, h) payload,
+#: the bin words packed and padded into the ping-pong buffers, the
+#: root's sums and stored best split; the root's histogram and scan
+#: inside it keep their own scopes) and ``grow/row_leaf`` (after the
+#: last: every row routed through the finished tree, the quantized
+#: leaf outputs renewed from that). Under a
 #: mesh, the two collective layers: ``grow/hist/allreduce`` (every
 #: histogram reduction: parallel/comms.py) and ``grow/sums/allreduce``
 #: (root and leaf sums, counts, SplitInfo combines: bytes, not KB).
@@ -71,6 +78,8 @@ DEVICE_SCOPES: Tuple[str, ...] = (
     "grow/sums/allreduce",
     "grow/split_scan",
     "grow/fixed",
+    "grow/setup",
+    "grow/row_leaf",
     "valid/score_update",
     "metric/eval",
 )
@@ -108,14 +117,13 @@ def scoped(name: str) -> Callable:
     return deco
 
 
-def scope_of_op_name(op_name: str) -> Tuple[Optional[str], bool]:
-    """``(innermost declared scope or None, every scope declared?)``
-    of one ``op_name`` path (``jit(step)/boost/grow/while/body/grow/
-    partition/key_sort/sort``). A scope starts at a segment that is a
-    declared root (``boost``, ``grow``); what follows must spell a
-    declared scope, or the path carries an undeclared one."""
+def _scopes_on(op_name: str) -> Tuple[list, bool]:
+    """``(the declared scopes on the path, outermost first; every scope
+    declared?)`` of one ``op_name`` path. A scope starts at a segment
+    that is a declared root (``boost``, ``grow``); what follows must
+    spell a declared scope, or the path carries an undeclared one."""
     segs = op_name.split("/")
-    found, ok, i = None, True, 0
+    found, ok, i = [], True, 0
     while i < len(segs):
         if segs[i] not in _ROOTS:
             i += 1
@@ -129,18 +137,52 @@ def scope_of_op_name(op_name: str) -> Tuple[Optional[str], bool]:
             ok = False
             i += 1
         else:
-            found = "/".join(match)
+            found.append("/".join(match))
             i += len(match)
     return found, ok
 
 
+def scope_of_op_name(op_name: str) -> Tuple[Optional[str], bool]:
+    """``(innermost declared scope or None, every scope declared?)``
+    of one ``op_name`` path (``jit(step)/boost/grow/while/body/grow/
+    partition/key_sort/sort``)."""
+    found, ok = _scopes_on(op_name)
+    return (found[-1] if found else None), ok
+
+
+def _own_name(op_name: str) -> str:
+    """What the op was called where it was made. A ``shard_map`` body's
+    ops are named relative to the body, and the compiler, inlining the
+    body, writes the call's own name before each
+    (``jit(fn)/shard_map/boost/grow/...``; before an op the body left
+    nameless, its instruction's: ``jit(fn)/shard_map/compare.718``): what
+    follows the last ``shard_map`` is the op's own."""
+    segs = op_name.split("/")
+    if "shard_map" in segs:
+        at = len(segs) - 1 - segs[::-1].index("shard_map")
+        return "/".join(segs[at + 1:])
+    return op_name
+
+
 class ScopeTable(dict):
     """``{op: scope}``; ``derived`` holds the ops whose scope is not
-    their own ``op_name``'s but was derived (:func:`scopes_from_hlo_text`)."""
+    their own ``op_name``'s but was derived (:func:`scopes_from_hlo_text`);
+    ``missing`` the declared scopes the program's CURRENT lowering opens
+    and no op of the executable carries: a compile cache answered with an
+    executable written before those scopes were (or the compiler folded a
+    small one away: a warning, not a refusal); ``module`` the
+    executable's module name (``jit_grow_tree_impl``)."""
     derived: frozenset = frozenset()
+    missing: Tuple[str, ...] = ()
+    #: the HLO module's name: what a trace's module line calls the program
+    module: Optional[str] = None
 
 
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+#: a name location of a lowering's debug info:
+#: ``loc("jit(f)/grow/setup/pad"(#loc7))``
+_LOC_NAME_RE = re.compile(r'loc\("([^"]*)"\(')
+_MODULE_RE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _CALLED_RE = re.compile(
     r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
@@ -216,9 +258,14 @@ def _parse_hlo(text: str):
     return insts, roots, callers
 
 
-def scopes_from_hlo_text(text: str, derive: bool = True
+def scopes_from_hlo_text(text: str, derive: bool = True,
+                         lowering: Optional[str] = None
                          ) -> Optional[ScopeTable]:
     """The op -> scope table of one optimized HLO module's text.
+    ``lowering``: the same program's lowered module WITH debug info
+    (``lowered.as_text(debug_info=True)``), whose name locations carry
+    the scopes the source opens now; those of them that no ``op_name``
+    of ``text`` carries are the table's ``missing``, with one log line.
 
     Direct: every instruction (of every computation, fused ones
     included) whose own ``op_name`` lies under a declared scope.
@@ -234,14 +281,30 @@ def scopes_from_hlo_text(text: str, derive: bool = True
     an op that only moves a value (``copy``, ``copy-start/done``,
     ``bitcast``, ``slice``, ...), the scope of what produced the value,
     followed through tuples and out of ``while`` loops to the body's
-    root operand; anything else, the scope of the instruction that
-    calls its computation (a loop body's op -> the loop's scope)."""
+    root operand; anything else the compiler made (no ``op_name``, or a
+    path-less one: ``reduce_window_sum``), the commonest scope of what
+    produced its operands (each looked up the same way: a scan the
+    tracer named without its scope path belongs with the values it
+    reads); else the scope of the instruction that calls its
+    computation (a loop body's op -> the loop's scope); and what of the
+    compiler's is still bare then (the entry computation's, which
+    nothing calls: a parameter's prefetch, a constant's broadcast), the
+    commonest scope of what consumes it. An op that carries the
+    tracer's path (``jit(f)/neg``) and no declared scope was traced
+    outside every ``with scope``: neither neighbour rule touches it, so
+    a body of work nobody named stays ``(unscoped)`` in a report. (Inside
+    a ``shard_map`` body the tracer's own bare op and the compiler's are
+    both path-less, :func:`_own_name`: there the rules cannot tell them
+    apart and take both.)"""
     insts, roots, callers = _parse_hlo(text)
     direct: Dict[str, str] = {}
+    carried = set()
     for name, inst in insts.items():
         if inst[5] is None:
             continue
-        found, ok = scope_of_op_name(inst[5])
+        on_path, ok = _scopes_on(inst[5])
+        carried.update(on_path)
+        found = on_path[-1] if on_path else None
         if not ok:
             from ..utils.log import log_warning
             log_warning(f"op_scopes: op {name} carries a scope "
@@ -258,6 +321,23 @@ def scopes_from_hlo_text(text: str, derive: bool = True
                     "metadata stripped); no table")
         return None
     table = ScopeTable(direct)
+    mm = _MODULE_RE.search(text)
+    table.module = mm.group(1) if mm else None
+    if lowering is not None:
+        opened = set()
+        for path in set(_LOC_NAME_RE.findall(lowering)):
+            opened.update(_scopes_on(path)[0])
+        table.missing = tuple(sc for sc in DEVICE_SCOPES
+                              if sc in opened and sc not in carried)
+        if table.missing:
+            from ..utils.log import log_warning
+            log_warning("op_scopes: the program opens "
+                        f"{', '.join(table.missing)} and no op of the "
+                        "executable carries them: a compile cache "
+                        "answered with an executable written before "
+                        "these scopes were (read it from a fresh "
+                        "JAX_COMPILATION_CACHE_DIR once), or the "
+                        "compiler folded them away")
     if not derive:
         return table
 
@@ -328,21 +408,58 @@ def scopes_from_hlo_text(text: str, derive: bool = True
             comp = insts[caller][0]
         return None
 
-    derived = set()
-    for name, (comp, opcode, operands, called, _, _) in insts.items():
+    def commonest(scopes_seen) -> Optional[str]:
+        counts: Dict[str, int] = {}
+        for sc in scopes_seen:
+            if sc is not None:
+                counts[sc] = counts.get(sc, 0) + 1
+        return max(sorted(counts), key=lambda sc: counts[sc]) \
+            if counts else None
+
+    def of_operands(operands) -> Optional[str]:
+        return commonest(table.get(op) or produced_by(op, set())
+                         for op in operands)
+
+    bare = []
+    for name, (comp, opcode, operands, called, _, op_name) in insts.items():
         if name in direct or opcode in ("parameter", "constant"):
             continue
+        # the tracer's own op, traced outside every ``with scope``
+        # (``jit(f)/neg``): a layer nobody named, which no neighbour's
+        # scope may claim
+        traced = op_name is not None and "/" in _own_name(op_name)
         sc = None
         if opcode == "fusion" and called:
             sc = of_fusion(called[0])
         elif opcode in _MOVERS:
             sc = produced_by(name, set())
+        if sc is None and not traced:
+            # text order: an operand's own derivation is in the table
+            sc = of_operands(operands)
         if sc is None:
             sc = of_caller(comp)
         if sc is not None:
             table[name] = sc
-            derived.add(name)
-    table.derived = frozenset(derived)
+        elif not traced:
+            bare.append(name)
+    if bare:
+        users: Dict[str, list] = {}
+        for name, inst in insts.items():
+            for op in inst[2]:
+                users.setdefault(op, []).append(name)
+
+        def of_users(name: str, depth: int = 0) -> Optional[str]:
+            # a tuple only bundles: what consumes IT consumes the value
+            return commonest(
+                table.get(u) or (of_users(u, depth + 1) if depth < 4
+                                 and insts[u][1] == "tuple" else None)
+                for u in users.get(name, ()))
+
+        for name in reversed(bare):     # a consumer's own comes first
+            sc = of_users(name)
+            if sc is not None:
+                table[name] = sc
+    table.derived = frozenset(set(table) - set(direct))
     return table
 
 
@@ -350,11 +467,13 @@ def op_scopes(entry: str) -> Optional[ScopeTable]:
     """``{op: scope}`` for the executable the registered entry point
     ``entry`` (``"gbdt/fused_iter"``) last compiled, from the compiled
     module's ``op_name`` metadata (and, for the ops the compiler left
-    without any, derived: the table's ``derived``). Built on request — a re-lowering at
-    the last call's avals and a compile the persistent cache answers
-    where it is on — never in a round's path. ``None`` where no live
-    entry of that name has run, where the executable cannot be reached,
-    or where it carries no scope or an undeclared one."""
+    without any, derived: the table's ``derived``). Built on request —
+    a re-lowering at the last call's avals and a compile the persistent
+    cache answers where it is on — never in a round's path. ``None``
+    where no live entry of that name has run, where the executable cannot
+    be reached, or where it carries no scope or an undeclared one. A
+    declared scope the re-lowering opens and the executable lacks is the
+    table's ``missing`` (a stale cache entry: see :class:`ScopeTable`)."""
     from .jit_tracker import live_entries
     for fn in reversed(live_entries(entry)):
         avals = getattr(fn, "last_avals", None)
@@ -362,34 +481,43 @@ def op_scopes(entry: str) -> Optional[ScopeTable]:
             continue
         args, kwargs = avals
         try:
-            text = fn.unwrapped.lower(*args, **kwargs).compile().as_text()
+            lowered = fn.unwrapped.lower(*args, **kwargs)
+            text = lowered.compile().as_text()
+            lowering = lowered.as_text(debug_info=True)
         except Exception as e:
             from ..utils.log import log_warning
             log_warning(f"op_scopes: cannot reach {entry!r}'s "
                         f"executable ({type(e).__name__}: {e})")
             return None
-        return scopes_from_hlo_text(text)
+        return scopes_from_hlo_text(text, lowering=lowering)
     return None
+
+
+def tables_of_run(entries: Sequence[str] = SCOPED_ENTRIES
+                  ) -> Dict[str, Optional[ScopeTable]]:
+    """``{entry: op_scopes(entry)}`` for each of ``entries`` that has
+    run in this process (``None`` where its table is refused)."""
+    from .jit_tracker import live_entries
+    return {entry: op_scopes(entry) for entry in entries
+            if any(getattr(fn, "last_avals", None) is not None
+                   for fn in live_entries(entry))}
 
 
 def write_op_scopes(directory: str,
                     entries: Sequence[str] = SCOPED_ENTRIES) -> Optional[str]:
     """Write ``op_scopes.json`` (``{entry: {"ops": {op: scope},
-    "derived": [op, ...]}}``) beside a program-owned capture, for each
-    of ``entries`` that has run and has a table. Returns the path, or
-    ``None`` when none had one."""
+    "derived": [op, ...], "missing": [scope, ...], "module": name}}``)
+    beside a program-owned capture, for each of ``entries`` that has run
+    and has a table. Returns the path, or ``None`` when none had one."""
     import json
     import os
-    from .jit_tracker import live_entries
     doc = {}
-    for entry in entries:
-        if not any(getattr(fn, "last_avals", None) is not None
-                   for fn in live_entries(entry)):
-            continue
-        table = op_scopes(entry)
+    for entry, table in tables_of_run(entries).items():
         if table:
             doc[entry] = {"ops": dict(table),
-                          "derived": sorted(table.derived)}
+                          "derived": sorted(table.derived),
+                          "missing": list(table.missing),
+                          "module": table.module}
     if not doc:
         return None
     path = os.path.join(directory, "op_scopes.json")

@@ -10,6 +10,10 @@ table the program wrote beside it (``op_scopes.json``), it reports
   counts its duration minus its children's — a loop never counts its
   body twice — and events are put down to the scope of their op; the
   time of ops the table does not hold is stated as ``(unscoped)``;
+- the same by PROGRAM: the compiler numbers every program's ops from
+  the same names (``fusion.202`` is one op of the grower and another of
+  the gradient), so each registered entry's table is laid over the ops
+  that ran inside THAT program's executions (the ``XLA Modules`` line);
 - device busy time as the union of op intervals, and every idle gap
   over a threshold put down to the innermost PROGRAM span covering its
   middle (the ``timed`` sections the capture holds as
@@ -28,17 +32,19 @@ a hand-built trace tests it. Times are seconds.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["HOST_SPAN_ROOTS", "find_xplane", "load", "op_head",
-           "self_times", "union_seconds", "by_scope", "idle_gaps",
-           "report", "render_report", "load_op_scopes"]
+           "union_seconds", "op_times", "idle_gaps", "report",
+           "render_report", "load_op_scopes"]
 
 DEVICE_PLANE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 UNSCOPED = "(unscoped)"
 
@@ -46,9 +52,13 @@ UNSCOPED = "(unscoped)"
 #: what tells a program span from the runtime's own host events in a
 #: capture
 HOST_SPAN_ROOTS = ("dataset", "train", "callbacks", "boosting", "tree",
-                   "tree_learner", "engine", "ingest")
+                   "tree_learner", "engine", "ingest", "valid", "metric",
+                   "compile")
 
 Event = Tuple[str, float, float]        # (name, start_s, dur_s)
+#: ``{program (a module's name) or None: {op: scope}}``; ``None`` holds
+#: a table that names no program (laid over any program without its own)
+Tables = Dict[Optional[str], Dict[str, str]]
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -60,16 +70,19 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str) -> Dict[str, Any]:
-    """``{"devices": {plane: [op events]}, "host": [events]}`` from an
-    ``.xplane.pb`` (imports jax: the one place this module does)."""
+    """``{"devices": {plane: [op events]}, "modules": {plane: [program
+    executions]}, "host": [events]}`` from an ``.xplane.pb`` (imports
+    jax: the one place this module does)."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    out: Dict[str, Any] = {"devices": {}, "host": []}
+    out: Dict[str, Any] = {"devices": {}, "modules": {}, "host": []}
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
             for line in plane.lines:
-                if line.name == OPS_LINE:
-                    out["devices"][plane.name] = [
+                key = {OPS_LINE: "devices",
+                       MODULES_LINE: "modules"}.get(line.name)
+                if key:
+                    out[key][plane.name] = [
                         (ev.name, ev.start_ns * 1e-9, ev.duration_ns * 1e-9)
                         for ev in line.events]
         elif plane.name == HOST_PLANE:
@@ -86,9 +99,10 @@ def op_head(name: str) -> str:
     return name.split(" = ", 1)[0].strip().lstrip("%")
 
 
-def self_times(events: Iterable[Event]) -> List[Tuple[str, float]]:
-    """``[(name, self_s)]``: each event's duration minus the part its
-    nested children cover (events of one line nest properly)."""
+def _self_events(events: Iterable[Event]) -> List[Event]:
+    """``[(name, start_s, self_s)]`` by start: each event's duration
+    minus the part its nested children cover (events of one line nest
+    properly)."""
     evs = sorted(events, key=lambda e: (e[1], -e[2]))
     out: List[List[Any]] = []
     stack: List[Tuple[float, int]] = []         # (end, index into out)
@@ -96,10 +110,10 @@ def self_times(events: Iterable[Event]) -> List[Tuple[str, float]]:
         while stack and start >= stack[-1][0] - 1e-12:
             stack.pop()
         if stack:
-            out[stack[-1][1]][1] -= dur
-        out.append([name, dur])
+            out[stack[-1][1]][2] -= dur
+        out.append([name, start, dur])
         stack.append((start + dur, len(out) - 1))
-    return [(n, max(s, 0.0)) for n, s in out]
+    return [(n, t, max(s, 0.0)) for n, t, s in out]
 
 
 def union_seconds(intervals: Iterable[Tuple[float, float]]
@@ -115,16 +129,34 @@ def union_seconds(intervals: Iterable[Tuple[float, float]]
     return sum(b - a for a, b in merged), merged
 
 
-def by_scope(ops: Iterable[Event], table: Optional[Dict[str, str]]
-             ) -> Dict[str, float]:
-    """Device self time by scope (``UNSCOPED`` for ops the table does
-    not hold; with no table, everything)."""
-    out: Dict[str, float] = {}
-    table = table or {}
-    for name, self_s in self_times(ops):
-        sc = table.get(op_head(name), UNSCOPED)
-        out[sc] = out.get(sc, 0.0) + self_s
-    return out
+def program_of(module_event: str) -> str:
+    """``jit_grow_tree_impl`` from ``jit_grow_tree_impl(1234567)``: a
+    module event is named by the program and its fingerprint."""
+    return module_event.split("(", 1)[0]
+
+
+def op_times(ops: Iterable[Event], tables: Optional[Tables],
+             modules: Iterable[Event] = ()
+             ) -> List[Tuple[Optional[str], str, float, str]]:
+    """``[(program, op, self_s, scope)]``, one row an op of a program:
+    every op event's self time, summed by the program whose execution
+    it started in (``None`` outside any) and its name, with the scope
+    THAT program's table gives it (``tables``: ``{program: {op:
+    scope}}``; the table under ``None``, a file's that names no program,
+    serves every program without one of its own; ``UNSCOPED`` where
+    neither holds the op)."""
+    tables = tables or {}
+    runs = sorted((s, s + d, program_of(n)) for n, s, d in modules)
+    starts = [r[0] for r in runs]
+    acc: Dict[Tuple[Optional[str], str], float] = {}
+    for name, start, self_s in _self_events(ops):
+        i = bisect.bisect_right(starts, start) - 1
+        prog = runs[i][2] if i >= 0 and start < runs[i][1] else None
+        key = (prog, op_head(name))
+        acc[key] = acc.get(key, 0.0) + self_s
+    fallback = tables.get(None, {})
+    return [(prog, op, s, tables.get(prog, fallback).get(op, UNSCOPED))
+            for (prog, op), s in acc.items()]
 
 
 def _is_program_span(name: str) -> bool:
@@ -155,29 +187,52 @@ def idle_gaps(ops: Iterable[Event], host: Iterable[Event],
 
 
 def load_op_scopes(path: str, entry: Optional[str] = None
-                   ) -> Optional[Dict[str, str]]:
-    """One flat op -> scope table from an ``op_scopes.json``
-    (``{entry: {"ops": {op: scope}, "derived": [...]}}``, as
-    ``obs.scopes.write_op_scopes`` writes it; entries merged, or only
-    ``entry``)."""
+                   ) -> Optional[Tables]:
+    """The op -> scope tables of an ``op_scopes.json`` (``{entry:
+    {"ops": {op: scope}, "derived": [...], "module": program}}``, as
+    ``obs.scopes.write_op_scopes`` writes it; every entry, or only
+    ``entry``), by program. An entry that names no program (a file from
+    before the per-program overlay) is merged under ``None``."""
     with open(path, encoding="utf-8") as fh:
         doc = json.load(fh)
-    table: Dict[str, str] = {}
+    tables: Tables = {}
     for name, tab in doc.items():
         if entry is None or name == entry:
-            table.update(tab["ops"])
-    return table or None
+            tables.setdefault(tab.get("module"), {}).update(tab["ops"])
+    return tables or None
 
 
-def report(trace: Dict[str, Any], table: Optional[Dict[str, str]],
+def _by_time(acc: Dict[Any, float]) -> Dict[Any, float]:
+    return dict(sorted(acc.items(), key=lambda kv: -kv[1]))
+
+
+def report(trace: Dict[str, Any], tables: Optional[Tables],
            min_gap_s: float = 1e-3) -> Dict[str, Any]:
-    """The numbers of one capture, per device plane."""
+    """The numbers of one capture, per device plane. ``tables``:
+    ``{program: {op: scope}}`` (:func:`load_op_scopes`). ``by_program``
+    is there where the capture has a module line: each program's
+    executions, device self time and that time by scope."""
     devices = []
     for plane, ops in sorted(trace["devices"].items()):
         if not ops:
             continue
+        mods = trace.get("modules", {}).get(plane, [])
         busy, merged = union_seconds((s, s + d) for _, s, d in ops)
-        scopes = by_scope(ops, table)
+        rows = op_times(ops, tables, mods)
+        scopes: Dict[str, float] = {}
+        programs: Dict[str, Dict[str, Any]] = {}
+        for n, _, _ in mods:
+            got = programs.setdefault(program_of(n), {
+                "runs": 0, "self_s": 0.0, "by_scope": {}})
+            got["runs"] += 1
+        for prog, _, self_s, sc in rows:
+            scopes[sc] = scopes.get(sc, 0.0) + self_s
+            if prog in programs:
+                got = programs[prog]
+                got["self_s"] += self_s
+                got["by_scope"][sc] = got["by_scope"].get(sc, 0.0) + self_s
+        for got in programs.values():
+            got["by_scope"] = _by_time(got["by_scope"])
         total = sum(scopes.values())
         devices.append({
             "plane": plane,
@@ -186,11 +241,12 @@ def report(trace: Dict[str, Any], table: Optional[Dict[str, str]],
             "self_s": total,
             "scoped_share": (1.0 - scopes.get(UNSCOPED, 0.0) / total)
             if total else 0.0,
-            "by_scope": dict(sorted(scopes.items(),
-                                    key=lambda kv: -kv[1])),
+            "by_scope": _by_time(scopes),
+            "by_program": dict(sorted(programs.items(),
+                                      key=lambda kv: -kv[1]["self_s"])),
             "idle_gaps": idle_gaps(ops, trace["host"], min_gap_s),
         })
-    return {"devices": devices, "has_table": bool(table)}
+    return {"devices": devices, "has_table": bool(tables)}
 
 
 def render_report(rep: Dict[str, Any]) -> str:
@@ -210,6 +266,14 @@ def render_report(rep: Dict[str, Any]) -> str:
         for sc, sec in dev["by_scope"].items():
             lines.append(f"  {sc:28s} {sec:12.6f} s  "
                          f"{100 * sec / total:6.2f}%")
+        for prog, got in dev.get("by_program", {}).items():
+            if got["self_s"] < 1e-3 * total:
+                continue
+            lines.append(f"  program {prog}: {got['runs']} runs, "
+                         f"{got['self_s']:.6f} s")
+            for sc, sec in got["by_scope"].items():
+                lines.append(f"    {sc:26s} {sec:12.6f} s  "
+                             f"{100 * sec / (got['self_s'] or 1.0):6.2f}%")
         for g in dev["idle_gaps"]:
             where = g["span"] or "(no program span)"
             host = f" > {g['host']}" if g["host"] \

@@ -1741,39 +1741,45 @@ def _grow_compact_impl(cfg: GrowConfig,
     # row-id / in-bag tracking (see GrowConfig.track_rows); consumers
     # force it on regardless of the flag
     track = cfg.track_rows or cegb or bundled
-    bins_rm = bins_T.T                      # [n, F] row-major for gathers
-    w = row_weight.astype(dtype)
-    inbag = row_weight > 0
-    gw2 = jnp.stack([grad * w, hess * w], axis=-1)  # [n, 2]
-    # scatter and pallas pass through; anything else ("onehot" legacy
-    # spelling included) maps to the MXU nibble kernel
-    hmethod = cfg.hist_method \
-        if cfg.hist_method in ("scatter", "pallas") else "mxu"
+    # once a tree, over every row, whatever the splits: the weighted
+    # payload here, the packed words, ping-pong buffers and root below
+    with scope("grow/setup"):
+        bins_rm = bins_T.T                      # [n, F] row-major for gathers
+        w = row_weight.astype(dtype)
+        inbag = row_weight > 0
+        gw2 = jnp.stack([grad * w, hess * w], axis=-1)  # [n, 2]
+        # scatter and pallas pass through; anything else ("onehot" legacy
+        # spelling included) maps to the MXU nibble kernel
+        hmethod = cfg.hist_method \
+            if cfg.hist_method in ("scatter", "pallas") else "mxu"
 
-    quant = cfg.quantized
-    if quant:
-        # GradientDiscretizer analog (gradient_discretizer.hpp:35):
-        # per-tree scales, stochastic rounding, int8 payload.
-        def pmax(x):
-            return lax.pmax(x, cfg.axis_name) if cfg.axis_name else x
+        quant = cfg.quantized
+        if quant:
+            # GradientDiscretizer analog (gradient_discretizer.hpp:35):
+            # per-tree scales, stochastic rounding, int8 payload.
+            def pmax(x):
+                if not cfg.axis_name:
+                    return x
+                with scope("grow/sums/allreduce"):
+                    return lax.pmax(x, cfg.axis_name)
 
-        half = max(1, cfg.quant_bins // 2)
-        gs = jnp.maximum(pmax(jnp.max(jnp.abs(gw2[:, 0]))), 1e-30) / half
-        hs = jnp.maximum(pmax(jnp.max(gw2[:, 1])), 1e-30) \
-            / max(1, cfg.quant_bins)
-        if cfg.stochastic and quant_key is not None:
-            k = quant_key
-            if cfg.axis_name and not fp:
-                # feature-parallel replicates rows: every device must
-                # round identically
-                k = jax.random.fold_in(k, lax.axis_index(cfg.axis_name))
-            u = jax.random.uniform(k, (n, 2), dtype)
-        else:
-            u = jnp.full((n, 2), 0.5, dtype)
-        gq = jnp.clip(jnp.floor(gw2[:, 0] / gs + u[:, 0]), -127, 127)
-        hq = jnp.clip(jnp.floor(gw2[:, 1] / hs + u[:, 1]), 0, 127)
-        gw2_q = jnp.stack([gq, hq], axis=-1).astype(jnp.int8)
-        scale2 = jnp.stack([gs, hs])
+            half = max(1, cfg.quant_bins // 2)
+            gs = jnp.maximum(pmax(jnp.max(jnp.abs(gw2[:, 0]))), 1e-30) / half
+            hs = jnp.maximum(pmax(jnp.max(gw2[:, 1])), 1e-30) \
+                / max(1, cfg.quant_bins)
+            if cfg.stochastic and quant_key is not None:
+                k = quant_key
+                if cfg.axis_name and not fp:
+                    # feature-parallel replicates rows: every device must
+                    # round identically
+                    k = jax.random.fold_in(k, lax.axis_index(cfg.axis_name))
+                u = jax.random.uniform(k, (n, 2), dtype)
+            else:
+                u = jnp.full((n, 2), 0.5, dtype)
+            gq = jnp.clip(jnp.floor(gw2[:, 0] / gs + u[:, 0]), -127, 127)
+            hq = jnp.clip(jnp.floor(gw2[:, 1] / hs + u[:, 1]), 0, 127)
+            gw2_q = jnp.stack([gq, hq], axis=-1).astype(jnp.int8)
+            scale2 = jnp.stack([gs, hs])
 
     def hist_f(h):
         """int32 histogram -> float stats for split search."""
@@ -2341,149 +2347,153 @@ def _grow_compact_impl(cfg: GrowConfig,
                 return hist_psum(lax.fori_loop(
                     0, window_chunks(cnt), body, acc0))
 
-    # the streamed copy of the bin matrix lives PACKED: u32 words of
-    # pack_w bin columns each (u8 arrays carry a (4,1) sub-byte tiling
-    # that taxes every dynamic slice / masked RMW ~2-4x)
-    bins_pk = bins_rm if Fp == F \
-        else jnp.pad(bins_rm, ((0, 0), (0, Fp - F)))
-    if nibble_bins:
-        nib = bins_pk.reshape(n, NW, 8).astype(jnp.uint32)
-        bins_pk = sum(nib[:, :, k] << (4 * k) for k in range(8))
-    else:
-        bins_pk = lax.bitcast_convert_type(
-            bins_pk.reshape(n, NW, pack_w), jnp.uint32)    # [n, NW]
-
-    # ---- root ----
-    # feature-parallel devices histogram only their own feature block
-    root_rows = _local_hist_rows(bins_pk, jnp.asarray(0, jnp.int32),
-                                 n) if fp else bins_rm
-    total_ci = psum(jnp.sum(inbag, dtype=jnp.int32))    # exact
-    total_c = total_ci.astype(dtype)
-    comm_ef0 = jnp.zeros((Fsp if sharded else FB, B, C),
-                         dtype) if use_ef else ()
-    if quant:
-        with comms.reduction_site("tree"):
-            root_hist = hist_psum(hist_from_rows_int(root_rows, gw2_q, B,
-                                                     hmethod))
-        if sharded:
-            # the GLOBAL feature-0 row lives on device 0's chunk only;
-            # broadcast it (exact int32 psum of one contributor) and
-            # sum the same bin sequence the gathered path sums
-            row0 = _sums_psum(
-                jnp.where(dev_idx == 0, root_hist[0],
-                          jnp.zeros_like(root_hist[0])), cfg.axis_name)
-            sums = (row0.astype(dtype) * scale2[None, :]).sum(axis=0)
+    # (the root's histogram and split search keep their own scopes:
+    # innermost wins)
+    with scope("grow/setup"):
+        # the streamed copy of the bin matrix lives PACKED: u32 words of
+        # pack_w bin columns each (u8 arrays carry a (4,1) sub-byte tiling
+        # that taxes every dynamic slice / masked RMW ~2-4x)
+        bins_pk = bins_rm if Fp == F \
+            else jnp.pad(bins_rm, ((0, 0), (0, Fp - F)))
+        if nibble_bins:
+            nib = bins_pk.reshape(n, NW, 8).astype(jnp.uint32)
+            bins_pk = sum(nib[:, :, k] << (4 * k) for k in range(8))
         else:
-            sums = hist_f(root_hist)[0].sum(axis=0)  # row hits feature 0
-        if vp:
-            # voting keeps the cache local; the root tuple is global
-            sums = _sums_psum(sums, cfg.axis_name)
-        total_g, total_h = sums[0], sums[1]
-    else:
-        total_g = psum(jnp.sum(gw2[:, 0]))
-        total_h = psum(jnp.sum(gw2[:, 1]))
-        with comms.reduction_site("tree"):
-            root_hist, comm_ef0 = hist_psum_ef(
-                hist_from_rows(root_rows, gw2, B, hmethod,
-                               cfg.hist_precision), comm_ef0)
+            bins_pk = lax.bitcast_convert_type(
+                bins_pk.reshape(n, NW, pack_w), jnp.uint32)    # [n, NW]
 
-    tree = _init_tree(L, B, dtype)
-    tree = tree._replace(
-        leaf_value=tree.leaf_value.at[0].set(leaf_output(total_g, total_h, p)),
-        leaf_weight=tree.leaf_weight.at[0].set(total_h),
-        leaf_count=tree.leaf_count.at[0].set(total_ci),
-    )
-    best = _BestSplits.init(L, B, dtype)
-    root_mask = None if interaction_groups is None \
-        else allowed_features(jnp.zeros((F_orig,), jnp.bool_))
-    cegb_state = ()
-    root_pen = None
-    if cegb:
-        coupled_used = coupled_used0
-        if cegb_lazy:
-            lazy_used = lazy_used0
-            root_nu = jnp.sum(~lazy_used & inbag[:, None],
-                              axis=0).astype(dtype)               # [F]
+        # ---- root ----
+        # feature-parallel devices histogram only their own feature block
+        root_rows = _local_hist_rows(bins_pk, jnp.asarray(0, jnp.int32),
+                                     n) if fp else bins_rm
+        total_ci = psum(jnp.sum(inbag, dtype=jnp.int32))    # exact
+        total_c = total_ci.astype(dtype)
+        comm_ef0 = jnp.zeros((Fsp if sharded else FB, B, C),
+                             dtype) if use_ef else ()
+        if quant:
+            with comms.reduction_site("tree"):
+                root_hist = hist_psum(hist_from_rows_int(root_rows, gw2_q, B,
+                                                         hmethod))
+            if sharded:
+                # the GLOBAL feature-0 row lives on device 0's chunk only;
+                # broadcast it (exact int32 psum of one contributor) and
+                # sum the same bin sequence the gathered path sums
+                row0 = _sums_psum(
+                    jnp.where(dev_idx == 0, root_hist[0],
+                              jnp.zeros_like(root_hist[0])), cfg.axis_name)
+                sums = (row0.astype(dtype) * scale2[None, :]).sum(axis=0)
+            else:
+                sums = hist_f(root_hist)[0].sum(axis=0)  # row hits feature 0
+            if vp:
+                # voting keeps the cache local; the root tuple is global
+                sums = _sums_psum(sums, cfg.axis_name)
+            total_g, total_h = sums[0], sums[1]
         else:
-            lazy_used = jnp.zeros((1, 1), jnp.bool_)
-            root_nu = jnp.zeros((F_orig,), dtype)
-        lazy_nu = jnp.zeros((L, F_orig), dtype).at[0].set(root_nu)
-        cegb_state = (coupled_used, lazy_used, lazy_nu)
-        root_pen = cegb_penalty(total_c, coupled_used, root_nu)
-    mono_state = ()
-    root_bounds = None
-    if has_mono:
-        leaf_min0 = jnp.full((L,), -jnp.inf, dtype)
-        leaf_max0 = jnp.full((L,), jnp.inf, dtype)
-        mono_state = (leaf_min0, leaf_max0)
-        if intermediate:
-            mono_state = mono_state + (jnp.zeros((L, L - 1), jnp.int8),)
-        root_bounds = (leaf_min0[0], leaf_max0[0])
-        if advanced:
-            # per-leaf bin-space boxes [lo, hi) per feature; the root
-            # covers everything
-            box_lo0 = jnp.zeros((L, F_orig), jnp.int32)
-            box_hi0 = jnp.full((L, F_orig), B, jnp.int32)
-            mono_state = mono_state + (box_lo0, box_hi0)
-            root_bounds = advanced_bounds(box_lo0, box_hi0,
-                                          tree.leaf_value,
-                                          tree.num_leaves,
-                                          box_lo0[0], box_hi0[0])
-    nmask_state = ()
-    root_node_mask = None
-    if use_bynode:
-        root_node_mask = node_feature_mask(0)
-        nmask_state = (jnp.zeros((L, F_orig), jnp.bool_)
-                       .at[0].set(root_node_mask),)
-        root_mask = root_node_mask if root_mask is None \
-            else root_mask & root_node_mask
-    # the root's "parent output" is its own unsmoothed output
-    # (GetParentOutput, serial_tree_learner.cpp:1005-1012)
-    root_out = tree.leaf_value[0]
-    best = best.store(0, best_for(hist_f(root_hist), total_g, total_h,
-                                  total_c, root_mask, root_pen,
-                                  root_out, jnp.asarray(0, jnp.int32),
-                                  root_bounds),
-                      jnp.asarray(True))
-    # histogram cache: full per-leaf [L, F, B, 2], or a bounded slot
-    # pool [PS, F, B, 2] with recompute-on-miss (HistogramPool analog,
-    # feature_histogram.hpp; budget from histogram_pool_size)
-    pooled = 0 < cfg.hist_pool_slots < L
-    PS = cfg.hist_pool_slots if pooled else L
-    hists = jnp.zeros((PS, FH, B, 2),
-                      jnp.int32 if quant else dtype).at[0].set(root_hist)
-    pool_state = ()
-    if pooled:
-        pool_state = (
-            jnp.full((L,), -1, jnp.int32).at[0].set(0),   # leaf2slot
-            jnp.full((PS,), -1, jnp.int32).at[0].set(0),  # slot2leaf
-            jnp.zeros((PS,), jnp.int32),                  # lru tick
+            total_g = psum(jnp.sum(gw2[:, 0]))
+            total_h = psum(jnp.sum(gw2[:, 1]))
+            with comms.reduction_site("tree"):
+                root_hist, comm_ef0 = hist_psum_ef(
+                    hist_from_rows(root_rows, gw2, B, hmethod,
+                                   cfg.hist_precision), comm_ef0)
+
+        tree = _init_tree(L, B, dtype)
+        tree = tree._replace(
+            leaf_value=tree.leaf_value.at[0].set(
+                leaf_output(total_g, total_h, p)),
+            leaf_weight=tree.leaf_weight.at[0].set(total_h),
+            leaf_count=tree.leaf_count.at[0].set(total_ci),
         )
-    pay0 = gw2_q if quant \
-        else (gw2.astype(jnp.bfloat16) if bf16_pay else gw2)
-    ord0 = (jnp.arange(n, dtype=jnp.uint32)
-            | jnp.where(inbag, _IB_BIT, jnp.uint32(0))) if track \
-        else jnp.zeros((2,), jnp.uint32)
-    bins2_0 = jnp.pad(bins_pk, ((PAD, PAD + SEG), (0, 0)))
-    state = _CompactState(
-        tree=tree, best=best, hists=hists,
-        # the wide partition stores the words FLAT (see wide_part)
-        bins2=bins2_0.reshape(-1) if wide_part else bins2_0,
-        # ... and the f32 payload PLANAR (see pay_planar)
-        pay2=jnp.concatenate(
-            [jnp.pad(pay0[:, c], (PAD, PAD + SEG)) for c in range(C)])
-        if pay_planar else jnp.pad(pay0, ((PAD, PAD + SEG), (0, 0))),
-        ord2=jnp.pad(ord0, (PAD, PAD + SEG)) if track else ord0,
-        leaf_buf=jnp.zeros((L,), jnp.int32),
-        leaf_begin=jnp.zeros((L,), jnp.int32),
-        leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(n),
-        branch=jnp.zeros((L, F_orig), jnp.bool_),
-        num_splits=jnp.asarray(0, jnp.int32),
-        cegb=cegb_state, mono=mono_state, node_masks=nmask_state,
-        pool=pool_state, comm_ef=comm_ef0,
-        # the first split's leaf is 0 (only the root has a stored
-        # candidate), so the prefetched parent is the root histogram
-        pcache=(jnp.zeros((1,), hists.dtype) if pooled else root_hist))
+        best = _BestSplits.init(L, B, dtype)
+        root_mask = None if interaction_groups is None \
+            else allowed_features(jnp.zeros((F_orig,), jnp.bool_))
+        cegb_state = ()
+        root_pen = None
+        if cegb:
+            coupled_used = coupled_used0
+            if cegb_lazy:
+                lazy_used = lazy_used0
+                root_nu = jnp.sum(~lazy_used & inbag[:, None],
+                                  axis=0).astype(dtype)               # [F]
+            else:
+                lazy_used = jnp.zeros((1, 1), jnp.bool_)
+                root_nu = jnp.zeros((F_orig,), dtype)
+            lazy_nu = jnp.zeros((L, F_orig), dtype).at[0].set(root_nu)
+            cegb_state = (coupled_used, lazy_used, lazy_nu)
+            root_pen = cegb_penalty(total_c, coupled_used, root_nu)
+        mono_state = ()
+        root_bounds = None
+        if has_mono:
+            leaf_min0 = jnp.full((L,), -jnp.inf, dtype)
+            leaf_max0 = jnp.full((L,), jnp.inf, dtype)
+            mono_state = (leaf_min0, leaf_max0)
+            if intermediate:
+                mono_state = mono_state + (jnp.zeros((L, L - 1), jnp.int8),)
+            root_bounds = (leaf_min0[0], leaf_max0[0])
+            if advanced:
+                # per-leaf bin-space boxes [lo, hi) per feature; the root
+                # covers everything
+                box_lo0 = jnp.zeros((L, F_orig), jnp.int32)
+                box_hi0 = jnp.full((L, F_orig), B, jnp.int32)
+                mono_state = mono_state + (box_lo0, box_hi0)
+                root_bounds = advanced_bounds(box_lo0, box_hi0,
+                                              tree.leaf_value,
+                                              tree.num_leaves,
+                                              box_lo0[0], box_hi0[0])
+        nmask_state = ()
+        root_node_mask = None
+        if use_bynode:
+            root_node_mask = node_feature_mask(0)
+            nmask_state = (jnp.zeros((L, F_orig), jnp.bool_)
+                           .at[0].set(root_node_mask),)
+            root_mask = root_node_mask if root_mask is None \
+                else root_mask & root_node_mask
+        # the root's "parent output" is its own unsmoothed output
+        # (GetParentOutput, serial_tree_learner.cpp:1005-1012)
+        root_out = tree.leaf_value[0]
+        best = best.store(0, best_for(hist_f(root_hist), total_g, total_h,
+                                      total_c, root_mask, root_pen,
+                                      root_out, jnp.asarray(0, jnp.int32),
+                                      root_bounds),
+                          jnp.asarray(True))
+        # histogram cache: full per-leaf [L, F, B, 2], or a bounded slot
+        # pool [PS, F, B, 2] with recompute-on-miss (HistogramPool analog,
+        # feature_histogram.hpp; budget from histogram_pool_size)
+        pooled = 0 < cfg.hist_pool_slots < L
+        PS = cfg.hist_pool_slots if pooled else L
+        hists = jnp.zeros((PS, FH, B, 2),
+                          jnp.int32 if quant else dtype).at[0].set(root_hist)
+        pool_state = ()
+        if pooled:
+            pool_state = (
+                jnp.full((L,), -1, jnp.int32).at[0].set(0),   # leaf2slot
+                jnp.full((PS,), -1, jnp.int32).at[0].set(0),  # slot2leaf
+                jnp.zeros((PS,), jnp.int32),                  # lru tick
+            )
+        pay0 = gw2_q if quant \
+            else (gw2.astype(jnp.bfloat16) if bf16_pay else gw2)
+        ord0 = (jnp.arange(n, dtype=jnp.uint32)
+                | jnp.where(inbag, _IB_BIT, jnp.uint32(0))) if track \
+            else jnp.zeros((2,), jnp.uint32)
+        bins2_0 = jnp.pad(bins_pk, ((PAD, PAD + SEG), (0, 0)))
+        state = _CompactState(
+            tree=tree, best=best, hists=hists,
+            # the wide partition stores the words FLAT (see wide_part)
+            bins2=bins2_0.reshape(-1) if wide_part else bins2_0,
+            # ... and the f32 payload PLANAR (see pay_planar)
+            pay2=jnp.concatenate(
+                [jnp.pad(pay0[:, c], (PAD, PAD + SEG)) for c in range(C)])
+            if pay_planar else jnp.pad(pay0, ((PAD, PAD + SEG), (0, 0))),
+            ord2=jnp.pad(ord0, (PAD, PAD + SEG)) if track else ord0,
+            leaf_buf=jnp.zeros((L,), jnp.int32),
+            leaf_begin=jnp.zeros((L,), jnp.int32),
+            leaf_count=jnp.zeros((L,), jnp.int32).at[0].set(n),
+            branch=jnp.zeros((L, F_orig), jnp.bool_),
+            num_splits=jnp.asarray(0, jnp.int32),
+            cegb=cegb_state, mono=mono_state, node_masks=nmask_state,
+            pool=pool_state, comm_ef=comm_ef0,
+            # the first split's leaf is 0 (only the root has a stored
+            # candidate), so the prefetched parent is the root histogram
+            pcache=(jnp.zeros((1,), hists.dtype) if pooled else root_hist))
 
     def depth_ok(d):
         if cfg.max_depth <= 0:
@@ -3045,9 +3055,10 @@ def _grow_compact_impl(cfg: GrowConfig,
         f_leaf, f_feat, f_bin = forced
         M = min(int(f_leaf.shape[0]), L - 1)
         forced_ok = jnp.asarray(True)
-        for i in range(M):
-            state, forced_ok = forced_step(state, forced_ok, f_leaf[i],
-                                           f_feat[i], f_bin[i])
+        with scope("grow/fixed"):
+            for i in range(M):
+                state, forced_ok = forced_step(state, forced_ok, f_leaf[i],
+                                               f_feat[i], f_bin[i])
 
     # growth loop: a while_loop with the stop condition in cond_fn (the
     # reference's early break, serial_tree_learner.cpp:225) — unlike a
@@ -3062,45 +3073,48 @@ def _grow_compact_impl(cfg: GrowConfig,
     # cost: tree and leaf bookkeeping, masks, bounds, the loop itself
     with scope("grow/fixed"):
         state = lax.while_loop(can_grow, do_split, state)
-    if bundled:
-        # bundle columns can't be re-routed by the predictor (the tree
-        # references ORIGINAL features); merge the per-leaf windows
-        # (each living in one ping-pong half) into one coherent order
-        # vector, then invert
-        leaf_of_pos = _leaf_of_positions(state.leaf_begin,
-                                         state.leaf_count, n, L)
-        in_b1 = _leaf_values_at_positions(
-            state.leaf_begin, state.leaf_count, state.leaf_buf, n) == 1
-        order_m = jnp.where(in_b1, state.ord2[SEG + PAD: SEG + PAD + n],
-                            state.ord2[PAD: PAD + n])
-        order_ids = (order_m & ~_IB_BIT).astype(jnp.int32)
-        row_leaf = _row_leaf_from_order(order_ids, leaf_of_pos)
-    else:
-        # re-route rows through the finished tree with the in-order
-        # node sweep (ops/predict.py) instead of inverting ord2 with
-        # two FULL-LENGTH variadic sorts: the sweep is nn sequential
-        # [n] column selects, while an n-row bitonic sort moves
-        # ~log^2(n) passes of row data through HBM — at 10.5M rows the
-        # sorts dwarf the sweep. Routing semantics are identical to
-        # chunk_goleft (same thresholds, NaN bins, cat masks).
-        t = state.tree
-        row_leaf = predict_leaf_binned(
-            t.split_feature, t.threshold_bin, t.default_left,
-            t.left_child, t.right_child, feat_nan_bin, bins_T,
-            t.split_is_cat if has_cat else None,
-            t.split_cat_mask if has_cat else None)
-        # an ungrown tree has no internal node 0 to route through
-        row_leaf = jnp.where(t.num_leaves > 1, row_leaf, 0)
-    tree = state.tree
-    if quant and cfg.renew_leaf:
-        # RenewIntGradTreeOutput (gradient_discretizer.hpp): replace the
-        # quantized leaf outputs with exact float sums per leaf.
-        sg = psum(jax.ops.segment_sum(gw2[:, 0], row_leaf, num_segments=L))
-        sh = psum(jax.ops.segment_sum(gw2[:, 1], row_leaf, num_segments=L))
-        newv = leaf_output(sg, sh, p)
-        lv = jnp.where(jnp.arange(L) < tree.num_leaves, newv,
-                       tree.leaf_value)
-        tree = tree._replace(leaf_value=lv)
+    # once a tree, over every row: the leaf each row ended in (and,
+    # quantized, the leaf outputs renewed from it)
+    with scope("grow/row_leaf"):
+        if bundled:
+            # bundle columns can't be re-routed by the predictor (the tree
+            # references ORIGINAL features); merge the per-leaf windows
+            # (each living in one ping-pong half) into one coherent order
+            # vector, then invert
+            leaf_of_pos = _leaf_of_positions(state.leaf_begin,
+                                             state.leaf_count, n, L)
+            in_b1 = _leaf_values_at_positions(
+                state.leaf_begin, state.leaf_count, state.leaf_buf, n) == 1
+            order_m = jnp.where(in_b1, state.ord2[SEG + PAD: SEG + PAD + n],
+                                state.ord2[PAD: PAD + n])
+            order_ids = (order_m & ~_IB_BIT).astype(jnp.int32)
+            row_leaf = _row_leaf_from_order(order_ids, leaf_of_pos)
+        else:
+            # re-route rows through the finished tree with the in-order
+            # node sweep (ops/predict.py) instead of inverting ord2 with
+            # two FULL-LENGTH variadic sorts: the sweep is nn sequential
+            # [n] column selects, while an n-row bitonic sort moves
+            # ~log^2(n) passes of row data through HBM — at 10.5M rows the
+            # sorts dwarf the sweep. Routing semantics are identical to
+            # chunk_goleft (same thresholds, NaN bins, cat masks).
+            t = state.tree
+            row_leaf = predict_leaf_binned(
+                t.split_feature, t.threshold_bin, t.default_left,
+                t.left_child, t.right_child, feat_nan_bin, bins_T,
+                t.split_is_cat if has_cat else None,
+                t.split_cat_mask if has_cat else None)
+            # an ungrown tree has no internal node 0 to route through
+            row_leaf = jnp.where(t.num_leaves > 1, row_leaf, 0)
+        tree = state.tree
+        if quant and cfg.renew_leaf:
+            # RenewIntGradTreeOutput (gradient_discretizer.hpp): replace the
+            # quantized leaf outputs with exact float sums per leaf.
+            sg = psum(jax.ops.segment_sum(gw2[:, 0], row_leaf, num_segments=L))
+            sh = psum(jax.ops.segment_sum(gw2[:, 1], row_leaf, num_segments=L))
+            newv = leaf_output(sg, sh, p)
+            lv = jnp.where(jnp.arange(L) < tree.num_leaves, newv,
+                           tree.leaf_value)
+            tree = tree._replace(leaf_value=lv)
     if cegb:
         return tree, row_leaf, state.cegb[0], state.cegb[1]
     return tree, row_leaf

@@ -251,6 +251,7 @@ class LambdarankNDCG(Objective):
         # against the slots this padded form computes and moves
         sizes = np.diff(self._qb)
         self._pass_queries = len(sizes)
+        self._pass_rows = int(self._qb[-1])
         self._pass_pairs = source_loop_pairs(
             sizes, np.split(label, self._qb[1:-1]), self.trunc)
         self._ready = True
@@ -297,6 +298,7 @@ class LambdarankNDCG(Objective):
             jnp.float32(self.sigmoid), trunc=self.trunc, norm=self.norm)
         from .obs.registry import registry
         registry.counter("rank_queries").inc(self._pass_queries)
+        registry.counter("rank_rows").inc(self._pass_rows)
         registry.counter("rank_pairs").inc(self._pass_pairs)
         registry.counter("rank_pair_slots").inc(layout.pair_slots)
         registry.counter("rank_row_slots").inc(layout.row_slots)

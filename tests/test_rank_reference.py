@@ -461,7 +461,8 @@ def test_the_gradient_program_is_shaped_by_the_length_classes(length_mix):
 def test_the_pass_counts_its_classes_slots(length_mix):
     """``rank_pairs`` is the source loop's whatever the layout;
     ``rank_pair_slots`` / ``rank_row_slots`` are the sums over the
-    classes; the classes are a function of the lengths alone."""
+    classes, ``rank_rows`` the documents they hold; the classes are a
+    function of the lengths alone."""
     name, sizes, label, weight, dataset = length_mix
     obj, again = _lambdarank(), _lambdarank()
     obj.set_dataset(dataset)
@@ -484,13 +485,15 @@ def test_the_pass_counts_its_classes_slots(length_mix):
     assert lay.pair_slots <= -(-len(sizes) // old_blk) * old_blk * longest ** 2
     if len(dims) == 1:
         assert dims[0][2] == longest
-    names = ("rank_queries", "rank_pairs", "rank_pair_slots", "rank_row_slots")
+    names = ("rank_queries", "rank_pairs", "rank_pair_slots",
+             "rank_row_slots", "rank_rows")
     before = [registry.counter(c).snapshot() for c in names]
     obj.grad_hess(jnp.zeros(len(label), jnp.float32), jnp.asarray(label), None)
     moved = [registry.counter(c).snapshot() - b for c, b in zip(names, before)]
     assert moved == [len(sizes), ranking.source_loop_pairs(
         sizes, np.split(label, np.cumsum(sizes)[:-1]), 30),
-        lay.pair_slots, lay.row_slots]
+        lay.pair_slots, lay.row_slots, len(label)]
+    assert len(label) <= lay.row_slots      # the row fill is a share
 
 
 def test_auc_is_the_rank_sum_statistic_with_ties():

@@ -259,6 +259,38 @@ def test_wide_partition_moves_a_chunks_rows_once(which, request):
     assert 1 <= len(retiles) <= 2, retiles
 
 
+@pytest.mark.parametrize("which", ["meshless", "four_rank"])
+def test_every_op_the_chip_would_time_has_a_scope(which, request):
+    """The grower's account closes in the program the chip's compiler
+    makes (ISSUE 37): outside the fused computations and reducers, whose
+    instructions a trace never shows, every instruction that does anything
+    is in the op -> scope table, directly or derived, once-a-tree work
+    under ``grow/setup`` and ``grow/row_leaf``; what is left bare
+    (parameters, constants, tuples and their elements, bitcasts) runs
+    nothing. The derivation's neighbour rules take only the compiler's
+    own ops, never one the tracer named outside every ``with scope``: so
+    this fails the day the grower traces work under no scope, and until
+    then ``(unscoped)`` in a trace of this program is a stale table."""
+    from lightgbm_tpu.obs import scopes
+    compiled, _, _ = request.getfixturevalue(which)
+    hlo = compiled.as_text()
+    table = scopes.scopes_from_hlo_text(hlo)
+    assert table is not None and table.module
+    assert {"grow/setup", "grow/row_leaf", "grow/fixed", "grow/hist/build",
+            "grow/partition/gather"} <= set(table.values())
+    insts, _, _ = scopes._parse_hlo(hlo)
+    # fused computations, reducers, comparators: run inside their caller
+    inner = {c for inst in insts.values() if inst[1] != "while"
+             for c in inst[3]}
+    free = {"parameter", "constant", "tuple", "get-tuple-element",
+            "bitcast"}
+    timed = {name for name, inst in insts.items()
+             if inst[0] not in inner and inst[1] not in free}
+    assert len(timed) > 500 and {"while", "fusion", "copy"} <= {
+        insts[name][1] for name in timed}
+    assert sorted(timed - set(table)) == []
+
+
 def test_the_lambdarank_pass_is_shaped_by_its_length_classes(one_chip):
     """The ranking gradient as the chip's compiler leaves it (PR 36), on
     the length mix of the benchmark's ranking table (18,919 lognormal

@@ -817,7 +817,7 @@ def _hand_built_capture():
     table = {"while.1": "grow/fixed", "sort.5": "grow/partition/key_sort",
              "fusion.211": "grow/hist/build",
              "copy.1297": "grow/partition/payload"}
-    return {"devices": {"/device:TPU:0": ops}, "host": host}, table
+    return {"devices": {"/device:TPU:0": ops}, "host": host}, {None: table}
 
 
 def test_xplane_by_scope_nesting_union_and_gap_attribution():
@@ -852,14 +852,135 @@ def test_xplane_by_scope_nesting_union_and_gap_attribution():
     assert "no op -> scope table" in xplane.render_report(bare)
 
 
+def _two_program_capture():
+    """Two programs whose ops share names (the compiler numbers each
+    program's from the same stock): the grower [0, 10) ms and the
+    gradient [12, 16) ms each run a ``fusion.202`` and a ``copy.7``;
+    ``neg.3`` runs between them, in no program. Host: the round, the
+    engine's ``metric/eval`` and ``valid/score_update`` inside a callback
+    span, each over one idle gap."""
+    ms = 1e-3
+    ops = [("%fusion.202 = s32[2270296] fusion(%a)", 0.0, 6 * ms),
+           ("%copy.7 = f32[8] copy(%b)", 6 * ms, 4 * ms),
+           ("%neg.3 = f32[8] negate(%x)", 10 * ms, 0.5 * ms),
+           ("%fusion.202 = f32[1774,128] fusion(%c)", 12 * ms, 3 * ms),
+           ("%copy.7 = f32[8] copy(%d)", 15 * ms, 1 * ms),
+           ("%fusion.9 = f32[8] fusion(%e)", 20 * ms, 1 * ms)]
+    modules = [("jit_grow_tree_impl(123)", 0.0, 10 * ms),
+               ("jit__lambdarank_grads(456)", 12 * ms, 4 * ms),
+               ("jit__ndcg_at(789)", 20 * ms, 1 * ms)]
+    host = [("train/round", -1 * ms, 23 * ms),
+            ("callbacks/after", 10 * ms, 11 * ms),
+            ("valid/score_update", 10.4 * ms, 1.7 * ms),
+            ("metric/eval", 15.9 * ms, 5 * ms),
+            ("PjRtFuture::Await", 16.5 * ms, 3 * ms)]
+    tables = {"jit_grow_tree_impl": {"fusion.202": "grow/row_leaf",
+                                     "copy.7": "grow/setup"},
+              "jit__lambdarank_grads": {
+                  "fusion.202": "boost/gradients/lambdarank"}}
+    return {"devices": {"/device:TPU:0": ops},
+            "modules": {"/device:TPU:0": modules}, "host": host}, tables
+
+
+@pytest.mark.parametrize("form", ["by_program", "flat", "file"])
+def test_xplane_lays_each_programs_table_over_its_own_ops(form, tmp_path):
+    """One op name in two programs: with the tables by program each
+    ``fusion.202`` goes to its own program's scope and the gradient's
+    ``copy.7``, which its table does not hold, stays unscoped; one table
+    under no program (a file from before the overlay, as
+    ``load_op_scopes`` folds it) names both alike."""
+    from lightgbm_tpu.obs import xplane
+    capture, tables = _two_program_capture()
+    if form == "flat":
+        tables = {None: {**tables["jit__lambdarank_grads"],
+                         **tables["jit_grow_tree_impl"]}}
+    elif form == "file":
+        path = tmp_path / "op_scopes.json"
+        path.write_text(json.dumps({
+            "ops/grow_tree": {"ops": tables["jit_grow_tree_impl"],
+                              "derived": [], "missing": [],
+                              "module": "jit_grow_tree_impl"},
+            "ranking/lambdarank_grads": {
+                "ops": tables["jit__lambdarank_grads"], "derived": [],
+                "module": "jit__lambdarank_grads"}}))
+        tables = xplane.load_op_scopes(str(path))
+        assert set(tables) == {"jit_grow_tree_impl",
+                               "jit__lambdarank_grads"}
+    dev = xplane.report(capture, tables)["devices"][0]
+    sc, progs = dev["by_scope"], dev["by_program"]
+    assert list(progs) == ["jit_grow_tree_impl", "jit__lambdarank_grads",
+                           "jit__ndcg_at"]
+    assert progs["jit_grow_tree_impl"]["runs"] == 1
+    assert progs["jit_grow_tree_impl"]["self_s"] == pytest.approx(10e-3)
+    assert progs["jit_grow_tree_impl"]["by_scope"] == pytest.approx(
+        {"grow/row_leaf": 6e-3, "grow/setup": 4e-3})
+    if form == "flat":
+        assert sc == pytest.approx({"grow/row_leaf": 9e-3,
+                                    "grow/setup": 5e-3,
+                                    xplane.UNSCOPED: 1.5e-3})
+    else:
+        assert sc == pytest.approx({
+            "grow/row_leaf": 6e-3, "grow/setup": 4e-3,
+            "boost/gradients/lambdarank": 3e-3, xplane.UNSCOPED: 2.5e-3})
+        assert progs["jit__lambdarank_grads"]["by_scope"] == pytest.approx(
+            {"boost/gradients/lambdarank": 3e-3, xplane.UNSCOPED: 1e-3})
+    ops, mods = (capture[k]["/device:TPU:0"] for k in ("devices", "modules"))
+    rows = xplane.op_times(ops, tables, mods)
+    assert sum(r[2] for r in rows) == pytest.approx(sum(sc.values()))
+    assert (None, "neg.3", pytest.approx(0.5e-3), xplane.UNSCOPED) in rows
+    text = xplane.render_report({"devices": [dev], "has_table": True})
+    assert "program jit_grow_tree_impl: 1 runs" in text
+
+
+@pytest.mark.parametrize("span,at_ms", [("valid/score_update", 10.5),
+                                        ("metric/eval", 16.0)])
+def test_a_gap_under_the_eval_paths_spans_is_put_down_to_them(span, at_ms):
+    """``valid/*``, ``metric/*`` (and ``compile/*``) are program spans:
+    a gap under one goes to it, not to the callback span around it."""
+    from lightgbm_tpu.obs import xplane
+    capture, tables = _two_program_capture()
+    gaps = xplane.report(capture, tables)["devices"][0]["idle_gaps"]
+    (gap,) = [g for g in gaps if g["at_s"] == pytest.approx(at_ms * 1e-3)]
+    assert gap["span"] == span
+    assert {"valid", "metric", "compile"} <= set(xplane.HOST_SPAN_ROOTS)
+
+
+def test_trace_cli_xplane_reads_an_eager_capture_program_by_program(
+        tmp_path, monkeypatch, capsys):
+    """``trace <dir> --xplane <trace-dir>`` on an eager ranking capture:
+    the tables ``write_op_scopes`` left beside it go each over its own
+    program, and a gap under the evaluation path's spans is theirs."""
+    from lightgbm_tpu.obs import xplane
+    capture, tables = _two_program_capture()
+    tdir = tmp_path / "prof"
+    tdir.mkdir()
+    (tdir / "op_scopes.json").write_text(json.dumps({
+        "ops/grow_tree": {"ops": tables["jit_grow_tree_impl"],
+                          "derived": [], "missing": [],
+                          "module": "jit_grow_tree_impl"},
+        "ranking/lambdarank_grads": {
+            "ops": tables["jit__lambdarank_grads"], "derived": [],
+            "missing": [], "module": "jit__lambdarank_grads"}}))
+    monkeypatch.setattr(xplane, "find_xplane", lambda d: str(tdir / "x"))
+    monkeypatch.setattr(xplane, "load", lambda path: capture)
+    assert T.main([str(tmp_path), "--xplane", str(tdir)]) == 0
+    out = capsys.readouterr().out
+    grower = out[out.index("program jit_grow_tree_impl"):
+                 out.index("program jit__lambdarank_grads")]
+    assert "grow/row_leaf" in grower and "lambdarank" not in grower
+    assert "boost/gradients/lambdarank" in out
+    assert "in valid/score_update" in out and "in metric/eval" in out
+
+
 def test_trace_cli_xplane_reads_the_table_beside_the_capture(
         tmp_path, monkeypatch, capsys):
     from lightgbm_tpu.obs import xplane
-    capture, table = _hand_built_capture()
+    capture, tables = _hand_built_capture()
     tdir = tmp_path / "prof"
     tdir.mkdir()
     (tdir / "op_scopes.json").write_text(json.dumps(
-        {"gbdt/fused_iter": {"ops": table, "derived": ["copy.1297"]}}))
+        {"gbdt/fused_iter": {"ops": tables[None],
+                             "derived": ["copy.1297"]}}))
     monkeypatch.setattr(xplane, "find_xplane", lambda d: str(tdir / "x"))
     monkeypatch.setattr(xplane, "load", lambda path: capture)
     spans = tmp_path / "telemetry"

@@ -5,14 +5,18 @@
 
 Runs the cell through the benchmark's own ``run_cell`` with the trace
 kept, holds the program's jitted entries alive past the driver's
-``del``, then asks the program for the op -> scope table
-(``obs.op_scopes``) and lays it over the device trace (``obs.xplane``):
-device self time by scope, the share directly scoped and derived, the
-heaviest ops with their scopes, each idle gap over 1 ms with the
-program span covering it, the persistent cache's hits and misses, and
-the job's host spans. Prints the benchmark's result line, then one JSON
-object; ``--out`` also writes it. Not part of the benchmark: it reads
-the program, the benchmark does not change.
+``del``, then asks the program for the op -> scope table of every
+scoped entry that ran (``obs.op_scopes`` over ``SCOPED_ENTRIES``) and
+lays each over the ops of ITS program's executions in the device trace
+(``obs.xplane``): device self time by scope and by program, the share
+directly scoped and derived, the heaviest ops of ``--entry``'s program
+with their scopes (a null scope: an op no scope or derivation rule
+reaches), the scopes each table is ``missing`` (a stale compile-cache
+entry), each idle gap over 1 ms with the program span covering it, the
+persistent cache's hits and misses, and the job's host spans. Prints
+the benchmark's result line, then one JSON object; ``--out`` also
+writes it. Not part of the benchmark: it reads the program, the
+benchmark does not change.
 """
 
 import argparse
@@ -59,13 +63,18 @@ def main(argv=None):
     print(json.dumps(line), flush=True)
 
     t0 = time.perf_counter()
-    table = scopes.op_scopes(args.entry)
+    by_entry = scopes.tables_of_run()
     table_s = time.perf_counter() - t0
+    tables = {t.module: t for t in by_entry.values() if t}
+    table = by_entry.get(args.entry)
     host = out["observations"]["host"]
     doc = {"workload": args.workload, "seed": args.seed,
            "entry": args.entry, "op_scopes_s": table_s,
-           "op_scopes": None if table is None else
-           {"ops": len(table), "derived": len(table.derived)},
+           "op_scopes": {
+               entry: t and {"module": t.module, "ops": len(t),
+                             "derived": len(t.derived),
+                             "missing": list(t.missing)}
+               for entry, t in by_entry.items()},
            "compile_cache_dir": host.get("compile_cache_dir"),
            "metrics": {k: v["value"] for k, v in line["metrics"].items()},
            "counters": {
@@ -78,20 +87,22 @@ def main(argv=None):
     tdir = host.get("trace_dir")
     if tdir:
         capture = xplane.load(xplane.find_xplane(tdir))
-        rep = xplane.report(capture, table)
+        rep = xplane.report(capture, tables)
         for dev in rep["devices"]:
             ops = capture["devices"][dev["plane"]]
-            per_op = {}
-            for name, self_s in xplane.self_times(ops):
-                head = xplane.op_head(name)
-                per_op[head] = per_op.get(head, 0.0) + self_s
-            derived = table.derived if table is not None else ()
-            dev["derived_s"] = sum(s for op, s in per_op.items()
-                                   if op in derived)
+            rows = xplane.op_times(
+                ops, tables, capture["modules"].get(dev["plane"], []))
+            dev["derived_s"] = sum(
+                s for prog, op, s, _ in rows
+                if prog in tables and op in tables[prog].derived)
+            # the heaviest ops of --entry's program (of every program
+            # where it has no table): [program, op, self_s, scope or
+            # null, derived?]
             dev["top_ops"] = [
-                [op, s, (table or {}).get(op), op in derived]
-                for op, s in sorted(per_op.items(),
-                                    key=lambda kv: -kv[1])[:40]]
+                [prog, op, s, None if sc == xplane.UNSCOPED else sc,
+                 prog in tables and op in tables[prog].derived]
+                for prog, op, s, sc in sorted(rows, key=lambda r: -r[2])
+                if not table or prog == table.module][:40]
             # what the host was doing across each gap: every host event
             # that overlaps it, outermost first
             t_first = min(s for _, s, _ in ops)
